@@ -13,18 +13,18 @@ descent with the filter on vs off — wall time and metric evals per query —
 and emits the ``frontier_parent_prune_*`` gate rows CI checks.
 
 Also: bulk build, engine-vs-ref page hits, insert/delete fast-path rates,
-and the sharded-serve-vs-single-device decode comparison (ROADMAP item) run
-as subprocesses over ``repro.launch.serve``.
+and the sharded-serve-vs-single-device decode comparison (ROADMAP item),
+both legs in-process through ``repro.launch.serve.main``.
 
 Scale envs: REPRO_BENCH_SMOKE=1 (tiny, CI) / REPRO_BENCH_FULL=1 (paper
 scale); default is the PR-acceptance matrix (b up to 1024, n up to 100k).
 """
 from __future__ import annotations
 
+import contextlib
+import io
 import os
 import re
-import subprocess
-import sys
 import time
 
 import jax
@@ -58,7 +58,6 @@ PRUNE_METRICS = ["d_inf", "l2", "l1"]
 PRUNE_EVAL_TARGET = 0.75
 K = 10
 MAX_FRONTIER = 64
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _cohort_impl() -> str:
@@ -163,39 +162,34 @@ def _prune_matrix(report):
 
 
 def _serve_case(report):
-    """ROADMAP item: sharded serve (--mesh host over forced host devices) vs
-    single-device decode, measured in ms/step via subprocesses (each needs
-    its own XLA_FLAGS before jax import)."""
-    steps = 4 if SMOKE else 8
-    base = [sys.executable, "-m", "repro.launch.serve", "--arch",
-            "qwen2.5-3b", "--smoke", "--batch", "8", "--prompt-len", "4",
-            "--steps", str(steps)]
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(_ROOT, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
+    """ROADMAP item: sharded serve (``--mesh host`` over the visible
+    devices) vs single-device decode, measured in ms/step.  Both run in
+    this process — a child process could not open a chip this process
+    already holds — with the driver's summary line parsed off stdout."""
+    from repro.launch import serve
 
-    def run_case(name, cmd, extra_env):
-        e = dict(env, **extra_env)
+    steps = 4 if SMOKE else 8
+    base = ["--arch", "qwen2.5-3b", "--smoke", "--batch", "8",
+            "--prompt-len", "4", "--steps", str(steps)]
+
+    def run_case(name, argv):
+        out = io.StringIO()
         try:
-            proc = subprocess.run(cmd, capture_output=True, text=True,
-                                  env=e, timeout=900)
-            m = re.search(r"\(([\d.]+) ms/step", proc.stdout)
-            if m is None:
-                # surface the failure so a NaN row in CI is diagnosable
-                print(f"# serve case {name}: no ms/step in output "
-                      f"(rc={proc.returncode})\n"
-                      f"# stderr tail: {proc.stderr[-2000:]}", flush=True)
-            report(name, float(m.group(1)) if m else float("nan"))
-            return float(m.group(1)) if m else float("nan")
+            with contextlib.redirect_stdout(out):
+                serve.main(argv)
         except Exception as exc:  # noqa: BLE001 — a bench row, not control flow
             print(f"# serve case {name} failed: {exc}", flush=True)
-            report(name, float("nan"))
-            return float("nan")
+        m = re.search(r"\(([\d.]+) ms/step", out.getvalue())
+        if m is None:
+            # surface the failure so a NaN row in CI is diagnosable
+            print(f"# serve case {name}: no ms/step in output\n"
+                  f"# output tail: {out.getvalue()[-2000:]}", flush=True)
+        value = float(m.group(1)) if m else float("nan")
+        report(name, value)
+        return value
 
-    single = run_case("serve_single_ms_per_step", base, {})
-    sharded = run_case(
-        "serve_sharded_ms_per_step", base + ["--mesh", "host"],
-        {"XLA_FLAGS": "--xla_force_host_platform_device_count=4"})
+    single = run_case("serve_single_ms_per_step", base)
+    sharded = run_case("serve_sharded_ms_per_step", base + ["--mesh", "host"])
     if np.isfinite(single) and np.isfinite(sharded) and sharded > 0:
         report("serve_sharded_vs_single_ratio", round(single / sharded, 3))
 

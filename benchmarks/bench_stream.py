@@ -18,10 +18,7 @@ Scale envs: REPRO_BENCH_SMOKE=1 (tiny, CI) / REPRO_BENCH_FULL=1.
 from __future__ import annotations
 
 import os
-import re
 import shutil
-import subprocess
-import sys
 import tempfile
 import time
 
@@ -231,144 +228,118 @@ def _time_loop(tree, ops, xs, oids) -> float:
     return n / (time.perf_counter() - t0)
 
 
-# Both legs (device collectives vs escalate-to-host) run INTERLEAVED in
-# one subprocess — dev/host/dev/host, best-of-2 per leg — because on a
-# shared CI/container host, separate minute-apart processes see ±30%
-# machine drift, which is larger than the effect under test.
-_MESH_WORKER = r"""
-import os, time
-import numpy as np
-import jax
-from repro.core.smtree import bulk_build
-from repro.core.smtree import OP_DELETE, OP_INSERT
-from repro.data.datagen import make_dataset
-from repro.stream import StreamingForest
+# Both legs (device collectives vs escalate-to-host) run INTERLEAVED —
+# dev/host/dev/host, best-of-2 per leg — because on a shared CI/container
+# host, runs minutes apart see ±30% machine drift, which is larger than
+# the effect under test.
+def _mesh_forest_case(kind: str, n: int, n_ops: int):
+    """Best-of-2 (device, host) ops/s of one mesh-forest workload, plus the
+    device leg's absorption counters.  Runs in this process, one shard per
+    visible device (up to 4): a child process could not open a chip this
+    process already holds."""
+    import jax
 
-S = 4
-n = int(os.environ["BSF_N"])
-n_ops = int(os.environ["BSF_OPS"])
-batch = 256
-kind = os.environ.get("BSF_KIND", "insert")
-mesh = jax.make_mesh((S,), ("model",))
-X = make_dataset("clustered", n, seed=7)[:, :10].copy()
-# insert streams need near-full leaves (split pressure) and free-ring
-# slack for sustained splits; delete streams need leaves near min-fill
-# (underflow pressure) and never allocate
-fill = 0.9 if kind == "insert" else 0.48
-slack = 4.0 if kind == "insert" else 1.5
-trees0 = [bulk_build(X[np.arange(s, n, S)], ids=np.arange(s, n, S),
-                     capacity=32, fill_frac=fill, slack=slack)
-          for s in range(S)]
-if kind == "insert":
-    xs = make_dataset("uniform", n_ops + batch, seed=11)[:, :10].copy()
-    oids = (10 * n + np.arange(n_ops + batch)).astype(np.int32)
-    ops_all = np.full(n_ops + batch, OP_INSERT, np.int32)
-else:   # delete-heavy mix: 90% deletes of live ids, 10% fresh inserts
-    rng = np.random.default_rng(13)
-    victims = rng.permutation(n)[:int((n_ops + batch) * 0.9)]
-    n_ins = n_ops + batch - len(victims)
-    ops_all = np.concatenate([np.full(len(victims), OP_DELETE, np.int32),
-                              np.full(n_ins, OP_INSERT, np.int32)])
-    oids = np.concatenate([victims,
-                           10 * n + np.arange(n_ins)]).astype(np.int32)
-    xs = np.concatenate([X[victims],
-                         make_dataset("uniform", n_ins,
-                                      seed=11)[:, :10]]).astype(np.float32)
-    perm = rng.permutation(n_ops + batch)
-    ops_all, oids, xs = ops_all[perm], oids[perm], xs[perm]
+    from repro.core import distributed as dist
+    from repro.core import smtree as smt
+    from repro.dist.sharding import make_mesh
+    from repro.stream import StreamingForest
 
+    S = min(4, jax.device_count())
+    batch = 256
+    mesh = make_mesh((S,), ("model",))
+    X = make_dataset("clustered", n, seed=7)[:, :10].copy()
+    # insert streams need near-full leaves (split pressure) and free-ring
+    # slack for sustained splits; delete streams need leaves near min-fill
+    # (underflow pressure) and never allocate
+    fill = 0.9 if kind == "insert" else 0.48
+    slack = 4.0 if kind == "insert" else 1.5
+    trees0 = [bulk_build(X[np.arange(s, n, S)], ids=np.arange(s, n, S),
+                         capacity=32, fill_frac=fill, slack=slack)
+              for s in range(S)]
+    if kind == "insert":
+        xs = make_dataset("uniform", n_ops + batch, seed=11)[:, :10].copy()
+        oids = (10 * n + np.arange(n_ops + batch)).astype(np.int32)
+        ops_all = np.full(n_ops + batch, OP_INSERT, np.int32)
+    else:   # delete-heavy mix: 90% deletes of live ids, 10% fresh inserts
+        rng = np.random.default_rng(13)
+        victims = rng.permutation(n)[:int((n_ops + batch) * 0.9)]
+        n_ins = n_ops + batch - len(victims)
+        ops_all = np.concatenate([np.full(len(victims), OP_DELETE, np.int32),
+                                  np.full(n_ins, OP_INSERT, np.int32)])
+        oids = np.concatenate([victims,
+                               10 * n + np.arange(n_ins)]).astype(np.int32)
+        xs = np.concatenate([X[victims],
+                             make_dataset("uniform", n_ins,
+                                          seed=11)[:, :10]]).astype(np.float32)
+        perm = rng.permutation(n_ops + batch)
+        ops_all, oids, xs = ops_all[perm], oids[perm], xs[perm]
 
-def run_leg(dev):
-    trees = [jax.tree.map(lambda a: a.copy(), t) for t in trees0]
-    sf = StreamingForest(trees, mesh=mesh, device_splits=dev,
-                         device_merges=dev)
-    stats = {"esc": 0, "dev": 0}
+    def run_leg(dev):
+        trees = [jax.tree.map(lambda a: a.copy(), t) for t in trees0]
+        sf = StreamingForest(trees, mesh=mesh, device_splits=dev,
+                             device_merges=dev)
+        stats = {"esc": 0, "dev": 0}
 
-    def step(s0):
-        r = sf.apply(ops_all[s0:s0 + batch],
-                     xs[s0:s0 + batch].astype(np.float32),
-                     oids[s0:s0 + batch])
-        stats["esc"] += r.n_escalated
-        stats["dev"] += r.n_split + r.n_merge
+        def step(s0):
+            r = sf.apply(ops_all[s0:s0 + batch],
+                         xs[s0:s0 + batch].astype(np.float32),
+                         oids[s0:s0 + batch])
+            stats["esc"] += r.n_escalated
+            stats["dev"] += r.n_split + r.n_merge
 
-    step(0)   # warm the apply collective (and stack the forest)
-    if dev:
-        # warm the split/merge collectives explicitly: the warm batch
-        # only reaches them when it happens to over/underflow a leaf,
-        # and their seconds-scale scan compile must not land in the
-        # timed loop.  NOP chunks compile the exact jit entries the hot
-        # path dispatches; the returned (unchanged) forest is discarded.
-        from repro.core import distributed as dist
-        from repro.core import smtree as smt
-        w = smt.SPLIT_CHUNK
-        dist.forest_apply_splits(
-            sf._stacked, mesh, np.full(w, smt.OP_NOP, np.int32),
-            np.zeros((w, 10), np.float32), np.full(w, -1, np.int32),
-            np.zeros(w, np.int32))
-        for w in (smt.MERGE_CHUNK, smt.MERGE_CHUNK_MAX):
-            dist.forest_apply_merges(
-                sf._stacked, mesh, np.full(w, smt.OP_NOP, np.int32),
-                np.full(w, -1, np.int32), np.zeros(w, np.int32))
-    stats["esc"] = stats["dev"] = 0
-    t0 = time.perf_counter()
-    for s0 in range(batch, batch + n_ops, batch):
-        step(s0)
-    return n_ops / (time.perf_counter() - t0), stats
-
-
-best = {True: 0.0, False: 0.0}
-counts = {}
-for rep in range(2):
-    for dev in (True, False):
-        rate, stats = run_leg(dev)
-        best[dev] = max(best[dev], rate)
+        step(0)   # warm the apply collective (and stack the forest)
         if dev:
-            counts = stats
-print(f"RESULT dev {best[True]:.1f} host {best[False]:.1f} ops/s "
-      f"ESC {counts['esc']} DEV {counts['dev']}")
-"""
+            # warm the split/merge collectives explicitly: the warm batch
+            # only reaches them when it happens to over/underflow a leaf,
+            # and their seconds-scale scan compile must not land in the
+            # timed loop.  NOP chunks compile the exact jit entries the
+            # hot path dispatches; the returned (unchanged) forest is
+            # discarded.
+            w = smt.SPLIT_CHUNK
+            dist.forest_apply_splits(
+                sf._stacked, mesh, np.full(w, smt.OP_NOP, np.int32),
+                np.zeros((w, 10), np.float32), np.full(w, -1, np.int32),
+                np.zeros(w, np.int32))
+            for w in (smt.MERGE_CHUNK, smt.MERGE_CHUNK_MAX):
+                dist.forest_apply_merges(
+                    sf._stacked, mesh, np.full(w, smt.OP_NOP, np.int32),
+                    np.full(w, -1, np.int32), np.zeros(w, np.int32))
+        stats["esc"] = stats["dev"] = 0
+        t0 = time.perf_counter()
+        for s0 in range(batch, batch + n_ops, batch):
+            step(s0)
+        return n_ops / (time.perf_counter() - t0), stats
+
+    best = {True: 0.0, False: 0.0}
+    counts = {}
+    for rep in range(2):
+        for dev in (True, False):
+            rate, stats = run_leg(dev)
+            best[dev] = max(best[dev], rate)
+            if dev:
+                counts = stats
+    return best[True], best[False], counts
 
 
 def _mesh_forest_rows(report):
-    """The tentpole measurements: a mesh-resident 4-shard StreamingForest,
-    device structure-edit collectives vs the escalate-to-host path (which
-    must unstack + restack the whole stacked forest around every host
-    edit).  Two workloads: the PR-4 split-heavy insert stream, and the
-    PR-5 delete-heavy mix (90% deletes) whose underflows run the
-    forest_apply_merges collective — with the absorption counters proving
-    zero host escalations on the device path.  Subprocesses: each needs
-    its own XLA_FLAGS device-count override before jax import."""
+    """The tentpole measurements: a mesh-resident StreamingForest (one
+    shard per device, up to 4), device structure-edit collectives vs the
+    escalate-to-host path (which must unstack + restack the whole stacked
+    forest around every host edit).  Two workloads: the PR-4 split-heavy
+    insert stream, and the PR-5 delete-heavy mix (90% deletes) whose
+    underflows run the forest_apply_merges collective — with the
+    absorption counters proving zero host escalations on the device
+    path."""
     # shards must be big enough that the host path's whole-forest
     # unstack/restack cost is visible over collective dispatch overhead
     n, n_ops = (2_000, 768) if SMOKE else (32_000, 2_048)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..",
-                                      "src") + os.pathsep
-                         + env.get("PYTHONPATH", ""))
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=4").strip()
-    env["BSF_N"] = str(n)
-    env["BSF_OPS"] = str(n_ops)
     for kind, label in (("insert", "split"), ("delete", "merge")):
-        e = dict(env, BSF_KIND=kind)
         d_rate = h_rate = float("nan")
         try:
-            proc = subprocess.run([sys.executable, "-c", _MESH_WORKER],
-                                  capture_output=True, text=True, env=e,
-                                  timeout=3600)
-            m = re.search(
-                r"RESULT dev ([\d.]+) host ([\d.]+) ops/s "
-                r"ESC (\d+) DEV (\d+)", proc.stdout)
-            if m is None:
-                print(f"# mesh forest case {label}: no result "
-                      f"(rc={proc.returncode})\n"
-                      f"# stderr tail: {proc.stderr[-2000:]}", flush=True)
-            else:
-                d_rate, h_rate = float(m.group(1)), float(m.group(2))
-                report(f"mesh_forest_{label}_heavy_host_escalations",
-                       int(m.group(3)))
-                report(f"mesh_forest_{label}_heavy_device_edits",
-                       int(m.group(4)))
+            d_rate, h_rate, counts = _mesh_forest_case(kind, n, n_ops)
+            report(f"mesh_forest_{label}_heavy_host_escalations",
+                   counts["esc"])
+            report(f"mesh_forest_{label}_heavy_device_edits", counts["dev"])
         except Exception as exc:  # noqa: BLE001 — a bench row
             print(f"# mesh forest case {label} failed: {exc}", flush=True)
         report(f"mesh_forest_{label}_heavy_ops_per_s", d_rate)

@@ -58,6 +58,8 @@ def main(argv=None) -> None:
     ap.add_argument("--tag", default=None,
                     help="write benchmarks/BENCH_<tag>.json")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     results: dict[str, dict[str, object]] = {}
     current = {"suite": None}

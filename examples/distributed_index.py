@@ -17,9 +17,10 @@ from repro.core.distributed import (brute_force_knn, build_forest,
 from repro.core.metric import pairwise
 from repro.data.datagen import clustered
 from repro.dist.sharding import use_mesh as _use_mesh
+from repro.dist.sharding import make_mesh
 
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 X = clustered(20_000, dims=12, seed=0)[:, :12].copy()
 Q = X[:32] + np.float32(0.005)
 

@@ -23,11 +23,12 @@ from repro.serve.knnlm import KnnLmConfig, KnnLmDatastore, mix_logits
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_step import TrainSettings, init_all, make_train_step
 from repro.dist.sharding import use_mesh as _use_mesh
+from repro.dist.sharding import make_mesh
 
 
 cfg = dataclasses.replace(smoke_config("qwen2.5-3b"), n_layers=2,
                           block_pattern=("attn",))
-mesh = jax.make_mesh((1, 1), ("data", "model"))
+mesh = make_mesh((1, 1), ("data", "model"))
 dc = DataConfig(seed=0, vocab_size=cfg.vocab_size, seq_len=64, global_batch=8)
 
 # --- 1. brief training -------------------------------------------------------

@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import smtree
 from repro.core.smtree import TreeArrays, bulk_build
-from repro.dist.sharding import shard_map  # version-portable wrapper
+from repro.dist.sharding import shard_map
 
 _DATA_FIELDS = ("vecs", "radius", "pdist", "child", "oid", "valid", "count",
                 "is_leaf", "alive", "parent", "pslot", "root", "n_nodes",
@@ -199,7 +199,7 @@ def _forest_knn_fn(mesh: Mesh, axis: str, batch_axis: str | None, k: int,
 
     @jax.jit
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+                       out_specs=out_specs)
     def run(forest_slice, q):
         tree = _local_tree(forest_slice)
         res = smtree.knn(tree, q, k=k, max_frontier=max_frontier,
@@ -249,7 +249,7 @@ def _forest_delete_fn(mesh: Mesh, axis: str):
     @jax.jit
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(P(axis), P(None), P(None)),
-                       out_specs=(P(axis), P(None)), check_rep=False)
+                       out_specs=(P(axis), P(None)))
     def run(forest_slice, xs, oids):
         tree = _local_tree(forest_slice)
 
@@ -328,7 +328,7 @@ def _forest_apply_mutations_fn(mesh: Mesh, axis: str):
     @jax.jit
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(P(axis), P(None), P(None), P(None), P(None)),
-                       out_specs=(P(axis), P(None)), check_rep=False)
+                       out_specs=(P(axis), P(None)))
     def run(forest_slice, ops, xs, oids, owner):
         tree = _local_tree(forest_slice)
         me = jax.lax.axis_index(axis)
@@ -365,7 +365,7 @@ def _forest_apply_splits_fn(mesh: Mesh, axis: str):
     @jax.jit
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(P(axis), P(None), P(None), P(None), P(None)),
-                       out_specs=(P(axis), P(None)), check_rep=False)
+                       out_specs=(P(axis), P(None)))
     def run(forest_slice, ops, xs, oids, owner):
         tree = _local_tree(forest_slice)
         me = jax.lax.axis_index(axis)
@@ -400,7 +400,7 @@ def _forest_apply_merges_fn(mesh: Mesh, axis: str):
     @jax.jit
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(P(axis), P(None), P(None), P(None)),
-                       out_specs=(P(axis), P(None)), check_rep=False)
+                       out_specs=(P(axis), P(None)))
     def run(forest_slice, ops, oids, owner):
         tree = _local_tree(forest_slice)
         me = jax.lax.axis_index(axis)
@@ -436,7 +436,7 @@ def _forest_extract_objects_fn(mesh: Mesh, axis: str):
     @jax.jit
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(P(axis), P(None), P(None)),
-                       out_specs=(P(None), P(None)), check_rep=False)
+                       out_specs=(P(None), P(None)))
     def run(forest_slice, oids, owner):
         tree = _local_tree(forest_slice)
         me = jax.lax.axis_index(axis)
@@ -459,7 +459,7 @@ def brute_force_knn(X: jax.Array, mesh: Mesh, queries: jax.Array, *,
     from repro.kernels import ops
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(P(axis), P(None)),
-                       out_specs=(P(None), P(None)), check_rep=False)
+                       out_specs=(P(None), P(None)))
     def run(xs, q):
         d = ops.pairwise_distance(q, xs, metric=metric)       # [b, n_loc]
         neg, idx = jax.lax.top_k(-d, k)

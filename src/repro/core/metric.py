@@ -14,7 +14,7 @@ reference implementation, the JAX engine's cohort descent, and the fused
 Pallas frontier kernel (three call sites, one definition — they cannot
 drift).
 
-Summing reductions go through ``_sum_last``, a fixed-association pairwise
+Summing reductions go through ``_fold_sum``, a fixed-association pairwise
 tree fold: the reduction tree depends only on the axis length, never on the
 leading shape or backend, so l1/l2 distances are *bitwise identical* whether
 evaluated on a ``[cap, dim]`` Pallas block, a ``[b, F, cap, dim]`` XLA
@@ -47,90 +47,75 @@ def get_metric(name: str) -> MetricFn:
         raise KeyError(f"unknown metric {name!r}; have {sorted(_REGISTRY)}") from None
 
 
-def _truncate(x, y, n_dims):
+def _take(x, start, stop, axis):
+    """``x[start:stop]`` along ``axis`` (keeps the axis; numpy or jax)."""
+    return x[(slice(None),) * (axis % x.ndim) + (slice(start, stop),)]
+
+
+def _truncate(x, y, n_dims, axis):
     if n_dims is not None:
-        x = x[..., :n_dims]
-        y = y[..., :n_dims]
+        x = _take(x, 0, n_dims, axis)
+        y = _take(y, 0, n_dims, axis)
     return x, y
 
 
-def _sum_last(x):
-    """Sum over the last axis with a fixed pairwise-tree association.
+def _fold_sum(x, axis=-1, keepdims=False):
+    """Sum over ``axis`` with a fixed pairwise-tree association.
 
     Floating-point addition is not associative, and XLA's reduce grouping
     varies with the operand's leading shape — the same row summed inside a
     ``[cap, dim]`` kernel block and a ``[b, F, cap, dim]`` gather can differ
-    in the last ulp.  This fold's association is a function of ``dim`` alone
-    (halve, add, carry the odd tail), so every call site produces bitwise
-    identical sums.  Works on numpy and jax arrays (slicing + ``+`` only).
+    in the last ulp.  This fold's association is a function of the axis
+    length alone (halve, add, carry the odd tail), so every call site
+    produces bitwise identical sums, whichever axis holds the coordinates
+    (the TPU kernel folds over sublanes, the XLA path over the last axis).
+    Works on numpy and jax arrays (static slices + ``+`` only).
     """
-    n = x.shape[-1]
-    if n == 0:
-        return x.sum(axis=-1)   # empty sum: zeros, association irrelevant
-    if n == 1:
-        return x[..., 0]
-    h = n // 2
-    s = _sum_last(x[..., :h] + x[..., h:2 * h])
-    if n % 2:
-        s = s + x[..., -1]
-    return s
-
-
-_JAX_BARRIER = None
-
-
-def _jax_barrier():
-    """Lazily built vmap-compatible optimization barrier (jax's own
-    primitive has no batching rule; batching is shape-preserving here, so a
-    pass-through custom_vmap is sound)."""
-    global _JAX_BARRIER
-    if _JAX_BARRIER is None:
-        import jax
-
-        @jax.custom_batching.custom_vmap
-        def barrier(x):
-            return jax.lax.optimization_barrier(x)
-
-        @barrier.def_vmap
-        def _barrier_vmap(axis_size, in_batched, x):
-            return barrier(x), in_batched[0]
-
-        _JAX_BARRIER = barrier
-    return _JAX_BARRIER
+    n = x.shape[axis]
+    if n == 0:   # empty sum: zeros, association irrelevant
+        return x.sum(axis=axis, keepdims=keepdims)
+    s = x
+    if n > 1:
+        h = n // 2
+        s = _fold_sum(_take(x, 0, h, axis) + _take(x, h, 2 * h, axis),
+                      axis, keepdims=True)
+        if n % 2:
+            s = s + _take(x, n - 1, n, axis)
+    return s if keepdims else s.squeeze(axis)
 
 
 def _pin_rounding(x):
-    """Keep XLA:CPU from contracting the squares into the fold's adds as
-    FMAs — contraction is fusion-context-dependent, so without this pin
-    the same l2 distance can differ by an ulp between e.g. a Pallas
-    interpret-mode kernel and a plain gather (breaking bitwise parity).
-
-    The optimization barrier alone is NOT sufficient: XLA:CPU strips
-    barriers before fusion, and LLVM then contracts ``fadd(fmul, ·)``
-    into an FMA in small fusion contexts (observed on the scalar pdist
-    eval inside the fused insert fast path — 1-ulp drift vs the numpy
-    fold, caught by tests/test_pdist_invariant.py).  ``max(x, 0)`` is an
-    identity for the squares this guards but interposes an op LLVM's
+    """Keep the compiler from contracting the squares into the fold's adds
+    as FMAs — contraction is fusion-context-dependent, so without this pin
+    the same l2 distance can differ by an ulp between e.g. the Pallas
+    kernel and the plain gather (breaking bitwise parity).  ``max(x, 0)``
+    is an identity for the squares this guards but interposes an op LLVM's
     contraction pattern cannot see through, so the product is rounded to
-    f32 exactly once at every call site.  No-op on numpy."""
+    f32 exactly once at every call site (observed on the scalar pdist eval
+    inside the fused insert fast path — 1-ulp drift vs the numpy fold,
+    caught by tests/test_pdist_invariant.py).  No-op on numpy."""
     if isinstance(x, np.ndarray):
         return x
     import jax.numpy as jnp
-    return jnp.maximum(_jax_barrier()(x), 0.0)
+    return jnp.maximum(x, 0.0)
 
+
+# Every metric reduces the coordinate axis ``axis`` (default: last) of the
+# broadcast difference.  ``keepdims`` leaves it as length 1 — the kernel's
+# [dim, cap] page view reduces to a [1, cap] row that way.
 
 @register_metric("d_inf")
-def d_inf(x, y, n_dims: int | None = None):
+def d_inf(x, y, n_dims: int | None = None, *, axis=-1, keepdims=False):
     """Chebyshev metric; broadcasting pairwise over leading axes."""
-    x, y = _truncate(x, y, n_dims)
-    return abs(x - y).max(axis=-1)
+    x, y = _truncate(x, y, n_dims, axis)
+    return abs(x - y).max(axis=axis, keepdims=keepdims)
 
 
 @register_metric("l2")
-def l2(x, y, n_dims: int | None = None):
-    x, y = _truncate(x, y, n_dims)
+def l2(x, y, n_dims: int | None = None, *, axis=-1, keepdims=False):
+    x, y = _truncate(x, y, n_dims, axis)
     d = x - y
-    s = _sum_last(_pin_rounding(d * d))
+    s = _fold_sum(_pin_rounding(d * d), axis, keepdims)
     if isinstance(s, np.ndarray):
         return np.sqrt(s)
     # true sqrt, not s ** 0.5: pow goes through libm whose rounding varies
@@ -141,9 +126,9 @@ def l2(x, y, n_dims: int | None = None):
 
 
 @register_metric("l1")
-def l1(x, y, n_dims: int | None = None):
-    x, y = _truncate(x, y, n_dims)
-    return _sum_last(abs(x - y))
+def l1(x, y, n_dims: int | None = None, *, axis=-1, keepdims=False):
+    x, y = _truncate(x, y, n_dims, axis)
+    return _fold_sum(abs(x - y), axis, keepdims)
 
 
 def pairwise(metric: str | MetricFn, X, Y, n_dims: int | None = None):

@@ -1146,13 +1146,13 @@ def _apply_mutations_jit(donate: bool):
 
 
 def apply_mutations(tree: TreeArrays, ops, xs, oids, *,
-                    donate: bool | None = None, splits: bool = True,
+                    donate: bool = False, splits: bool = True,
                     merges: bool = True):
     """Batched insert/delete apply.  Returns (tree, statuses [B] int32).
 
     ops: [B] int32 opcodes, xs: [B, dim] f32, oids: [B] int32.  Ops apply in
     log order; see ``_apply_mutations_impl`` for escalation statuses.  With
-    ``donate`` (default: on accelerators) the input tree's buffers are
+    ``donate`` (default off, on every backend) the input tree's buffers are
     donated — callers must treat the argument as consumed.
 
     With ``splits`` (default), overflow rows are resolved by the on-device
@@ -1169,12 +1169,10 @@ def apply_mutations(tree: TreeArrays, ops, xs, oids, *,
     are abstract) both flags are no-ops and the caller runs the
     collectives itself (``core.distributed.forest_apply_splits`` /
     ``forest_apply_merges``)."""
-    if donate is None:
-        donate = jax.default_backend() not in ("cpu",)
     ops = jnp.asarray(ops, jnp.int32)
     xs = jnp.asarray(xs, jnp.float32)
     oids = jnp.asarray(oids, jnp.int32)
-    tree, status = _apply_mutations_jit(bool(donate))(tree, ops, xs, oids)
+    tree, status = _apply_mutations_jit(donate)(tree, ops, xs, oids)
     if splits or merges:
         try:
             st_host = np.asarray(status)
@@ -1598,7 +1596,7 @@ def _apply_splits_jit(donate: bool):
 
 
 def apply_splits(tree: TreeArrays, ops, xs, oids, *,
-                 donate: bool | None = None):
+                 donate: bool = False):
     """On-device split pass over a compacted batch of overflow inserts.
 
     ops/xs/oids: [K] rows previously reported ST_OVERFLOW by
@@ -1607,12 +1605,10 @@ def apply_splits(tree: TreeArrays, ops, xs, oids, *,
     device, ST_OVERFLOW for rows needing the host control plane (multi-level
     or root splits, or an empty free ring — and, to preserve log order,
     every row after the first such failure), ST_NOP for pads."""
-    if donate is None:
-        donate = jax.default_backend() not in ("cpu",)
     ops = jnp.asarray(ops, jnp.int32)
     xs = jnp.asarray(xs, jnp.float32)
     oids = jnp.asarray(oids, jnp.int32)
-    return _apply_splits_jit(bool(donate))(tree, ops, xs, oids)
+    return _apply_splits_jit(donate)(tree, ops, xs, oids)
 
 
 # Fixed dispatch width for the split pass: exactly ONE jit entry per tree
@@ -1629,7 +1625,7 @@ def split_chunks(n: int):
 
 
 def resolve_overflows(tree: TreeArrays, ops, xs, oids, statuses, *,
-                      donate: bool | None = None):
+                      donate: bool = False):
     """Compact a batch's ST_OVERFLOW rows and run the device split pass.
 
     statuses: [B] int32 on the host.  Returns (tree, statuses, n_resolved)
@@ -1926,7 +1922,7 @@ def _apply_merges_jit(donate: bool):
 
 
 def apply_merges(tree: TreeArrays, ops, oids, *,
-                 donate: bool | None = None):
+                 donate: bool = False):
     """On-device merge pass over a compacted batch of underflow deletes.
 
     ops/oids: [K] rows previously reported ST_UNDERFLOW by
@@ -1935,11 +1931,9 @@ def apply_merges(tree: TreeArrays, ops, oids, *,
     targets that vanished (cannot happen inside a conflict-free cohort,
     kept for the host path's semantics), ST_NOP for pads.  Merges never
     allocate, so — unlike ``apply_splits`` — no row ever blocks."""
-    if donate is None:
-        donate = jax.default_backend() not in ("cpu",)
     ops = jnp.asarray(ops, jnp.int32)
     oids = jnp.asarray(oids, jnp.int32)
-    return _apply_merges_jit(bool(donate))(tree, ops, oids)
+    return _apply_merges_jit(donate)(tree, ops, oids)
 
 
 # Dispatch widths for the merge pass.  Unlike the split ladder (one fixed
@@ -1973,7 +1967,7 @@ def merge_chunks(n: int):
 
 
 def resolve_underflows(tree: TreeArrays, ops, oids, statuses, *,
-                       donate: bool | None = None):
+                       donate: bool = False):
     """Compact a batch's ST_UNDERFLOW rows and run the device merge pass.
 
     statuses: [B] int32 on the host.  Returns (tree, statuses, n_resolved)

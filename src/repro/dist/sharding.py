@@ -17,12 +17,11 @@ Design rules (DESIGN.md §7):
   * Rules are duck-typed on the mesh (only ``.shape``/``.axis_names`` are
     read) so they unit-test without devices (tests/test_sharding_rules.py).
 
-Also hosts the small runtime layer the model code uses:
-``use_mesh``/``_ambient_mesh`` (an explicit ambient-mesh stack that works on
-every jax version, with or without ``jax.sharding.set_mesh``),
+Also hosts the small runtime layer the model code uses: ``make_mesh``
+(every mesh of the repo, with Auto axes), ``use_mesh``/``_ambient_mesh``
+(the ambient mesh ``constrain`` resolves axis names against),
 ``constrain``/``constrain_batch_seq`` (divisibility-guarded
-with_sharding_constraint), ``set_sequence_parallel`` and a ``shard_map``
-compat wrapper.
+with_sharding_constraint), ``set_sequence_parallel`` and ``shard_map``.
 """
 from __future__ import annotations
 
@@ -44,10 +43,22 @@ DP_AXES = ("pod", "data")
 
 
 # ---------------------------------------------------------------------------
-# ambient mesh (compat layer: jax<=0.4 has no jax.sharding.set_mesh)
+# meshes and the ambient mesh
 # ---------------------------------------------------------------------------
 _MESH_STACK: list[Any] = []
 _SEQ_PARALLEL = False
+
+
+def make_mesh(shape, axis_names, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    jax's own default is Explicit axes, under which a gather from a sharded
+    array must name its output sharding; this repo's sharding is GSPMD's
+    (``constrain``, jit argument shardings, ``shard_map`` bodies), so every
+    mesh it builds is Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def _ambient_mesh():
@@ -59,7 +70,6 @@ def _ambient_mesh():
 def use_mesh(mesh):
     """Context manager installing ``mesh`` as the ambient mesh.
 
-    Portable replacement for ``jax.sharding.set_mesh`` (absent in older jax):
     ``constrain`` resolves axis names against this mesh, and the physical
     ``Mesh`` context is entered too so named in-jit collectives resolve.
     """
@@ -83,35 +93,18 @@ def set_sequence_parallel(flag: bool) -> None:
 
 
 def shard_map(f=None, mesh=None, in_specs=None, out_specs=None,
-              axis_names=None, **kwargs):
-    """shard_map across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=, axis_names=)``, older
-    only ``jax.experimental.shard_map.shard_map(..., check_rep=, auto=)``;
-    this wrapper accepts either spelling (and partial application as a
-    decorator) and forwards to whichever exists.  ``axis_names`` is the set
-    of *manual* axes (new-API convention): on new jax it passes through, so
-    e.g. the MoE expert-parallel body stays manual over 'data' only while
-    GSPMD tensor-shards the expert FFN over 'model'.  On old jax the
-    equivalent partial-manual spelling (``auto=`` complement) hard-crashes
-    the XLA SPMD partitioner for these bodies, so the wrapper falls back to
-    fully-manual there — numerically identical, at the cost of replicated
-    expert FFN compute across 'model' on that jax version only.
-    Replication checking is always off — the forest/moe bodies do their own
-    collectives."""
+              axis_names=None):
+    """``jax.shard_map`` with replication checking off (the forest/moe
+    bodies do their own collectives), usable as a partial-application
+    decorator.  ``axis_names`` is the set of *manual* axes: e.g. the MoE
+    expert-parallel body stays manual over 'data' only while GSPMD
+    tensor-shards the expert FFN over 'model'."""
     if f is None:                       # functools.partial decorator form
         return lambda fn: shard_map(fn, mesh, in_specs, out_specs,
-                                    axis_names=axis_names, **kwargs)
-    kwargs.pop("check_rep", None)
-    kwargs.pop("check_vma", None)
-    if hasattr(jax, "shard_map"):
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, **kwargs)
+                                    axis_names=axis_names)
+    kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kwargs)
 
 
 # ---------------------------------------------------------------------------

@@ -25,9 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams; support both
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+# metrics whose per-chunk parts add up (the rest take the running max)
+_SUMMED = ("l2", "l1", "sqeuclidean", "ip")
 
 
 def _dist_kernel(q_ref, e_ref, out_ref, *, metric: str, nk: int):
@@ -36,8 +35,12 @@ def _dist_kernel(q_ref, e_ref, out_ref, *, metric: str, nk: int):
     e = e_ref[...].astype(jnp.float32)          # [be, bd]
     if metric == "d_inf":
         part = jnp.max(jnp.abs(q[:, None, :] - e[None, :, :]), axis=-1)
-        acc0 = jnp.zeros_like(part)
-        combine = jnp.maximum
+    elif metric == "l2":
+        # squared differences, summed here; the wrapper takes the sqrt
+        diff = q[:, None, :] - e[None, :, :]
+        part = jnp.sum(diff * diff, axis=-1)
+    elif metric == "l1":
+        part = jnp.sum(jnp.abs(q[:, None, :] - e[None, :, :]), axis=-1)
     elif metric == "sqeuclidean":
         # |q-e|^2 = |q|^2 - 2 q.e + |e|^2 : MXU does the q @ e.T contraction
         qq = jnp.sum(q * q, axis=-1, keepdims=True)          # [bq, 1]
@@ -45,18 +48,15 @@ def _dist_kernel(q_ref, e_ref, out_ref, *, metric: str, nk: int):
         qe = jax.lax.dot_general(q, e, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         part = qq - 2.0 * qe + ee
-        acc0 = jnp.zeros_like(part)
-        combine = lambda a, b: a + b
     elif metric == "ip":
         part = -jax.lax.dot_general(q, e, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-        acc0 = jnp.zeros_like(part)
-        combine = lambda a, b: a + b
     else:
         raise ValueError(metric)
 
-    prev = jnp.where(k == 0, acc0, out_ref[...])
-    out_ref[...] = combine(prev, part)
+    prev = jnp.where(k == 0, jnp.zeros_like(part), out_ref[...])
+    out_ref[...] = prev + part if metric in _SUMMED \
+        else jnp.maximum(prev, part)
 
 
 def _dist_prune_kernel(q_ref, e_ref, rq_ref, re_ref, out_ref, mask_ref,
@@ -68,12 +68,17 @@ def _dist_prune_kernel(q_ref, e_ref, rq_ref, re_ref, out_ref, mask_ref,
 
     @pl.when(k == nk - 1)
     def _():
-        d = out_ref[...]
+        d = _finish(out_ref[...], metric)
         if metric == "sqeuclidean":
             d = jnp.sqrt(jnp.maximum(d, 0.0))
         rq = rq_ref[...].astype(jnp.float32)    # [bq]
         re = re_ref[...].astype(jnp.float32)    # [be]
         mask_ref[...] = d <= rq[:, None] + re[None, :]
+
+
+def _finish(acc, metric):
+    """Accumulator -> distance: l2 accumulates squares."""
+    return jnp.sqrt(acc) if metric == "l2" else acc
 
 
 def _pad_to(x, mult, axis, value=0.0):
@@ -109,11 +114,11 @@ def pairwise_distance_pallas(q: jax.Array, e: jax.Array, *, metric: str = "d_inf
         ],
         out_specs=pl.BlockSpec((bq, be), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nqp, nep), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, ep)
-    out = out[:nq, :ne]
+    out = _finish(out[:nq, :ne], metric)
     if metric == "sqeuclidean":
         out = jnp.maximum(out, 0.0)
     return out
@@ -155,8 +160,8 @@ def pairwise_distance_prune_pallas(q, e, r_q, r_e, *, metric: str = "d_inf",
             jax.ShapeDtypeStruct((nqp, nep), jnp.float32),
             jax.ShapeDtypeStruct((nqp, nep), jnp.bool_),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, ep, rqp, rep)
-    return dist[:nq, :ne], mask[:nq, :ne]
+    return _finish(dist[:nq, :ne], metric), mask[:nq, :ne]

@@ -26,10 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams; support both
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
 NEG_INF = -1e30
@@ -128,7 +124,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)

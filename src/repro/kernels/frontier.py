@@ -19,8 +19,8 @@ in HBM.  This kernel instead keys the pipeline on the frontier itself: the
 ``[b, F]`` node-id table is a *scalar-prefetch* operand
 (``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index maps read the ids
 before the body runs and the Pallas pipeline streams exactly the referenced
-node pages (``vecs``/``radius``/``pdist``/validity rows) HBM→VMEM,
-double-buffered across grid steps.  Distances and all four outputs are
+node pages (``vecs``, and the 8-row blocks of ``radius``/``pdist``/validity
+holding the node's row) HBM→VMEM, double-buffered across grid steps.  Distances and all four outputs are
 computed in one VMEM-resident pass; nothing of size ``[b, F, cap, dim]``
 ever exists.
 
@@ -35,10 +35,9 @@ and the per-query radius ``rq``, the prologue drops every entry with
 *before* the metric eval: by the triangle inequality
 |d(q,p) - d(e,p)| <= d(q,e), so such an entry provably fails the descent's
 d - r <= r_q + eps prune test and its distance never needed computing.
-Filtered entries' VPU lanes are masked (``jnp.where`` on the page input)
-and a node whose entries are all filtered skips the reduction entirely
-(``pl.when``).  Outputs are bitwise identical to the unfiltered kernel —
-only the evaluation count changes.
+Filtered entries emit +inf, and a node whose entries are all filtered
+skips the reduction entirely (``pl.when``).  Outputs are bitwise identical
+to the unfiltered kernel — only the evaluation count changes.
 
 Grid: ``(b, F)`` — one step per (query, frontier-slot) pair.  Invalid slots
 (node id < 0, the frontier padding) emit +inf rows; the metric itself is the
@@ -74,67 +73,65 @@ _PRUNE_PAD = 2e-5
 _IMPLS = ("pallas", "xla")
 
 
-def _emit(dmax_ref, score_ref, leafd_ref, dq_ref, iv, lv, live, q_ref,
-          vecs_ref, r, *, metric: str, mask_lanes: bool):
-    """Shared kernel epilogue: evaluate the metric for one streamed node
-    page and write the four output rows, or emit +inf rows without touching
-    the VPU when no entry needs a distance (``pl.when`` whole-node skip)."""
-    any_live = jnp.any(live)
+def _frontier_kernel(fids_ref, *refs, metric: str, prune: bool, qb: int,
+                     nb: int):
+    """One grid step (i, j): score frontier slot j of query i.
+
+    Operands keep their HBM shapes; the TPU's (8, 128) block rule is met by
+    whole-row blocks: queries/rq/qpd arrive as the ``qb``-row block holding
+    query i, and each ``[N, cap]`` per-entry array as the ``nb``-row block
+    holding node ``fids[i, j]`` — the kernel picks its row with a dynamic
+    sublane slice.  The outputs' ``[w, cap]`` block of query i stays
+    resident across the j steps and is written one row at a time.
+
+    The page is scored transposed (``[dim, cap]``): the metric then folds
+    the coordinates over sublanes and yields the ``[1, cap]`` row the
+    outputs want, with no lane slicing.  Masks are i32 rows (1 internal,
+    2 leaf, 0 neither), compared only at the final selects."""
+    if prune:
+        (q_ref, qpd_ref, rq_ref, vecs_ref, rad_ref, pd_ref, ival_ref,
+         lval_ref, dmax_ref, score_ref, leafd_ref, dq_ref) = refs
+    else:
+        (q_ref, vecs_ref, rad_ref, ival_ref, lval_ref,
+         dmax_ref, score_ref, leafd_ref, dq_ref) = refs
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    fid = fids_ref[i, j]
+    qrow = pl.ds(i % qb, 1)
+    nrow = pl.ds(jnp.maximum(fid, 0) % nb, 1)
+    r = rad_ref[nrow, :]                                   # [1, cap]
+    kind = ival_ref[nrow, :] + 2 * lval_ref[nrow, :]       # [1, cap] i32
+    kind = jnp.where(fid >= 0, kind, 0)
+    if prune:
+        # triangle-inequality pre-filter on the already-resident rows — no
+        # metric eval yet.  Invalid slots carry qpd = +inf, so nothing is
+        # kept there and the whole page is skipped.
+        w = qpd_ref.shape[1]
+        slot = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) == j
+        qpd = jnp.max(jnp.where(slot, qpd_ref[qrow, :], -_INF),
+                      axis=1, keepdims=True)               # [1, 1]
+        lb = jnp.abs(qpd - pd_ref[nrow, :])
+        keep = lb <= rq_ref[qrow, :] + r + _PRUNE_PAD
+        kind = jnp.where(keep, kind, 0)
+    out_row = pl.ds(j, 1)
+    any_live = jnp.max(kind) > 0
 
     @pl.when(any_live)
     def _():
-        q = q_ref[0, :]                  # [dim]
-        e = vecs_ref[0, :, :]            # [cap, dim] — the streamed node page
-        if mask_lanes:
-            # filtered entries: zero the lanes so the reduction they ride
-            # through is dead weight the compiler can drop; live entries'
-            # inputs are untouched, keeping d bitwise equal to the
-            # unfiltered kernel
-            e = jnp.where(live[:, None], e, 0.0)
-        d = get_metric(metric)(q[None, :], e)        # [cap]
-        dmax_ref[0, 0, :] = jnp.where(iv, d + r, _INF)
-        score_ref[0, 0, :] = jnp.where(iv, d - r, _INF)
-        leafd_ref[0, 0, :] = jnp.where(lv, d, _INF)
-        dq_ref[0, 0, :] = jnp.where(iv, d, _INF)
+        q = q_ref[qrow, :]                                 # [1, dim]
+        page = vecs_ref[0]                                 # [cap, dim]
+        d = get_metric(metric)(q.T, page.T, axis=0, keepdims=True)  # [1, cap]
+        iv = kind == 1
+        dmax_ref[0, out_row, :] = jnp.where(iv, d + r, _INF)
+        score_ref[0, out_row, :] = jnp.where(iv, d - r, _INF)
+        leafd_ref[0, out_row, :] = jnp.where(kind == 2, d, _INF)
+        dq_ref[0, out_row, :] = jnp.where(iv, d, _INF)
 
     @pl.when(jnp.logical_not(any_live))
     def _():
         inf_row = jnp.full_like(r, _INF)
-        dmax_ref[0, 0, :] = inf_row
-        score_ref[0, 0, :] = inf_row
-        leafd_ref[0, 0, :] = inf_row
-        dq_ref[0, 0, :] = inf_row
-
-
-def _frontier_kernel(fids_ref, q_ref, vecs_ref, rad_ref, ival_ref, lval_ref,
-                     dmax_ref, score_ref, leafd_ref, dq_ref, *, metric: str):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    ok = fids_ref[i, j] >= 0
-    r = rad_ref[0, :]
-    iv = (ival_ref[0, :] != 0) & ok
-    lv = (lval_ref[0, :] != 0) & ok
-    _emit(dmax_ref, score_ref, leafd_ref, dq_ref, iv, lv, iv | lv,
-          q_ref, vecs_ref, r, metric=metric, mask_lanes=False)
-
-
-def _frontier_kernel_pruned(fids_ref, q_ref, qpd_ref, rq_ref, vecs_ref,
-                            rad_ref, pd_ref, ival_ref, lval_ref,
-                            dmax_ref, score_ref, leafd_ref, dq_ref, *,
-                            metric: str):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    ok = fids_ref[i, j] >= 0
-    r = rad_ref[0, :]
-    # triangle-inequality pre-filter on the already-resident scalars — no
-    # metric eval yet.  Invalid slots carry qpd = +inf, so keep is all-False
-    # there and the whole page is skipped.
-    lb = jnp.abs(qpd_ref[0, 0] - pd_ref[0, :])
-    keep = lb <= rq_ref[0, 0] + r + _PRUNE_PAD
-    iv = (ival_ref[0, :] != 0) & ok & keep
-    lv = (lval_ref[0, :] != 0) & ok & keep
-    _emit(dmax_ref, score_ref, leafd_ref, dq_ref, iv, lv, iv | lv,
-          q_ref, vecs_ref, r, metric=metric, mask_lanes=True)
+        for ref in (dmax_ref, score_ref, leafd_ref, dq_ref):
+            ref[0, out_row, :] = inf_row
 
 
 def _check_prune_args(pdist, qpd, rq):
@@ -172,53 +169,41 @@ def frontier_scores_pallas(fids, queries, vecs, radius, internal_valid,
     """
     prune = _check_prune_args(pdist, qpd, rq)
     b, w = fids.shape
-    _, cap, dim = vecs.shape
-    internal_valid = internal_valid.astype(jnp.int8)
-    leaf_valid = leaf_valid.astype(jnp.int8)
+    n, cap, dim = vecs.shape
+    # 8-row blocks satisfy the TPU's sublane rule; a smaller array is one
+    # whole block (a block dim equal to the array dim is always legal)
+    qb, nb = min(b, 8), min(n, 8)
+    internal_valid = internal_valid.astype(jnp.int32)
+    leaf_valid = leaf_valid.astype(jnp.int32)
 
-    def node_row(ndim_tail):
-        # block index for a [N, ...] page row selected by the prefetched id;
-        # empty slots clamp to row 0 and are masked in the kernel body
-        return lambda i, j, fids: (jnp.maximum(fids[i, j], 0),) + (0,) * ndim_tail
-
-    q_spec = pl.BlockSpec((1, dim), lambda i, j, fids: (i, 0))
-    out_spec = pl.BlockSpec((1, 1, cap), lambda i, j, fids: (i, j, 0))
+    q_block = lambda cols: pl.BlockSpec((qb, cols), lambda i, j, f: (i // qb, 0))
+    node_block = pl.BlockSpec(
+        (nb, cap), lambda i, j, f: (jnp.maximum(f[i, j], 0) // nb, 0))
+    page = pl.BlockSpec((1, cap, dim),
+                        lambda i, j, f: (jnp.maximum(f[i, j], 0), 0, 0))
     if prune:
-        in_specs = [
-            q_spec,
-            pl.BlockSpec((1, 1), lambda i, j, fids: (i, j)),   # qpd
-            pl.BlockSpec((1, 1), lambda i, j, fids: (i, 0)),   # rq
-            pl.BlockSpec((1, cap, dim), node_row(2)),
-            pl.BlockSpec((1, cap), node_row(1)),
-            pl.BlockSpec((1, cap), node_row(1)),               # pdist page
-            pl.BlockSpec((1, cap), node_row(1)),
-            pl.BlockSpec((1, cap), node_row(1)),
-        ]
-        operands = (fids, queries, qpd, rq[:, None], vecs, radius,
-                    pdist, internal_valid, leaf_valid)
-        kernel = _frontier_kernel_pruned
+        in_specs = [q_block(dim), q_block(w), q_block(1), page, node_block,
+                    node_block, node_block, node_block]
+        operands = (fids, queries, qpd, rq[:, None], vecs, radius, pdist,
+                    internal_valid, leaf_valid)
     else:
-        in_specs = [
-            q_spec,
-            pl.BlockSpec((1, cap, dim), node_row(2)),
-            pl.BlockSpec((1, cap), node_row(1)),
-            pl.BlockSpec((1, cap), node_row(1)),
-            pl.BlockSpec((1, cap), node_row(1)),
-        ]
+        in_specs = [q_block(dim), page, node_block, node_block, node_block]
         operands = (fids, queries, vecs, radius, internal_valid, leaf_valid)
-        kernel = _frontier_kernel
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, w),
         in_specs=in_specs,
-        out_specs=[out_spec] * 4,
+        out_specs=[pl.BlockSpec((1, w, cap), lambda i, j, f: (i, 0, 0))] * 4,
     )
     out_shape = [jax.ShapeDtypeStruct((b, w, cap), jnp.float32)] * 4
     return pl.pallas_call(
-        functools.partial(kernel, metric=metric),
+        functools.partial(_frontier_kernel, metric=metric, prune=prune,
+                          qb=qb, nb=nb),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
 

@@ -13,8 +13,9 @@ import jax.numpy as jnp
 def pairwise_distance_ref(q: jax.Array, e: jax.Array, metric: str = "d_inf") -> jax.Array:
     """[nq, d] x [ne, d] -> [nq, ne] distances.
 
-    metric: 'd_inf' (Chebyshev), 'l2' (Euclidean), 'sqeuclidean', 'ip'
-    (negative inner product, for MIPS-style retrieval over normalised keys).
+    metric: 'd_inf' (Chebyshev), 'l2' (Euclidean), 'l1' (Manhattan),
+    'sqeuclidean', 'ip' (negative inner product, for MIPS-style retrieval
+    over normalised keys).
     """
     q = q[:, None, :]
     e = e[None, :, :]
@@ -23,6 +24,8 @@ def pairwise_distance_ref(q: jax.Array, e: jax.Array, metric: str = "d_inf") -> 
     if metric in ("l2", "sqeuclidean"):
         d2 = jnp.sum((q - e) ** 2, axis=-1)
         return jnp.sqrt(d2) if metric == "l2" else d2
+    if metric == "l1":
+        return jnp.sum(jnp.abs(q - e), axis=-1)
     if metric == "ip":
         return -jnp.sum(q * e, axis=-1)
     raise ValueError(f"unknown metric {metric!r}")

@@ -6,7 +6,7 @@ XLA_FLAGS before any backend initialisation.
 """
 from __future__ import annotations
 
-import jax
+from repro.dist.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,12 +18,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh over host CPU devices (tests / examples)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

@@ -11,6 +11,7 @@ the decode_32k / long_500k dry-run cells lower for the production mesh.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -20,6 +21,7 @@ import numpy as np
 from repro.configs.all_archs import smoke_config
 from repro.configs.base import get_config
 from repro.data.pipeline import DataConfig, synth_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 
 
@@ -147,7 +149,7 @@ def serve_sharded(args, cfg):
 
     n_dev = len(jax.devices())
     nm = 2 if n_dev % 2 == 0 else 1
-    mesh = jax.make_mesh((n_dev // nm, nm), ("data", "model"))
+    mesh = shd.make_mesh((n_dev // nm, nm), ("data", "model"))
     total = args.prompt_len + args.steps + 1
     shape = ShapeSpec("serve", total, args.batch, "decode")
     dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.prompt_len,
@@ -162,8 +164,9 @@ def serve_sharded(args, cfg):
                          out_shardings=(sh["token"], sh["logits"],
                                         sh["cache"]),
                          donate_argnums=(2,))
-        params = jax.device_put(M.init_params(cfg, jax.random.PRNGKey(0)),
-                                sh["params"])
+        params = jax.jit(M.init_params, static_argnums=0,
+                         out_shardings=sh["params"])(
+            cfg, jax.random.PRNGKey(0))
         cache = jax.device_put(M.init_cache(cfg, args.batch, total),
                                sh["cache"])
         mix_fn = None
@@ -202,6 +205,59 @@ def serve_sharded(args, cfg):
           f"prefill {prefill_s:.2f}s, decode {args.steps} steps in "
           f"{decode_s:.2f}s ({decode_s / args.steps * 1e3:.1f} ms/step"
           f"{', kNN-LM mixed' if mix_fn else ''}{mut}{fe})")
+    print("[serve] sample:", toks[0][:12])
+    _dump_obs(args)
+    return toks
+
+
+def serve_single(args, cfg):
+    """Greedy decode on one device, optionally kNN-LM mixed."""
+    # jitted so the random init fuses into the parameter buffers: eager
+    # init would hold each op's full-size temporaries beside the weights
+    params = jax.jit(M.init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(0))
+    dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.prompt_len,
+                    global_batch=args.batch)
+    prompt = jnp.asarray(synth_batch(dc, 0, with_labels=False)["tokens"])
+
+    store = _build_store(args, cfg) if args.knn else None
+    mutator = (_WindowMutator(store)
+               if store is not None and args.knn_mutate else None)
+
+    cache = M.init_cache(cfg, args.batch, args.prompt_len + args.steps + 1)
+    step_fn = jax.jit(M.decode_step, static_argnums=1)
+
+    t0 = time.time()
+    for pos in range(args.prompt_len):
+        logits, cache = step_fn(params, cfg, prompt[:, pos], cache,
+                                jnp.int32(pos))
+    prefill_s = time.time() - t0
+
+    tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    out = [tok]
+    t0 = time.time()
+    for step in range(args.steps):
+        pos = args.prompt_len + step
+        logits, cache = step_fn(params, cfg, tok, cache, jnp.int32(pos))
+        if store is not None:
+            from repro.serve.knnlm import mix_logits
+            h = params["embed"][tok].astype(jnp.float32)
+            logits = mix_logits(logits, store.knn_logits(
+                h, logits.shape[-1]), args.lam)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        if store is not None and mutator is not None:
+            mutator.step(h, tok)
+        out.append(tok)
+    jax.block_until_ready(tok)   # async dispatch: sync before timing
+    decode_s = time.time() - t0
+    fe = _finish_frontend(store)
+    toks = np.stack([np.asarray(t) for t in out], axis=1)
+    mut = (f", {mutator.n_ops} live mutations "
+           f"({mutator.n_ops / decode_s:.0f} ops/s)" if mutator else "")
+    print(f"[serve] batch {args.batch}: prefill {prefill_s:.2f}s, "
+          f"decode {args.steps} steps in {decode_s:.2f}s "
+          f"({decode_s / args.steps * 1e3:.1f} ms/step"
+          f"{', kNN-LM mixed' if store else ''}{mut}{fe})")
     print("[serve] sample:", toks[0][:12])
     _dump_obs(args)
     return toks
@@ -257,8 +313,10 @@ def main(argv=None):
                          "to PATH (for CI assertions)")
     ap.add_argument("--lam", type=float, default=0.3)
     ap.add_argument("--mesh", default="single", choices=["single", "host"],
-                    help="'host': sharded decode over all host devices")
+                    help="'host': sharded decode over all visible devices "
+                         "(a 1x1 mesh on one device)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.prompt_len < 1:
         ap.error("--prompt-len must be >= 1 (decode needs a seed token)")
     if args.replicas and not args.frontend:
@@ -279,59 +337,18 @@ def main(argv=None):
         obs.enable()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    if args.mesh == "host":
-        if len(jax.devices()) >= 2:
+    # an f32 model's matmuls run in f32.  At default precision XLA:TPU
+    # feeds the MXU bf16 copies of the f32 weights and hoists those
+    # converts out of the layer scan: a bf16 copy of every stacked weight
+    # at once (5.2 GB beside qwen2.5-3b's 12.4 GB, more than a 16 GB chip)
+    precision = (jax.default_matmul_precision("highest")
+                 if cfg.compute_dtype == "float32"
+                 else contextlib.nullcontext())
+    with precision:
+        if args.mesh == "host":
+            # on one device this is a 1x1 mesh: the same sharded program
             return serve_sharded(args, cfg)
-        print("[serve] --mesh host requested but only 1 device visible; "
-              "falling back to the UNSHARDED single-device path "
-              "(set XLA_FLAGS=--xla_force_host_platform_device_count=N "
-              "to shard on CPU)", flush=True)
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.prompt_len,
-                    global_batch=args.batch)
-    prompt = jnp.asarray(synth_batch(dc, 0, with_labels=False)["tokens"])
-
-    store = _build_store(args, cfg) if args.knn else None
-    mutator = (_WindowMutator(store)
-               if store is not None and args.knn_mutate else None)
-
-    cache = M.init_cache(cfg, args.batch, args.prompt_len + args.steps + 1)
-    step_fn = jax.jit(M.decode_step, static_argnums=1)
-
-    t0 = time.time()
-    for pos in range(args.prompt_len):
-        logits, cache = step_fn(params, cfg, prompt[:, pos], cache,
-                                jnp.int32(pos))
-    prefill_s = time.time() - t0
-
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    out = [tok]
-    t0 = time.time()
-    for step in range(args.steps):
-        pos = args.prompt_len + step
-        logits, cache = step_fn(params, cfg, tok, cache, jnp.int32(pos))
-        if store is not None:
-            from repro.serve.knnlm import mix_logits
-            h = params["embed"][tok].astype(jnp.float32)
-            logits = mix_logits(logits, store.knn_logits(
-                h, logits.shape[-1]), args.lam)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        if store is not None and mutator is not None:
-            mutator.step(h, tok)
-        out.append(tok)
-    jax.block_until_ready(tok)   # async dispatch: sync before timing
-    decode_s = time.time() - t0
-    fe = _finish_frontend(store)
-    toks = np.stack([np.asarray(t) for t in out], axis=1)
-    mut = (f", {mutator.n_ops} live mutations "
-           f"({mutator.n_ops / decode_s:.0f} ops/s)" if mutator else "")
-    print(f"[serve] batch {args.batch}: prefill {prefill_s:.2f}s, "
-          f"decode {args.steps} steps in {decode_s:.2f}s "
-          f"({decode_s / args.steps * 1e3:.1f} ms/step"
-          f"{', kNN-LM mixed' if store else ''}{mut}{fe})")
-    print("[serve] sample:", toks[0][:12])
-    _dump_obs(args)
-    return toks
+        return serve_single(args, cfg)
 
 
 if __name__ == "__main__":

@@ -21,6 +21,7 @@ from repro.configs.base import get_config
 from repro.data.pipeline import DataConfig, synth_batch
 from repro.dist import sharding as shd
 from repro.dist.checkpoint import CheckpointManager, latest_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_step import TrainSettings, init_all, make_train_step
 
@@ -43,14 +44,15 @@ def main(argv=None):
     ap.add_argument("--data-seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     n_dev = len(jax.devices())
     if args.mesh == "host" and n_dev >= 2:
         nm = 2 if n_dev % 2 == 0 else 1
-        mesh = jax.make_mesh((n_dev // nm, nm), ("data", "model"))
+        mesh = shd.make_mesh((n_dev // nm, nm), ("data", "model"))
     else:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = shd.make_mesh((1, 1), ("data", "model"))
 
     dc = DataConfig(seed=args.data_seed, vocab_size=cfg.vocab_size,
                     seq_len=args.seq_len, global_batch=args.global_batch)

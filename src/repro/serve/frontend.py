@@ -192,6 +192,7 @@ class FrontendStats:
     n_deadline_dispatch: int = 0  # cohorts shipped by the SLO deadline
     n_mutation_batches: int = 0
     n_maintenance: int = 0        # maintenance slots that did repair work
+    n_maintenance_faults: int = 0  # maintenance slots that raised
     n_shed: int = 0               # admissions rejected with QueueFull
     queue_depth: int = 0          # gauges, updated on every queue touch
     mutation_queue_depth: int = 0
@@ -245,6 +246,7 @@ class FrontendStats:
                 "n_full_dispatch": self.n_full_dispatch,
                 "n_deadline_dispatch": self.n_deadline_dispatch,
                 "n_mutation_batches": self.n_mutation_batches,
+                "n_maintenance_faults": self.n_maintenance_faults,
                 "n_shed": self.n_shed,
                 "queue_depth": self.queue_depth,
                 "mutation_queue_depth": self.mutation_queue_depth,
@@ -544,8 +546,9 @@ class ServeFrontend:
                 # batch, on this same single-writer thread (migration
                 # steps and mutation batches must serialize — both mutate
                 # the trees, and the WAL order is the replay contract).
-                # A repair failure is recorded as a fault, not surfaced on
-                # the user's ticket — their batch already applied.
+                # A repair failure is not surfaced on the user's ticket —
+                # their batch already applied — but it is counted in the
+                # stats (``n_maintenance_faults``) and recorded as a fault.
                 if self.cfg.maintenance:
                     try:
                         maint = getattr(self.engine, "maintenance", None)
@@ -553,6 +556,8 @@ class ServeFrontend:
                             with self._cond:
                                 self.stats.n_maintenance += 1
                     except Exception as exc:  # noqa: BLE001
+                        with self._cond:
+                            self.stats.n_maintenance_faults += 1
                         obs.record_fault("frontend.maintenance", exc)
             finally:
                 tk.span.end()

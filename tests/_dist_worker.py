@@ -11,12 +11,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.dist.sharding import use_mesh as _use_mesh  # noqa: E402
+from repro.dist.sharding import make_mesh  # noqa: E402
 
 
 def scenario_forest_knn():
     from repro.core.distributed import build_forest, brute_force_knn, forest_knn
     from repro.core.metric import pairwise
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     X = np.random.default_rng(0).random((4000, 8)).astype(np.float32)
     Q = np.random.default_rng(1).random((16, 8)).astype(np.float32)
     forest, _ = build_forest(X, mesh, capacity=16)
@@ -33,7 +34,7 @@ def scenario_forest_knn():
 
 def scenario_forest_brute_matches_tree():
     from repro.core.distributed import build_forest, brute_force_knn, forest_knn
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     X = np.random.default_rng(3).random((2048, 16)).astype(np.float32)
     Q = np.random.default_rng(4).random((8, 16)).astype(np.float32)
     forest, _ = build_forest(X, mesh, capacity=16)
@@ -47,7 +48,7 @@ def scenario_forest_brute_matches_tree():
 
 def scenario_forest_delete():
     from repro.core.distributed import build_forest, forest_delete, forest_knn
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     X = np.random.default_rng(5).random((4096, 8)).astype(np.float32)
     forest, _ = build_forest(X, mesh, capacity=16)
     victims = np.arange(0, 256)
@@ -74,7 +75,7 @@ def scenario_forest_stream():
                                         forest_apply_mutations, forest_knn)
     from repro.core.metric import pairwise
     from repro.core.smtree import OP_DELETE, OP_INSERT, ST_APPLIED
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     rng = np.random.default_rng(9)
     X = rng.random((4096, 8)).astype(np.float32)
     forest, _ = build_forest(X, mesh, capacity=16)
@@ -114,7 +115,7 @@ def scenario_forest_device_splits():
     from repro.core.engine import SMTreeEngine
     from repro.core.smtree import OP_DELETE, OP_INSERT, ST_APPLIED
     from repro.stream import StreamingForest
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     rng = np.random.default_rng(17)
     X = rng.random((2048, 6)).astype(np.float32)
 
@@ -176,7 +177,7 @@ def scenario_forest_device_merges():
     from repro.core.smtree import OP_DELETE, OP_INSERT, ST_APPLIED
     from repro.core.smtree import packed_free_list
     from repro.stream import StreamingForest
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     rng = np.random.default_rng(23)
     X = rng.random((2048, 6)).astype(np.float32)
 
@@ -234,7 +235,7 @@ def scenario_forest_device_merges():
 def scenario_forest_knn_cohort_parity():
     """forest_knn static-height cohort path == per-query fallback."""
     from repro.core.distributed import build_forest, forest_knn
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     X = np.random.default_rng(12).random((2048, 8)).astype(np.float32)
     Q = np.random.default_rng(13).random((16, 8)).astype(np.float32)
     forest, _ = build_forest(X, mesh, capacity=16)
@@ -255,7 +256,7 @@ def scenario_forest_parent_prune_parity():
     bitwise identical to the unpruned collective — both via the explicit
     kwarg and via the REPRO_PARENT_PRUNE env toggle."""
     from repro.core.distributed import build_forest, forest_knn
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     X = np.random.default_rng(41).random((4096, 8)).astype(np.float32)
     # near-data queries (the regime where the filter actually fires)
     Q = (X[:32] + np.random.default_rng(42)
@@ -290,7 +291,7 @@ def scenario_replica_forest_mesh():
     from repro.core.smtree import OP_DELETE, OP_INSERT, ST_APPLIED
     from repro.stream import (Replica, StreamingForest, WriteAheadLog,
                               ledger_digest)
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     rng = np.random.default_rng(29)
     X = rng.random((2048, 8)).astype(np.float32)
     live = set(range(2048))
@@ -360,7 +361,7 @@ def scenario_promote_follower_mesh():
         def __call__(self):
             return self.t
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     rng = np.random.default_rng(31)
     X = rng.random((2048, 8)).astype(np.float32)
     vec = {i: X[i] for i in range(2048)}
@@ -422,7 +423,7 @@ def scenario_train_step_sharded():
 
     cfg = dataclasses.replace(smoke_config("qwen2.5-3b"), n_layers=2,
                               block_pattern=("attn",))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=64, global_batch=8)
     batch0 = synth_batch(dc, 0)
     inputs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
@@ -457,13 +458,13 @@ def scenario_elastic_reshard():
 
     cfg = smoke_config("qwen2.5-3b")
     params = M.init_params(cfg, jax.random.PRNGKey(7))
-    mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+    mesh_a = make_mesh((2, 4), ("data", "model"))
     spec_a = shd.param_pspecs(cfg, params, mesh_a)
     pa = jax.device_put(params, shd.to_named(spec_a, mesh_a))
     with tempfile.TemporaryDirectory() as d:
         save_checkpoint(d, 3, {"params": pa})
         for shape in [(1, 8), (4, 2)]:
-            mesh_b = jax.make_mesh(shape, ("data", "model"))
+            mesh_b = make_mesh(shape, ("data", "model"))
             spec_b = shd.param_pspecs(cfg, params, mesh_b)
             out, manifest = restore_checkpoint(
                 d, {"params": params},
@@ -481,11 +482,11 @@ def scenario_compressed_psum():
     from repro.dist.compression import compressed_psum_mean
     from repro.dist.sharding import shard_map
     import functools
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     g = np.random.default_rng(11).normal(size=(8, 4096)).astype(np.float32)
 
     @functools.partial(shard_map, mesh=mesh, in_specs=P("data"),
-                       out_specs=(P("data"), P("data")), check_rep=False)
+                       out_specs=(P("data"), P("data")))
     def run(gs):
         mean, err = compressed_psum_mean({"g": gs}, "data")
         return mean["g"], err["g"]
@@ -509,7 +510,7 @@ def scenario_moe_ep_equivalence():
     import dataclasses
     from repro.configs.all_archs import smoke_config
     from repro.models import moe as moe_mod
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = dataclasses.replace(smoke_config("grok-1-314b"),
                               n_experts=8, experts_per_token=2,
                               expert_pad_to=0, capacity_factor=64.0)
@@ -533,7 +534,7 @@ def scenario_forest_migration_mesh():
     from repro.core.distributed import build_forest_trees
     from repro.core.engine import SMTreeEngine
     from repro.stream import StreamingForest, collect_stats
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     rng = np.random.default_rng(23)
     X = rng.random((4096, 6)).astype(np.float32)
 

@@ -29,6 +29,7 @@ from repro.core.smtree import (MAX_HEIGHT, OP_DELETE, OP_INSERT, ST_APPLIED,
                                ST_NOP, ST_NOTFOUND, bulk_build, grow_tree,
                                needs_headroom, packed_free_list)
 from repro.data.datagen import clustered, uniform
+from repro.dist.sharding import make_mesh
 from repro.stream import StreamingEngine, StreamingForest
 from repro.stream.batcher import MutationBatcher
 
@@ -274,7 +275,7 @@ def test_forest_mesh_merges_match_host(seed):
     """Property: the mesh-resident StreamingForest (apply + split + merge
     collectives under shard_map) stays bitwise-equal to the host-centric
     batcher path on delete-heavy streams."""
-    mesh = jax.make_mesh((jax.device_count(),), ("model",))
+    mesh = make_mesh((jax.device_count(),), ("model",))
     if mesh.shape["model"] != 1:
         pytest.skip("main-process test assumes a single host device")
     rng = np.random.default_rng(seed)
@@ -371,7 +372,7 @@ def test_streaming_forest_growth_bitwise_across_modes(tmp_path):
     replay after a snapshot reproduces the grown geometry exactly."""
     from repro.dist.checkpoint import CheckpointManager
     from repro.stream import WriteAheadLog
-    mesh = jax.make_mesh((jax.device_count(),), ("model",))
+    mesh = make_mesh((jax.device_count(),), ("model",))
     if mesh.shape["model"] != 1:
         pytest.skip("main-process test assumes a single host device")
     X = clustered(100, dims=DIM, seed=11)

@@ -24,6 +24,7 @@ from hypothesis import strategies as st
 from repro.core import smtree
 from repro.core.engine import SMTreeEngine
 from repro.core.metric import pairwise
+from repro.dist.sharding import make_mesh
 from repro.core.smtree import (OP_DELETE, OP_INSERT, ST_APPLIED, ST_NOTFOUND,
                                bulk_build, empty_tree, packed_free_list)
 from repro.data.datagen import clustered, uniform
@@ -179,7 +180,7 @@ def test_forest_mesh_matches_host_and_reference(seed):
     device-split collective under shard_map) stays bitwise-equal to the
     host-centric batcher path, and both match brute force over the live
     set — exact queries, correct semantics vs the one-at-a-time log."""
-    mesh = jax.make_mesh((jax.device_count(),), ("model",))
+    mesh = make_mesh((jax.device_count(),), ("model",))
     if mesh.shape["model"] != 1:
         pytest.skip("main-process test assumes a single host device")
     rng = np.random.default_rng(seed)
@@ -239,7 +240,7 @@ def test_negative_oid_rejected_at_boundaries(tmp_path):
 
 def test_forest_apply_mutations_validate_flag():
     from repro.core.distributed import forest_apply_mutations, stack_trees
-    mesh = jax.make_mesh((jax.device_count(),), ("model",))
+    mesh = make_mesh((jax.device_count(),), ("model",))
     if mesh.shape["model"] != 1:
         pytest.skip("main-process test assumes a single host device")
     X = uniform(120, dims=DIM, seed=7)

@@ -8,7 +8,7 @@ from repro.kernels import ops, ref
 
 
 SHAPES = [(8, 8, 4), (128, 128, 128), (100, 130, 20), (1, 257, 96), (300, 7, 160)]
-METRICS = ["d_inf", "sqeuclidean", "ip"]
+METRICS = ["d_inf", "l2", "l1", "sqeuclidean", "ip"]
 
 
 @pytest.mark.parametrize("nq,ne,d", SHAPES)

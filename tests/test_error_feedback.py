@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.dist.sharding import make_mesh
 from repro.dist.compression import (compressed_mean_hook, compressed_psum_mean,
                                     init_ef_state)
 
@@ -61,12 +62,12 @@ def test_psum_mean_accepts_ef():
     from repro.dist.sharding import shard_map
     from jax.sharding import PartitionSpec as P
     import functools
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     g = np.linspace(-1, 1, 64).astype(np.float32)[None]
     ef0 = np.full((1, 64), 0.003, np.float32)
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
-                       out_specs=(P("data"), P("data")), check_rep=False)
+                       out_specs=(P("data"), P("data")))
     def run(gs, efs):
         mean, err = compressed_psum_mean({"g": gs}, "data", ef={"g": efs})
         return mean["g"], err["g"]
@@ -91,7 +92,7 @@ def test_train_step_ef_convergence_parity():
 
     cfg = dataclasses.replace(smoke_config("qwen2.5-3b"), n_layers=1,
                               block_pattern=("attn",))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=32, global_batch=4)
     batch0 = synth_batch(dc, 0)
     inputs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
