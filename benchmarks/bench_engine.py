@@ -58,6 +58,7 @@ PRUNE_METRICS = ["d_inf", "l2", "l1"]
 PRUNE_EVAL_TARGET = 0.75
 K = 10
 MAX_FRONTIER = 64
+SERVE_MESH_DEVICES = 4   # serve_sharded_*: a (2, 2) data x model mesh
 
 
 def _cohort_impl() -> str:
@@ -162,10 +163,10 @@ def _prune_matrix(report):
 
 
 def _serve_case(report):
-    """ROADMAP item: sharded serve (``--mesh host`` over the visible
-    devices) vs single-device decode, measured in ms/step.  Both run in
-    this process — a child process could not open a chip this process
-    already holds — with the driver's summary line parsed off stdout."""
+    """ROADMAP item: sharded serve (``--mesh host`` over 4 devices) vs
+    single-device decode, measured in ms/step.  Both run in this process —
+    a child process could not open a chip this process already holds —
+    with the driver's summary line parsed off stdout."""
     from repro.launch import serve
 
     steps = 4 if SMOKE else 8
@@ -173,6 +174,13 @@ def _serve_case(report):
             "--prompt-len", "4", "--steps", str(steps)]
 
     def run_case(name, argv):
+        if "--mesh" in argv and jax.device_count() != SERVE_MESH_DEVICES:
+            print(f"# serve case {name}: needs {SERVE_MESH_DEVICES} devices, "
+                  f"{jax.device_count()} visible (benchmarks/run.py forces "
+                  f"{SERVE_MESH_DEVICES} host devices on a CPU host)",
+                  flush=True)
+            report(name, float("nan"))
+            return float("nan")
         out = io.StringIO()
         try:
             with contextlib.redirect_stdout(out):

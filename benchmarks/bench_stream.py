@@ -53,6 +53,7 @@ else:
     N_LOOP = 1_024
 DIM = 10
 CAPACITY = 32
+MESH_SHARDS = 4   # the mesh-forest rows: one shard per device
 
 
 def _make_stream(rng, kind: str, n_ops: int, n_live: int, base_id: int):
@@ -234,9 +235,9 @@ def _time_loop(tree, ops, xs, oids) -> float:
 # the effect under test.
 def _mesh_forest_case(kind: str, n: int, n_ops: int):
     """Best-of-2 (device, host) ops/s of one mesh-forest workload, plus the
-    device leg's absorption counters.  Runs in this process, one shard per
-    visible device (up to 4): a child process could not open a chip this
-    process already holds."""
+    device leg's absorption counters.  Runs in this process (a child
+    process could not open a chip this process already holds), one shard
+    on each of MESH_SHARDS devices; fewer visible devices is an error."""
     import jax
 
     from repro.core import distributed as dist
@@ -244,9 +245,13 @@ def _mesh_forest_case(kind: str, n: int, n_ops: int):
     from repro.dist.sharding import make_mesh
     from repro.stream import StreamingForest
 
-    S = min(4, jax.device_count())
+    S = MESH_SHARDS
+    if jax.device_count() < S:
+        raise RuntimeError(f"needs {S} devices, {jax.device_count()} "
+                           f"visible (benchmarks/run.py forces {S} host "
+                           f"devices on a CPU host)")
     batch = 256
-    mesh = make_mesh((S,), ("model",))
+    mesh = make_mesh((S,), ("model",), devices=jax.devices()[:S])
     X = make_dataset("clustered", n, seed=7)[:, :10].copy()
     # insert streams need near-full leaves (split pressure) and free-ring
     # slack for sustained splits; delete streams need leaves near min-fill
@@ -322,8 +327,8 @@ def _mesh_forest_case(kind: str, n: int, n_ops: int):
 
 
 def _mesh_forest_rows(report):
-    """The tentpole measurements: a mesh-resident StreamingForest (one
-    shard per device, up to 4), device structure-edit collectives vs the
+    """The tentpole measurements: a mesh-resident 4-shard StreamingForest
+    (one shard per device), device structure-edit collectives vs the
     escalate-to-host path (which must unstack + restack the whole stacked
     forest around every host edit).  Two workloads: the PR-4 split-heavy
     insert stream, and the PR-5 delete-heavy mix (90% deletes) whose

@@ -18,6 +18,16 @@ if __package__ in (None, ""):   # script invocation: make repo root importable
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+# The mesh rows (bench_stream mesh_forest_*, bench_engine serve_sharded_*)
+# run over 4 devices, on a CPU host forced host devices.  Every suite runs
+# in this process, so the flag must be set before jax is imported; it sizes
+# only the host platform, so on an accelerator the device list is unchanged.
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS",
+                                                                 ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                               "--xla_force_host_platform_device_count=4"
+                               ).strip()
+
 # (suite name, module) — modules import lazily and individually so one
 # missing dependency (e.g. the distributed stack on a minimal single-host
 # CPU image) skips its suite instead of killing the whole entrypoint

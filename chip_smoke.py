@@ -47,8 +47,10 @@ ROOT = Path(__file__).resolve().parent
 sys.path.insert(0, str(ROOT / "src"))
 
 K, CAP, DIM, F = 10, 32, 128, 64
+N_CORPUS, N_QUERIES = 1_000_000, 768     # SIFT-1M: 1M base vectors
 # at F=64 a 128-d query's frontier is truncated (an approximate answer,
-# flagged ``overflow``); a wider frontier probe checks exact answers too
+# flagged ``overflow``); a wide-frontier probe, served at every epoch,
+# checks exact answers too
 F_EXACT, N_EXACT = 2048, 64
 WIDTH, SLO_MS = 1024, 5.0
 N_BATCHES, BATCH = 4, 4096
@@ -56,7 +58,9 @@ N_BATCHES, BATCH = 4, 4096
 # fresh neighbours (its leaf overflows), each delete anchor loses its 32
 # nearest live objects (its leaf underflows)
 PER_ANCHOR = 32
-TOL = 1e-5      # distance agreement; ids may differ only within it (ties)
+# distance agreement, relative above 1 (the reference sums in its own
+# order); ids may differ only between distances that agree (ties)
+TOL = 1e-5
 
 
 class CheckFailed(AssertionError):
@@ -76,16 +80,15 @@ def require(cond, msg: str) -> None:
 @functools.partial(jax.jit, static_argnames=("k", "chunk"))
 def _brute_knn(Q, X, live, *, k: int, chunk: int):
     """Exact kNN of Q [q, dim] over the live rows of X [n, dim] (n a
-    multiple of ``chunk``), in plain jax.numpy with the index's own f32 L2
-    (core/metric.py), scanning X in chunks with a running top-k."""
-    from repro.core.metric import get_metric
-    l2 = get_metric("l2")
+    multiple of ``chunk``): f32 L2 in plain jax.numpy, none of the index's
+    own code, scanning X in chunks with a running top-k."""
 
     def body(carry, c):
         best_d, best_i = carry
         xc = jax.lax.dynamic_slice_in_dim(X, c * chunk, chunk)
         lc = jax.lax.dynamic_slice_in_dim(live, c * chunk, chunk)
-        d = jnp.where(lc[None, :], l2(Q[:, None, :], xc[None, :, :]), jnp.inf)
+        d2 = jnp.sum(jnp.square(Q[:, None, :] - xc[None, :, :]), axis=-1)
+        d = jnp.where(lc[None, :], jnp.sqrt(d2), jnp.inf)
         ids = jnp.broadcast_to(c * chunk + jnp.arange(chunk, dtype=jnp.int32),
                                d.shape)
         neg, sel = jax.lax.top_k(-jnp.concatenate([best_d, d], 1), k)
@@ -130,14 +133,22 @@ class Reference:
         return np.concatenate(ds), np.concatenate(ids)
 
 
+def _l2_host(q, X):
+    """f32 L2 from q [dim] to the rows of X, in numpy."""
+    return np.sqrt(np.sum(np.square(q[None, :] - X), axis=-1))
+
+
+def _agree(a, b) -> bool:
+    return bool(np.all(np.abs(np.asarray(a) - np.asarray(b))
+                       <= TOL * np.maximum(1.0, np.abs(b))))
+
+
 def check_answers(name: str, Q, d, ids, overflow, ref: Reference, live,
                   k: int) -> dict:
     """Hold each answer to the exact kNN over ``live``.  Where ``overflow``
     is False the k distances must agree with brute force and the ids may
     differ only between equidistant objects; every answer's ids must be
     live and carry their true distance."""
-    from repro.core.metric import get_metric
-    l2 = get_metric("l2")
     ref_d, ref_i = ref.knn(Q, live, k)
     bad = []
     recall = []
@@ -145,15 +156,14 @@ def check_answers(name: str, Q, d, ids, overflow, ref: Reference, live,
         got_i, got_d = np.asarray(ids[r]), np.asarray(d[r])
         ok = (got_i >= 0).all() and live[got_i].all()
         if ok:
-            true = l2(Q[r][None, :], ref.host[got_i])
-            ok = np.allclose(got_d, true, rtol=0, atol=TOL)
+            ok = _agree(got_d, _l2_host(Q[r], ref.host[got_i]))
         recall.append(len(set(got_i) & set(ref_i[r])) / k)
         if ok and not overflow[r]:
-            ok = np.allclose(got_d, ref_d[r], rtol=0, atol=TOL)
+            ok = _agree(got_d, ref_d[r])
             for o in set(got_i) ^ set(ref_i[r]):
                 # a swapped id must be a tie at the k-th distance
-                do = l2(Q[r][None, :], ref.host[[o]])[0]
-                ok = ok and abs(do - ref_d[r][-1]) <= TOL
+                ok = ok and _agree(_l2_host(Q[r], ref.host[[o]])[0],
+                                   ref_d[r][-1])
         if not ok:
             bad.append(r)
     n_over = int(np.sum(overflow))
@@ -261,7 +271,7 @@ def index_phase(args, out: Path, dev) -> dict:
     from repro.stream import StreamingEngine, WriteAheadLog
 
     t0 = time.perf_counter()
-    pool, n, Q, batches = make_workload(args.n, args.queries, args.seed)
+    pool, n, Q, batches = make_workload(N_CORPUS, N_QUERIES, args.seed)
     log(f"index: workload {n} x {DIM} corpus, {len(Q)} queries, "
         f"{N_BATCHES} batches x {BATCH} ops in "
         f"{time.perf_counter() - t0:.1f}s")
@@ -282,28 +292,41 @@ def index_phase(args, out: Path, dev) -> dict:
     log(f"index: query compile + first cohort "
         f"{time.perf_counter() - t0:.1f}s (set-up)")
 
-    overflow: dict[bytes, bool] = {}
-
-    def knn_fn(pinned, q):
-        r = smtree.knn(pinned, q, k=K, max_frontier=F)
-        ov = np.asarray(r.overflow)
-        for row, flag in zip(q, ov):
-            overflow[row.tobytes()] = bool(flag)
-        return r.dists, r.ids
+    def recording_knn(f: int, overflow: dict[bytes, bool]):
+        """The front end's cohort call at max_frontier ``f``, recording
+        each query's ``overflow`` flag."""
+        def knn_fn(pinned, q):
+            r = smtree.knn(pinned, q, k=K, max_frontier=f)
+            for row, flag in zip(q, np.asarray(r.overflow)):
+                overflow[row.tobytes()] = bool(flag)
+            return r.dists, r.ids
+        return knn_fn
 
     wal_dir = out / "wal"
     shutil.rmtree(wal_dir, ignore_errors=True)
     eng = StreamingEngine(tree, wal=WriteAheadLog(str(wal_dir)),
                           max_batch=BATCH)
     del tree
-    cfg = FrontendConfig(cohort_width=WIDTH, slo_ms=SLO_MS, k=K,
-                         max_frontier=F)
+    overflow: dict[bytes, bool] = {}
+    fe = ServeFrontend(eng, FrontendConfig(cohort_width=WIDTH, slo_ms=SLO_MS,
+                                           k=K, max_frontier=F),
+                       knn_fn=recording_knn(F, overflow))
+    # the exact probe: a second front end on the same engine, one cohort
+    # of N_EXACT queries at max_frontier F_EXACT, served at every epoch
+    overflow_exact: dict[bytes, bool] = {}
+    fe_exact = ServeFrontend(eng, FrontendConfig(
+        cohort_width=N_EXACT, slo_ms=SLO_MS, k=K, max_frontier=F_EXACT,
+        maintenance=False), knn_fn=recording_knn(F_EXACT, overflow_exact))
     waves = np.array_split(np.arange(len(Q)), N_BATCHES + 1)
-    tickets, epochs_after, results = [], [], []
+    tickets, probes, epochs_after, results = [], [], [], []
     t0 = time.perf_counter()
-    with ServeFrontend(eng, cfg, knn_fn=knn_fn) as fe:
+    with fe, fe_exact:
         for w, rows in enumerate(waves):
             tickets += [(r, fe.submit(Q[r])) for r in rows]
+            # answered before the next batch is submitted: one probe
+            # cohort at each epoch
+            wave_probes = [(r, fe_exact.submit(Q[r])) for r in range(N_EXACT)]
+            probes += [(r, tk.result(900), tk.epoch) for r, tk in wave_probes]
             if w < N_BATCHES:
                 tb = time.perf_counter()
                 results.append(fe.submit_mutations(*batches[w]).result(900))
@@ -312,12 +335,13 @@ def index_phase(args, out: Path, dev) -> dict:
                 log(f"index: batch {w}: {time.perf_counter() - tb:.2f}s, "
                     f"splits {r.n_split}, merges {r.n_merge}, "
                     f"escalated {r.n_escalated}, epoch {epochs_after[-1]}")
-        answers = [(r, tk.result(900)) for r, tk in tickets]
-        epochs = np.asarray([tk.epoch for _, tk in tickets])
+        answers = [(r, tk.result(900), tk.epoch) for r, tk in tickets]
         stats = fe.stats.snapshot()
     log(f"index: served in {time.perf_counter() - t0:.1f}s: "
         f"{json.dumps(stats)}")
-    require(fe.stats.n_maintenance_faults == 0, "maintenance faulted")
+    for name, front in (("served", fe), ("exact probe", fe_exact)):
+        require(front.stats.n_maintenance_faults == 0,
+                f"{name} front end: maintenance faulted")
     require(sum(r.n_split for r in results) > 0, "no device split fired")
     require(sum(r.n_merge for r in results) > 0, "no device merge fired")
     from repro.core.smtree import ST_APPLIED
@@ -328,30 +352,39 @@ def index_phase(args, out: Path, dev) -> dict:
     # publish came at or before e
     lives = live_after(len(pool), n, batches)
     ref = Reference(pool)
-    totals = {"checked": 0, "mismatches": 0, "overflowed": 0}
-    for e in np.unique(epochs):
-        sel = np.nonzero(epochs == e)[0]
-        n_applied = int(np.sum(np.asarray(epochs_after) <= e))
-        rows = np.asarray([answers[i][0] for i in sel])
-        d = np.stack([answers[i][1][0] for i in sel])
-        ids = np.stack([answers[i][1][1] for i in sel])
-        ov = np.asarray([overflow[Q[r].tobytes()] for r in rows])
-        s = check_answers(f"index epoch {e} ({n_applied} batches applied)",
-                          Q[rows], d, ids, ov, ref, lives[n_applied], K)
-        for key in totals:
-            totals[key] += s[key]
-    require(totals["checked"] == len(Q), "not every ticket was checked")
 
-    # exact answers from the same kernel: a wide-frontier probe
-    _, final = eng.epochs.current()
-    r = smtree.knn(final, Q[:N_EXACT], k=K, max_frontier=F_EXACT)
-    probe = check_answers(f"index exact probe (max_frontier {F_EXACT})",
-                          Q[:N_EXACT], np.asarray(r.dists),
-                          np.asarray(r.ids), np.asarray(r.overflow), ref,
-                          lives[-1], K)
-    totals["exact_answers"] = probe["checked"] - probe["overflowed"]
+    def check_by_epoch(name, answered, flags) -> dict:
+        epochs = np.asarray([e for _, _, e in answered])
+        totals = {"checked": 0, "mismatches": 0, "overflowed": 0}
+        states = set()     # tree states seen, by batches applied
+        for e in np.unique(epochs):
+            sel = [answered[i] for i in np.nonzero(epochs == e)[0]]
+            n_applied = int(np.sum(np.asarray(epochs_after) <= e))
+            rows = np.asarray([r for r, _, _ in sel])
+            ov = np.asarray([flags[Q[r].tobytes()] for r in rows])
+            s = check_answers(
+                f"index {name}, epoch {e} ({n_applied} batches applied)",
+                Q[rows], np.stack([a[0] for _, a, _ in sel]),
+                np.stack([a[1] for _, a, _ in sel]), ov, ref,
+                lives[n_applied], K)
+            for key in ("checked", "mismatches", "overflowed"):
+                totals[key] += s[key]
+            states.add(n_applied)
+        require(totals["checked"] == len(answered),
+                f"{name}: not every ticket was checked")
+        totals["states"] = len(states)
+        return totals
+
+    totals = check_by_epoch(f"served (max_frontier {F})", answers, overflow)
+    exact = check_by_epoch(f"exact probe (max_frontier {F_EXACT})", probes,
+                           overflow_exact)
+    require(exact["states"] == N_BATCHES + 1 and exact["overflowed"] == 0,
+            f"the exact probe must answer exactly at each of the "
+            f"{N_BATCHES + 1} tree states: {exact}")
+    totals["exact_answers"] = exact["checked"]
 
     # the XLA path against the kernel, one b=256 cohort
+    _, final = eng.epochs.current()
     qb = Q[:256]
     rp = smtree.knn(final, qb, k=K, max_frontier=F, impl="pallas")
     rx = smtree.knn(final, qb, k=K, max_frontier=F, impl="xla")
@@ -371,40 +404,42 @@ def index_phase(args, out: Path, dev) -> dict:
 
 def knnlm_phase(serve_argv: list[str], dev) -> dict:
     """``repro.launch.serve.main`` in this process; every front-end
-    retrieval it makes is recorded and held to brute force."""
+    retrieval it makes is recorded and held to brute force over the keys
+    the datastore was built from (key i is oid i)."""
     from repro.launch import serve
     from repro.serve.frontend import ServeFrontend
+    from repro.serve.knnlm import KnnLmDatastore
 
-    calls = []
-    orig = ServeFrontend.knn
+    built, calls = [], []
+    orig_build, orig_knn = KnnLmDatastore.build, ServeFrontend.knn
+
+    def recording_build(self, keys, values):
+        built.append(np.array(keys, np.float32))
+        return orig_build(self, keys, values)
 
     def recording_knn(self, qs, timeout=60.0):
-        d, i = orig(self, qs, timeout)
+        d, i = orig_knn(self, qs, timeout)
         calls.append((self, self.engine.epochs.current(), np.array(qs), d, i))
         return d, i
 
-    ServeFrontend.knn = recording_knn
+    KnnLmDatastore.build, ServeFrontend.knn = recording_build, recording_knn
     t0 = time.perf_counter()
     try:
         serve.main(serve_argv)
     finally:
-        ServeFrontend.knn = orig
+        KnnLmDatastore.build, ServeFrontend.knn = orig_build, orig_knn
     log(f"knnlm: serve.main {time.perf_counter() - t0:.1f}s, "
         f"{len(calls)} retrieval calls")
+    require(len(built) == 1, f"{len(built)} datastore builds, expected 1")
     require(calls, "the decode made no retrieval")
     fe = calls[0][0]
     require(fe.stats.n_maintenance_faults == 0, "maintenance faulted")
     require(len({id(c[1][1]) for c in calls}) == 1,
             "the datastore changed during a read-only decode")
     (_, tree) = calls[0][1]
-    live = (np.asarray(tree.alive)[:, None] & np.asarray(tree.is_leaf)[:, None]
-            & np.asarray(tree.valid))
-    oids = np.asarray(tree.oid)[live]
-    keys = np.asarray(tree.vecs)[live]
-    require(np.array_equal(np.sort(oids), np.arange(len(oids))),
-            "datastore oids are not 0..n-1")
-    pool = np.zeros_like(keys)
-    pool[oids] = keys
+    pool = built[0]
+    require(int(tree.n_objects) == len(pool),
+            f"the datastore holds {int(tree.n_objects)} of {len(pool)} keys")
     # exact answers need every leaf to fit the frontier
     n_leaves = int(np.sum(np.asarray(tree.alive) & np.asarray(tree.is_leaf)))
     k = fe.cfg.k
@@ -413,7 +448,7 @@ def knnlm_phase(serve_argv: list[str], dev) -> dict:
     Q = np.concatenate([c[2] for c in calls])
     d = np.concatenate([c[3] for c in calls])
     i = np.concatenate([c[4] for c in calls])
-    stats = check_answers(f"knnlm ({len(oids)} keys, dim {pool.shape[1]})",
+    stats = check_answers(f"knnlm ({len(pool)} keys, dim {pool.shape[1]})",
                           Q, d, i, np.zeros(len(Q), bool), Reference(pool),
                           np.ones(len(pool), bool), k)
     check_no_faults("knnlm")
@@ -431,8 +466,8 @@ def forest_phase(args, out: Path, devices) -> dict:
     S = len(devices)
     mesh = make_mesh((S,), ("model",), devices=devices)
     t0 = time.perf_counter()
-    pool, n, Q, batches = make_workload(args.n, args.queries, args.seed)
-    trees = dist.build_forest_trees(pool[:n], S, capacity=CAP, metric="l2",
+    pool, n, Q, batches = make_workload(N_CORPUS, N_QUERIES, args.seed)
+    trees =dist.build_forest_trees(pool[:n], S, capacity=CAP, metric="l2",
                                     seed=args.seed)
     log(f"forest: {S} shards of {n} x {DIM} built in "
         f"{time.perf_counter() - t0:.1f}s (set-up)")
@@ -498,9 +533,6 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1, choices=[1, 4],
                     help="4: run only the mesh-resident forest phase")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--n", type=int, default=1_000_000,
-                    help="corpus size (SIFT-1M: 1,000,000)")
-    ap.add_argument("--queries", type=int, default=768)
     ap.add_argument("--out", default=str(ROOT / "chiprun_out" / "chip_smoke"))
     args = ap.parse_args(argv)
 
