@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.metric import get_metric
 
 MAX_HEIGHT = 16          # supports capacity^15 objects; plenty
@@ -57,6 +58,10 @@ _EPS = 1e-5
 # scored (see _knn_cohort).  Purely a schedule knob — results are exact
 # kNN for any value >= 1.
 _LEAF_CHUNKS = 4
+# named scope of the descent's top-k compactions (the per-level frontier
+# cut and the leaf-chunk merges): it lands in the ops' op_name metadata,
+# so a profile can total their device time
+COMPACT_SCOPE = "descent.compact"
 
 
 # --------------------------------------------------------------------------
@@ -432,7 +437,8 @@ def _query(tree: TreeArrays, queries: jax.Array, k: int, F: int, r_cap,
         height = int(static_height)
     else:
         try:
-            height = int(tree.height)
+            with obs.child_span("descent.height_read"):
+                height = int(tree.height)
         except jax.errors.ConcretizationTypeError:
             res = _knn_perquery(tree, queries, k, F, r_cap)
             return (res, None) if level_stats else res
@@ -440,6 +446,16 @@ def _query(tree: TreeArrays, queries: jax.Array, k: int, F: int, r_cap,
     return _knn_cohort(tree, queries, r_cap, k=k, F=F, height=height,
                        impl=impl, interpret=interpret,
                        level_stats=level_stats, prune=parent_prune)
+
+
+def level_widths(height: int, capacity: int, F: int) -> list[int]:
+    """Frontier width of each level of the cohort descent: ``w(0)=1,
+    w(l+1)=min(F, w(l)*capacity)``.  A query row's kernel grid is
+    ``sum(widths)`` slots whatever the descent prunes."""
+    widths = [1]
+    for _ in range(height - 1):
+        widths.append(min(F, widths[-1] * capacity))
+    return widths
 
 
 @functools.partial(jax.jit,
@@ -501,9 +517,7 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
     cap = tree.capacity
     r_cap = jnp.broadcast_to(jnp.asarray(r_cap, jnp.float32), (b,))
 
-    widths = [1]
-    for _ in range(height - 1):
-        widths.append(min(F, widths[-1] * cap))
+    widths = level_widths(height, cap, F)
 
     internal_valid = tree.valid & ~tree.is_leaf[:, None]
     leaf_valid = tree.valid & tree.is_leaf[:, None]
@@ -606,25 +620,26 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
                 pruned_levels.append(jnp.sum(
                     jnp.isfinite(score) & ~imask,
                     axis=1, dtype=jnp.int32))
-            sc = jnp.where(imask, score, _INF)
-            childs = tree.child[nodes].reshape(b, w * cap)
-            w_out = widths[lvl + 1]
-            neg_s, order = jax.lax.top_k(-sc, w_out)
-            sel_ok = -neg_s < _INF
-            frontier = jnp.where(
-                sel_ok, jnp.take_along_axis(childs, order, axis=1), -1)
-            overflow |= jnp.sum(imask, axis=1) > w_out
-            # carry d(q, routing object) of each admitted entry: it is
-            # the next level's d(q, parent), and the child's pdist was
-            # computed against this exact routing object.  Selected
-            # slots always came through imask, so their dq is finite.
-            # Carried even with the filter off — the pre-eval upper
-            # bound above consumes it in both traces.
-            qpd = jnp.where(
-                sel_ok,
-                jnp.take_along_axis(dq.reshape(b, w * cap), order,
-                                    axis=1),
-                _INF)
+            with jax.named_scope(COMPACT_SCOPE):
+                sc = jnp.where(imask, score, _INF)
+                childs = tree.child[nodes].reshape(b, w * cap)
+                w_out = widths[lvl + 1]
+                neg_s, order = jax.lax.top_k(-sc, w_out)
+                sel_ok = -neg_s < _INF
+                frontier = jnp.where(
+                    sel_ok, jnp.take_along_axis(childs, order, axis=1), -1)
+                overflow |= jnp.sum(imask, axis=1) > w_out
+                # carry d(q, routing object) of each admitted entry: it
+                # is the next level's d(q, parent), and the child's pdist
+                # was computed against this exact routing object.
+                # Selected slots always came through imask, so their dq
+                # is finite.  Carried even with the filter off — the
+                # pre-eval upper bound above consumes it in both traces.
+                qpd = jnp.where(
+                    sel_ok,
+                    jnp.take_along_axis(dq.reshape(b, w * cap), order,
+                                        axis=1),
+                    _INF)
         else:
             # --- leaf level: merge candidates into the running top-k,
             # chunked over the (score-sorted) frontier so each chunk's
@@ -657,15 +672,16 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
                     evalid = tree.valid[nodes_c] & (fr_c >= 0)[:, :, None]
                     parent_acc += jnp.sum(
                         evalid, axis=(1, 2), dtype=jnp.int32) - n_eval
-                leaf_d = leaf_d.reshape(b, wc * cap)
-                cd = jnp.where(leaf_d <= r_q[:, None], leaf_d, _INF)
-                eoid = tree.oid[nodes_c].reshape(b, wc * cap)
-                ci = jnp.where(cd < _INF, eoid, -1)
-                all_d = jnp.concatenate([topk_d, cd], axis=1)
-                all_i = jnp.concatenate([topk_i, ci], axis=1)
-                neg, sel = jax.lax.top_k(-all_d, k)
-                topk_d = -neg
-                topk_i = jnp.take_along_axis(all_i, sel, axis=1)
+                with jax.named_scope(COMPACT_SCOPE):
+                    leaf_d = leaf_d.reshape(b, wc * cap)
+                    cd = jnp.where(leaf_d <= r_q[:, None], leaf_d, _INF)
+                    eoid = tree.oid[nodes_c].reshape(b, wc * cap)
+                    ci = jnp.where(cd < _INF, eoid, -1)
+                    all_d = jnp.concatenate([topk_d, cd], axis=1)
+                    all_i = jnp.concatenate([topk_i, ci], axis=1)
+                    neg, sel = jax.lax.top_k(-all_d, k)
+                    topk_d = -neg
+                    topk_i = jnp.take_along_axis(all_i, sel, axis=1)
             if level_stats:
                 parent_levels.append(parent_acc)
 
@@ -1172,29 +1188,40 @@ def apply_mutations(tree: TreeArrays, ops, xs, oids, *,
     ops = jnp.asarray(ops, jnp.int32)
     xs = jnp.asarray(xs, jnp.float32)
     oids = jnp.asarray(oids, jnp.int32)
-    tree, status = _apply_mutations_jit(donate)(tree, ops, xs, oids)
-    if splits or merges:
+    with obs.child_span("mutation.scan"):
+        tree, status = _apply_mutations_jit(donate)(tree, ops, xs, oids)
+        if not (splits or merges):
+            return tree, status
         try:
             st_host = np.asarray(status)
         except (jax.errors.ConcretizationTypeError,
                 jax.errors.TracerArrayConversionError):
             return tree, status
-        dirty = 0
-        # the post-scan tree is an exclusively-owned intermediate (callers
-        # only ever see the final return), so the split/merge chain can
-        # donate its buffers even where the scan itself must not (the scan
-        # input is the caller's live tree, typically pinned by an epoch)
-        if splits:
-            tree, st_host, n_split = resolve_overflows(
-                tree, ops, xs, oids, st_host, donate=True)
-            dirty += n_split
-        if merges and not (st_host == ST_OVERFLOW).any():
-            tree, st_host, n_merge = resolve_underflows(
-                tree, ops, oids, st_host, donate=True)
-            dirty += n_merge
-        if dirty:
-            status = jnp.asarray(st_host)
+        count_host_sync()
+    dirty = 0
+    # the post-scan tree is an exclusively-owned intermediate (callers
+    # only ever see the final return), so the split/merge chain can
+    # donate its buffers even where the scan itself must not (the scan
+    # input is the caller's live tree, typically pinned by an epoch)
+    if splits:
+        tree, st_host, n_split = resolve_overflows(
+            tree, ops, xs, oids, st_host, donate=True)
+        dirty += n_split
+    if merges and not (st_host == ST_OVERFLOW).any():
+        tree, st_host, n_merge = resolve_underflows(
+            tree, ops, oids, st_host, donate=True)
+        dirty += n_merge
+    if dirty:
+        status = jnp.asarray(st_host)
     return tree, status
+
+
+def count_host_sync() -> None:
+    """Count one device-to-host status or scalar read on the mutation
+    path (``mutation.host_syncs_total``): each waits for every program
+    queued on the device before it, query cohorts included."""
+    if obs.enabled():
+        obs.counter("mutation.host_syncs_total").inc()
 
 
 # --------------------------------------------------------------------------
@@ -1639,29 +1666,33 @@ def resolve_overflows(tree: TreeArrays, ops, xs, oids, statuses, *,
     statuses = np.asarray(statuses)
     ops_np = np.asarray(ops)
     idx = np.nonzero((statuses == ST_OVERFLOW) & (ops_np == OP_INSERT))[0]
-    if not len(idx):
-        return tree, statuses, 0
-    xs_np = np.asarray(xs, np.float32)
-    oids_np = np.asarray(oids, np.int32)
-    out = statuses.copy()
-    n_resolved = 0
-    c0 = 0
-    for w in split_chunks(len(idx)):
-        chunk = idx[c0:c0 + w]
-        c0 += w
-        k = len(chunk)
-        ops_k = np.full(w, OP_NOP, np.int32)
-        ops_k[:k] = OP_INSERT
-        xs_k = np.zeros((w, xs_np.shape[1]), np.float32)
-        xs_k[:k] = xs_np[chunk]
-        oids_k = np.full(w, -1, np.int32)
-        oids_k[:k] = oids_np[chunk]
-        tree, st = apply_splits(tree, ops_k, xs_k, oids_k, donate=donate)
-        st = np.asarray(jax.device_get(st))[:k]
-        out[chunk[st == ST_SPLIT]] = ST_SPLIT
-        n_resolved += int((st == ST_SPLIT).sum())
-        if (st == ST_OVERFLOW).any():
-            break   # blocked: the rest goes to the host in log order
+    with obs.child_span("mutation.split_pass", rows=len(idx)):
+        if not len(idx):
+            return tree, statuses, 0
+        xs_np = np.asarray(xs, np.float32)
+        oids_np = np.asarray(oids, np.int32)
+        out = statuses.copy()
+        n_resolved = 0
+        c0 = 0
+        for w in split_chunks(len(idx)):
+            chunk = idx[c0:c0 + w]
+            c0 += w
+            k = len(chunk)
+            with obs.child_span("mutation.split_chunk"):
+                ops_k = np.full(w, OP_NOP, np.int32)
+                ops_k[:k] = OP_INSERT
+                xs_k = np.zeros((w, xs_np.shape[1]), np.float32)
+                xs_k[:k] = xs_np[chunk]
+                oids_k = np.full(w, -1, np.int32)
+                oids_k[:k] = oids_np[chunk]
+                tree, st = apply_splits(tree, ops_k, xs_k, oids_k,
+                                        donate=donate)
+                st = np.asarray(jax.device_get(st))[:k]
+                count_host_sync()
+            out[chunk[st == ST_SPLIT]] = ST_SPLIT
+            n_resolved += int((st == ST_SPLIT).sum())
+            if (st == ST_OVERFLOW).any():
+                break   # blocked: the rest goes to the host in log order
     return tree, out, n_resolved
 
 
@@ -1979,29 +2010,31 @@ def resolve_underflows(tree: TreeArrays, ops, oids, statuses, *,
     statuses = np.asarray(statuses)
     ops_np = np.asarray(ops)
     idx = np.nonzero((statuses == ST_UNDERFLOW) & (ops_np == OP_DELETE))[0]
-    if not len(idx):
-        return tree, statuses, 0
-    oids_np = np.asarray(oids, np.int32)
-    out = statuses.copy()
-    c0 = 0
-    pending = []
-    # dispatch every chunk back-to-back and sync the statuses once at the
-    # end: merges never block (unlike the split ladder, which must stop at
-    # the first blocked chunk), so there is no decision to make between
-    # chunks and no reason to stall the dispatch queue on a host
-    # round-trip per chunk
-    for w in merge_chunks(len(idx)):
-        chunk = idx[c0:c0 + w]
-        c0 += w
-        k = len(chunk)
-        ops_k = np.full(w, OP_NOP, np.int32)
-        ops_k[:k] = OP_DELETE
-        oids_k = np.full(w, -1, np.int32)
-        oids_k[:k] = oids_np[chunk]
-        tree, st = apply_merges(tree, ops_k, oids_k, donate=donate)
-        pending.append((chunk, k, st))
-    for chunk, k, st in pending:
-        out[chunk] = np.asarray(jax.device_get(st))[:k]
+    with obs.child_span("mutation.merge_pass", rows=len(idx)):
+        if not len(idx):
+            return tree, statuses, 0
+        oids_np = np.asarray(oids, np.int32)
+        out = statuses.copy()
+        c0 = 0
+        pending = []
+        # dispatch every chunk back-to-back and sync the statuses once at
+        # the end: merges never block (unlike the split ladder, which must
+        # stop at the first blocked chunk), so there is no decision to
+        # make between chunks and no reason to stall the dispatch queue
+        # on a host round-trip per chunk
+        for w in merge_chunks(len(idx)):
+            chunk = idx[c0:c0 + w]
+            c0 += w
+            k = len(chunk)
+            ops_k = np.full(w, OP_NOP, np.int32)
+            ops_k[:k] = OP_DELETE
+            oids_k = np.full(w, -1, np.int32)
+            oids_k[:k] = oids_np[chunk]
+            tree, st = apply_merges(tree, ops_k, oids_k, donate=donate)
+            pending.append((chunk, k, st))
+        for chunk, k, st in pending:
+            out[chunk] = np.asarray(jax.device_get(st))[:k]
+            count_host_sync()
     return tree, out, len(idx)
 
 
@@ -2015,7 +2048,9 @@ def needs_headroom(tree: TreeArrays, *, frac: float = 1 / 16) -> bool:
     — the worst case a *single* overflow row can allocate — so growth
     always fires before a row can block.  Syncs one scalar."""
     wm = max(MAX_HEIGHT + 1, int(tree.max_nodes * frac))
-    return int(jax.device_get(tree.free_head)) < wm
+    free_head = int(jax.device_get(tree.free_head))
+    count_host_sync()
+    return free_head < wm
 
 
 def grow_tree(tree: TreeArrays, *, factor: int = 2) -> TreeArrays:

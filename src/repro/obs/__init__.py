@@ -41,15 +41,15 @@ from . import export, recorder, registry, trace
 from .recorder import FlightRecorder
 from .registry import (DEFAULT_BUCKETS, LATENCY_BUCKETS_S, Counter, Gauge,
                        Histogram, Registry, _Gate)
-from .trace import (NULL_SPAN, Span, SpanCtx, assemble_trace, current_ctx,
-                    new_trace_id, sample_root, span, start_span,
-                    trace_connected)
+from .trace import (NULL_SPAN, Span, SpanCtx, assemble_trace, child_span,
+                    current_ctx, new_trace_id, sample_root, span,
+                    start_span, trace_connected)
 
 __all__ = [
     "REGISTRY", "RECORDER",
     "enabled", "enable", "disable", "reset",
     "counter", "gauge", "histogram",
-    "span", "start_span", "current_ctx", "new_trace_id", "sample_root",
+    "span", "child_span", "start_span", "current_ctx", "new_trace_id", "sample_root",
     "record_event", "record_fault",
     "observe_query_result", "want_level_stats", "LEVEL_STATS_EVERY",
     "set_trace_sampling", "TRACE_SAMPLE_EVERY",
@@ -158,11 +158,21 @@ def want_level_stats() -> bool:
     return next(_level_stats_n) % LEVEL_STATS_EVERY == 0
 
 
-def observe_query_result(res, pruned=None, *, prefix: str = "descent") -> None:
+def observe_query_result(res, pruned=None, *, prefix: str = "descent",
+                         rows: int | None = None,
+                         widths=None) -> None:
     """Accumulate the descent's per-dispatch reductions into paper-level
     counters: metric (distance) evaluations, nodes visited, and — when
     the kernel was asked for level stats — pruned-by-bound and
     pruned-by-parent per level.
+
+    ``rows`` counts only the first ``rows`` rows of the result: a front
+    end pads its cohort to a fixed width, and pad rows are not queries.
+    ``widths``, the descent's per-level frontier widths
+    (``smtree.level_widths``), adds ``{prefix}.grid_slots_total``: rows x
+    sum(widths), the frontier slots the descent's grid scores whatever
+    it prunes; ``nodes_visited_total`` over it is the share that held a
+    live node.
 
     ``pruned`` is what ``smtree.knn(..., level_stats=True)`` returned:
     a ``(by_bound, by_parent)`` pair of ``[levels, b]`` stacks (a bare
@@ -180,13 +190,16 @@ def observe_query_result(res, pruned=None, *, prefix: str = "descent") -> None:
     entry that should only ever compile with obs on."""
     if not _GATE.on:
         return
-    b = int(np.asarray(res.dists).shape[0])
-    dist_evals = int(np.sum(np.asarray(res.dist_evals)))
-    nodes = int(np.sum(np.asarray(res.page_hits)))
-    overflow = int(np.sum(np.asarray(res.overflow)))
+    b = int(np.asarray(res.dists).shape[0]) if rows is None else int(rows)
+    dist_evals = int(np.sum(np.asarray(res.dist_evals)[:b]))
+    nodes = int(np.sum(np.asarray(res.page_hits)[:b]))
+    overflow = int(np.sum(np.asarray(res.overflow)[:b]))
     REGISTRY.counter(f"{prefix}.queries_total").inc(b)
     REGISTRY.counter(f"{prefix}.dist_evals_total").inc(dist_evals)
     REGISTRY.counter(f"{prefix}.nodes_visited_total").inc(nodes)
+    if widths is not None:
+        REGISTRY.counter(f"{prefix}.grid_slots_total").inc(
+            b * int(sum(widths)))
     if overflow:
         REGISTRY.counter(f"{prefix}.frontier_overflow_total").inc(overflow)
     if pruned is None:
@@ -197,7 +210,7 @@ def observe_query_result(res, pruned=None, *, prefix: str = "descent") -> None:
                         (by_parent, "pruned_by_parent")):
         if stack is None:
             continue
-        p = np.asarray(stack)           # [levels, b]
+        p = np.asarray(stack)[:, :b]    # [levels, b]
         REGISTRY.counter(f"{prefix}.{kind}_total").inc(int(p.sum()))
         for lvl in range(p.shape[0]):
             REGISTRY.counter(

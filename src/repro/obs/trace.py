@@ -16,9 +16,17 @@ not fan-out: the cohort span is parented on one member ticket and
 carries ``links`` — the trace_ids of every other member — so each
 ticket's trace still reaches the shared device-compute span.
 
-Disabled path: :func:`span` returns a shared no-op context manager and
-:func:`start_span` returns a shared ``_NullSpan``; neither allocates,
-takes a time reading, or touches the recorder.
+Profiler mirror: with tracing on, a span that starts and ends on one
+thread also opens and closes a ``jax.profiler.TraceAnnotation`` of the
+same name, so while the JAX profiler records, the program's steps sit on
+the trace's host plane, on the trace's own clock, one line per thread.
+A span that ends on another thread than it started on (the query and
+mutation ticket roots) is opened with ``mirror=False`` and stays in the
+ring only: a profiler event belongs to the thread that closes it.
+
+Disabled path: :func:`span`, :func:`child_span` and :func:`start_span`
+return the shared no-op ``NULL_SPAN``; none allocates, takes a time
+reading, enters an annotation, or touches the recorder.
 
 Head sampling: span creation is the dominant obs cost on the serving
 hot path (a cohort of 64 tickets is 64 root spans), so high-rate roots
@@ -37,12 +45,15 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Span",
     "SpanCtx",
     "new_trace_id",
     "start_span",
     "span",
+    "child_span",
     "current_ctx",
     "assemble_trace",
     "trace_connected",
@@ -95,19 +106,23 @@ class SpanCtx:
 
 class Span:
     __slots__ = ("trace_id", "span_id", "parent_id", "name",
-                 "t_start", "t_end", "attrs", "links", "_done")
+                 "t_start", "t_end", "attrs", "links", "_done", "_mirror")
 
     def __init__(self, name: str, trace_id: str, parent_id: str | None,
-                 links=(), attrs: dict | None = None):
+                 links=(), attrs: dict | None = None, mirror: bool = True):
         self.name = name
         self.trace_id = trace_id
         self.span_id = _new_span_id()
         self.parent_id = parent_id
         self.links = tuple(links)
         self.attrs = dict(attrs) if attrs else {}
+        self._done = False
+        self._mirror = None
+        if mirror:
+            self._mirror = TraceAnnotation(name)
+            self._mirror.__enter__()
         self.t_start = time.monotonic()
         self.t_end = None
-        self._done = False
 
     @property
     def ctx(self) -> SpanCtx:
@@ -128,6 +143,8 @@ class Span:
         if attrs:
             self.attrs.update(attrs)
         self.t_end = time.monotonic()
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
         sink = GATE.sink
         if sink is not None:
             sink(self)
@@ -197,9 +214,11 @@ def sample_root() -> bool:
 
 def start_span(name: str, *, parent: SpanCtx | None = None,
                trace_id: str | None = None, links=(), sampled: bool = False,
-               **attrs):
+               mirror: bool = True, **attrs):
     """Open a span (caller must ``end()`` it).  Parent resolution:
     explicit ``parent`` ctx > thread-local current span > new root.
+    ``mirror=False`` keeps a span that another thread will end out of
+    the profiler's trace (module docstring).
 
     ``sampled=True`` marks a high-rate root: when the span *would* start
     a new trace (no parent, no explicit trace_id), only 1 in
@@ -220,7 +239,7 @@ def start_span(name: str, *, parent: SpanCtx | None = None,
     else:
         tid = trace_id if trace_id is not None else new_trace_id()
         pid = None
-    return Span(name, tid, pid, links=links, attrs=attrs)
+    return Span(name, tid, pid, links=links, attrs=attrs, mirror=mirror)
 
 
 class _ActiveSpan:
@@ -254,6 +273,20 @@ def span(name: str, *, parent: SpanCtx | None = None,
         return NULL_SPAN
     return _ActiveSpan(start_span(name, parent=parent, trace_id=trace_id,
                                   links=links, **attrs))
+
+
+def child_span(name: str, **attrs):
+    """Like :func:`span`, but only under a span the calling thread has
+    open; otherwise the shared no-op manager.  For deep callees (the
+    descent's height read, the mutation passes) that run both inside
+    traced work and on their own, where a root of their own would only
+    be noise."""
+    if not GATE.on:
+        return NULL_SPAN
+    cur = getattr(_tls, "current", None)
+    if cur is None:
+        return NULL_SPAN
+    return _ActiveSpan(Span(name, cur.trace_id, cur.span_id, attrs=attrs))
 
 
 # ---------------------------------------------------------------- analysis

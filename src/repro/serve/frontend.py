@@ -37,6 +37,7 @@ import dataclasses
 import threading
 import time
 
+import jax
 import numpy as np
 
 from repro import obs
@@ -77,36 +78,67 @@ class FrontendConfig:
     maintenance: bool = True
 
 
+# The live rows of the cohort the dispatcher thread is serving: it pads a
+# cohort to its width, and pad rows are not queries.  Set around the
+# ``knn_fn`` call, so ``pinned_knn`` keeps the (pinned, queries, *, k,
+# max_frontier) form every ``knn_fn`` has.
+_cohort = threading.local()
+
+
 def pinned_knn(pinned, queries: np.ndarray, *, k: int, max_frontier: int):
     """kNN over one pinned epoch: a single tree, or a tuple of forest
     shards (per-shard cohort descent + host top-k merge — the forest read
-    path, shared here so the front-end serves both layouts).
+    path, shared here so the front-end serves both layouts).  Called
+    from the dispatcher, only the cohort's live rows count as queries.
 
     With observability on, a 1/``obs.LEVEL_STATS_EVERY`` sample of
     dispatches runs the level-stats descent variant (a separate jit
     cache entry — default geometry untouched) and accumulates the paper
-    counters: queries, distance evals, nodes visited, pruned-by-bound
-    per level.  Sampling the whole counter path — denominator included —
-    keeps per-query averages unbiased while the other 15/16 dispatches
-    pay nothing (no device fetches for the reduction arrays)."""
+    counters: queries, distance evals, nodes visited, grid slots,
+    pruned-by-bound per level.  Sampling the whole counter path —
+    denominator included — keeps per-query averages unbiased while the
+    other 15/16 dispatches pay nothing (no device fetches for the
+    reduction arrays).
+
+    Under an open span (the front end's ``frontend.device_compute``) the
+    call is three children: ``frontend.dispatch`` (every shard's descent
+    enqueued), ``frontend.device_wait`` (a wait for the device, issued
+    only with observability on) and ``frontend.fetch`` (the results
+    copied to the host and merged)."""
     if not isinstance(pinned, (tuple, list)):
         pinned = (pinned,)
     on = obs.enabled()
-    ds, ids = [], []
-    for t in pinned:
-        if on and obs.want_level_stats():
-            res, pruned = smtree.knn(t, queries, k=k,
-                                     max_frontier=max_frontier,
-                                     level_stats=True)
-            obs.observe_query_result(res, pruned)
-        else:
-            res = smtree.knn(t, queries, k=k, max_frontier=max_frontier)
-        ds.append(np.asarray(res.dists))
-        ids.append(np.asarray(res.ids))
-    d = np.concatenate(ds, axis=1)
-    i = np.concatenate(ids, axis=1)
-    order = np.argsort(d, axis=1, kind="stable")[:, :k]
-    return np.take_along_axis(d, order, 1), np.take_along_axis(i, order, 1)
+    rows = getattr(_cohort, "rows", None)
+    out = []            # (result, level stats or None, sampled, tree)
+    with obs.child_span("frontend.dispatch"):
+        for t in pinned:
+            if on and obs.want_level_stats():
+                res, pruned = smtree.knn(t, queries, k=k,
+                                         max_frontier=max_frontier,
+                                         level_stats=True)
+                out.append((res, pruned, True, t))
+            else:
+                res = smtree.knn(t, queries, k=k, max_frontier=max_frontier)
+                out.append((res, None, False, t))
+    if on:
+        with obs.child_span("frontend.device_wait"):
+            jax.block_until_ready([res for res, *_ in out])
+    with obs.child_span("frontend.fetch"):
+        ds, ids = [], []
+        for res, pruned, sampled, t in out:
+            ds.append(np.asarray(res.dists))
+            ids.append(np.asarray(res.ids))
+            if sampled:
+                # the cohort descent's by-parent stack has one row a level
+                widths = (None if pruned is None else smtree.level_widths(
+                    pruned[1].shape[0], t.capacity, max_frontier))
+                obs.observe_query_result(res, pruned, rows=rows,
+                                         widths=widths)
+        d = np.concatenate(ds, axis=1)
+        i = np.concatenate(ids, axis=1)
+        order = np.argsort(d, axis=1, kind="stable")[:, :k]
+        return (np.take_along_axis(d, order, 1),
+                np.take_along_axis(i, order, 1))
 
 
 class QueryTicket:
@@ -130,9 +162,12 @@ class QueryTicket:
         self.err = None
         # sample_root() decides head sampling without the start_span
         # kwargs call — the unsampled majority of tickets pays one
-        # cheap predicate, not a span-construction attempt
+        # cheap predicate, not a span-construction attempt.  The span
+        # ends on the dispatcher thread, so it stays out of the profiler
+        # trace (mirror=False).
         if trace_ctx is not None or obs.sample_root():
-            self.span = obs.start_span("frontend.query", parent=trace_ctx)
+            self.span = obs.start_span("frontend.query", parent=trace_ctx,
+                                       mirror=False)
         else:
             self.span = obs.NULL_SPAN
         self._event = threading.Event()
@@ -165,7 +200,8 @@ class MutationTicket:
         self.ops, self.xs, self.oids = ops, xs, oids
         self.res = None
         self.err = None
-        self.span = obs.start_span("frontend.mutation", parent=trace_ctx)
+        self.span = obs.start_span("frontend.mutation", parent=trace_ctx,
+                                   mirror=False)
         self._event = threading.Event()
 
     @property
@@ -437,7 +473,8 @@ class ServeFrontend:
         W = self.cfg.cohort_width
         slo_s = self.cfg.slo_ms / 1e3
         while True:
-            with self._cond:
+            # the thread's time is all in this span or in the cohort's
+            with obs.span("frontend.assemble"), self._cond:
                 while not self._queue and self._running:
                     self._cond.wait(0.05)
                 if not self._queue:
@@ -460,37 +497,35 @@ class ServeFrontend:
     def _run_cohort(self, batch: list[QueryTicket], *, full: bool) -> None:
         W = self.cfg.cohort_width
         n = len(batch)
-        # Cohort fan-in: the cohort span parents on the first *traced*
-        # member ticket and *links* every other traced member's
-        # trace_id, so each sampled ticket's trace reaches the shared
-        # pin/compute spans.  Head sampling means most tickets carry
-        # NULL_SPAN; a cohort with no traced member skips the cohort-
-        # side spans entirely.
+        # Every cohort gets its dispatcher-side spans while observability
+        # is on (a cohort is low-rate).  Cohort fan-in: the cohort span
+        # parents on the first *traced* member ticket, if any, and
+        # *links* every other traced member's trace_id, so each sampled
+        # ticket's trace reaches the shared pin/compute spans.  The span
+        # covers the cohort's bookkeeping too, up to its publish.
         cspan = obs.NULL_SPAN
         if obs.enabled():
             members = [tk for tk in batch if tk.span is not obs.NULL_SPAN]
-            if members:
-                cspan = obs.start_span(
-                    "frontend.cohort", parent=members[0].span.ctx,
-                    links=tuple(tk.span.trace_id for tk in members[1:]),
-                    fill=n, width=W, full=full)
-        traced = cspan is not obs.NULL_SPAN
+            cspan = obs.start_span(
+                "frontend.cohort",
+                parent=members[0].span.ctx if members else None,
+                links=tuple(tk.span.trace_id for tk in members[1:]),
+                fill=n, width=W, full=full)
         try:
             dim = batch[0].q.shape[-1]
             Q = np.zeros((W, dim), np.float32)   # pad-to-width: one geometry
             for r, tk in enumerate(batch):
                 Q[r] = tk.q
-            pin = (obs.start_span("frontend.epoch_pin", parent=cspan.ctx)
-                   if traced else obs.NULL_SPAN)
+            pin = obs.start_span("frontend.epoch_pin", parent=cspan.ctx)
             with self.engine.epochs.reading(with_epoch=True) as (e, pinned):
                 pin.end(epoch=e)
-                comp = (obs.start_span("frontend.device_compute",
-                                       parent=cspan.ctx)
-                        if traced else obs.NULL_SPAN)
-                d, ids = self._knn_fn(pinned, Q)
-                comp.end()
-            reply = (obs.start_span("frontend.reply", parent=cspan.ctx)
-                     if traced else obs.NULL_SPAN)
+                with obs.span("frontend.device_compute", parent=cspan.ctx):
+                    _cohort.rows = n
+                    try:
+                        d, ids = self._knn_fn(pinned, Q)
+                    finally:
+                        _cohort.rows = None
+            reply = obs.start_span("frontend.reply", parent=cspan.ctx)
             d, ids = np.asarray(d)[:n], np.asarray(ids)[:n]
             t_done = time.monotonic()
             for r, tk in enumerate(batch):
@@ -502,7 +537,6 @@ class ServeFrontend:
             for tk in batch:
                 tk.err = exc
         finally:
-            cspan.end()
             for tk in batch:
                 if tk.span is not obs.NULL_SPAN:
                     if tk.err is not None:
@@ -516,6 +550,7 @@ class ServeFrontend:
                     [tk.latency_s for tk in batch if tk.err is None])
                 self._cond.notify_all()
             self.stats.publish(n, full)
+            cspan.end()
 
     # -- scheduler (mutation batches) -------------------------------------
     def _mutation_loop(self) -> None:

@@ -28,6 +28,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core import smtree
 from repro.core.smtree import (OP_DELETE, OP_INSERT, OP_NOP, ST_APPLIED,
                                ST_MERGE, ST_NOTFOUND, ST_OVERFLOW, ST_SPLIT,
@@ -107,7 +108,8 @@ def escalate_rows(tree: TreeArrays, statuses: np.ndarray, ops, xs,
     if not rows:
         return tree
     from repro.core.engine import _HostView
-    hv = _HostView(tree)
+    hv = _HostView(tree)        # the whole tree, read to the host
+    smtree.count_host_sync()
     for i in rows:
         if ops[i] == OP_INSERT:
             hv.insert_with_split(np.asarray(xs[i], np.float32),
@@ -147,7 +149,8 @@ class MutationBatcher:
 
     # -- host escalation ---------------------------------------------------
     def _escalate(self, statuses: np.ndarray, ops, xs, oids) -> np.ndarray:
-        self.tree = escalate_rows(self.tree, statuses, ops, xs, oids)
+        with obs.child_span("mutation.escalate"):
+            self.tree = escalate_rows(self.tree, statuses, ops, xs, oids)
         return statuses
 
     # -- public API --------------------------------------------------------
@@ -191,6 +194,8 @@ class MutationBatcher:
                                           donate=self.donate,
                                           splits=self.device_splits,
                                           merges=self.device_merges)
-        st = np.array(jax.device_get(st[:n]))   # copy: escalation mutates
+        with obs.child_span("mutation.status_read"):
+            st = np.array(jax.device_get(st[:n]))  # copy: escalation mutates
+            smtree.count_host_sync()
         self.tree = tree
         return st

@@ -118,12 +118,14 @@ class StreamingEngine:
                 self.wal.append_batch(np.asarray(ops, np.int8), xs, oids)
         with obs.span("mutation.apply", n=len(ops)):
             res = self.batcher.apply(ops, xs, oids)
-        if (self.headroom_frac is not None
-                and smtree.needs_headroom(self.tree,
-                                          frac=self.headroom_frac)):
-            self.batcher.tree = smtree.grow_tree(self.tree)
-            self.n_grows += 1
-            obs.record_event("stream.tree_grow", n_grows=self.n_grows)
+            if self.headroom_frac is not None:
+                with obs.span("mutation.headroom"):
+                    if smtree.needs_headroom(self.tree,
+                                             frac=self.headroom_frac):
+                        self.batcher.tree = smtree.grow_tree(self.tree)
+                        self.n_grows += 1
+                        obs.record_event("stream.tree_grow",
+                                         n_grows=self.n_grows)
         with obs.span("mutation.publish"):
             self.epochs.publish(self.tree)
         if obs.enabled():
