@@ -370,12 +370,15 @@ def knn(tree: TreeArrays, queries: jax.Array, *, k: int = 1,
     per-query engine.
 
     ``level_stats=True`` returns ``(QueryResult, pruned)`` where pruned is
-    a ``(by_bound, by_parent)`` pair of int32 stacks — ``by_bound``
-    ``[n_internal_levels, b]`` counts entries whose d_min bound excluded
-    their subtree; ``by_parent`` ``[height, b]`` counts entries the
-    parent-distance pre-filter dropped *before* any metric eval
-    (DESIGN.md §17; all-zero with ``parent_prune`` off, and at the root
-    level, which has no parent).  It is a *static* flag: a separate jit
+    a ``(by_bound, by_parent, live_blocks)`` triple of int32 stacks —
+    ``by_bound`` ``[n_internal_levels, b]`` counts entries whose d_min
+    bound excluded their subtree; ``by_parent`` ``[height, b]`` counts
+    entries the parent-distance pre-filter dropped *before* any metric
+    eval (DESIGN.md §17; all-zero with ``parent_prune`` off, and at the
+    root level, which has no parent); ``live_blocks`` ``[height, b]``
+    counts the frontier kernel's grid steps whose block of slots held a
+    live node (``kernels.frontier.live_blocks``; the leaf level sums its
+    chunks).  It is a *static* flag: a separate jit
     cache entry that leaves the default geometry untouched
     (observability's paper counters; DESIGN.md §15).  ``pruned`` is None
     when the per-query fallback engine served the call.
@@ -450,12 +453,33 @@ def _query(tree: TreeArrays, queries: jax.Array, k: int, F: int, r_cap,
 
 def level_widths(height: int, capacity: int, F: int) -> list[int]:
     """Frontier width of each level of the cohort descent: ``w(0)=1,
-    w(l+1)=min(F, w(l)*capacity)``.  A query row's kernel grid is
+    w(l+1)=min(F, w(l)*capacity)``.  A query row's kernel grid covers
     ``sum(widths)`` slots whatever the descent prunes."""
     widths = [1]
     for _ in range(height - 1):
         widths.append(min(F, widths[-1] * capacity))
     return widths
+
+
+def leaf_chunks(w: int) -> list[tuple[int, int]]:
+    """(start, width) of each slice the leaf level of width ``w`` is
+    scored in: ``_LEAF_CHUNKS`` slices of equal width, the last one short."""
+    chw = -(-w // min(_LEAF_CHUNKS, w))
+    return [(c0, min(chw, w - c0)) for c0 in range(0, w, chw)]
+
+
+def level_grid_steps(height: int, capacity: int, F: int,
+                     dim: int) -> list[int]:
+    """Frontier-kernel grid steps a query row runs at each level: one step
+    a block of ``block_slots`` slots, summed over the leaf level's chunks
+    (kernels/frontier.py)."""
+    from repro.kernels.frontier import block_slots
+
+    def steps(w):
+        return -(-w // block_slots(w, capacity, dim))
+    widths = level_widths(height, capacity, F)
+    return ([steps(w) for w in widths[:-1]]
+            + [sum(steps(wc) for _, wc in leaf_chunks(widths[-1]))])
 
 
 @functools.partial(jax.jit,
@@ -487,8 +511,8 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
 
     ``level_stats`` is static so the default (False) trace emits exactly
     the ops it always did; the True variant additionally stacks per-level
-    pruned-by-bound and pruned-by-parent counts and only ever compiles
-    when observability asks for it.
+    pruned-by-bound, pruned-by-parent and live-block counts and only ever
+    compiles when observability asks for it.
 
     ``prune`` (static) turns on the parent-distance pre-filter
     (DESIGN.md §17): each frontier slot carries ``qpd`` — the distance
@@ -511,7 +535,7 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
     chunk by chunk, and the unpruned path still evaluates every valid
     entry — only wall-clock layout changes, not its dist_evals.
     """
-    from repro.kernels.frontier import frontier_scores
+    from repro.kernels.frontier import frontier_scores, live_blocks
 
     b = queries.shape[0]
     cap = tree.capacity
@@ -532,6 +556,7 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
     overflow = jnp.zeros((b,), bool)
     pruned_levels = []          # level_stats only: [b] per internal level
     parent_levels = []          # level_stats only: [b] per level
+    block_levels = []           # level_stats only: [b] per level
 
     for lvl in range(height):
         w = widths[lvl]
@@ -580,9 +605,12 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
                 leaf_valid, metric=tree.metric, impl=impl,
                 interpret=interpret, **filt)
 
-            # evaluations actually performed: finite outputs ⇔ the scorer
-            # ran the metric for that entry (valid, on a live slot, not
-            # filtered).  With the filter off this equals the old
+            # entries the scorer left unmasked: finite outputs ⇔ valid, on
+            # a live slot and not dropped by the parent-distance filter.
+            # These are the evaluations the descent needs, not the work
+            # the device did: both backends compute the metric for every
+            # entry of a live page and the filter only masks outputs
+            # (DESIGN.md §17).  With the filter off this equals the
             # valid-entry count.
             performed = jnp.isfinite(dmax) | jnp.isfinite(leaf_d)
             n_eval = jnp.sum(performed, axis=(1, 2), dtype=jnp.int32)
@@ -591,6 +619,7 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
                 evalid = tree.valid[nodes] & fvalid[:, :, None]
                 parent_levels.append(
                     jnp.sum(evalid, axis=(1, 2), dtype=jnp.int32) - n_eval)
+                block_levels.append(live_blocks(frontier, cap, tree.dim))
             # --- internal level: d_max bound, prune, compact the frontier
             # r covers the *whole* subtree, and every non-root node holds at
             # least min_fill entries, so an entry at this level covers >=
@@ -648,17 +677,16 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
             # the remaining chunks — most of the leaf entries — see a
             # near-oracle radius both in the candidate test and in the
             # parent-distance pre-filter.
-            chw = -(-w // min(_LEAF_CHUNKS, w))
             parent_acc = jnp.zeros((b,), jnp.int32)
-            for c0 in range(0, w, chw):
-                fr_c = frontier[:, c0:c0 + chw]
-                nodes_c = nodes[:, c0:c0 + chw]
-                wc = fr_c.shape[1]
+            blocks_acc = jnp.zeros((b,), jnp.int32)
+            for c0, wc in leaf_chunks(w):
+                fr_c = frontier[:, c0:c0 + wc]
+                nodes_c = nodes[:, c0:c0 + wc]
                 # per-chunk query radius: identical formula (and value)
                 # with the filter on or off — the bitwise-identity proof
                 # applies per chunk
                 r_q = jnp.minimum(jnp.minimum(topk_d[:, k - 1], r_cap), ub)
-                filt = (dict(pdist=tree.pdist, qpd=qpd[:, c0:c0 + chw],
+                filt = (dict(pdist=tree.pdist, qpd=qpd[:, c0:c0 + wc],
                              rq=r_q)
                         if use_filter else {})
                 dmax_c, _, leaf_d, _ = frontier_scores(
@@ -672,6 +700,7 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
                     evalid = tree.valid[nodes_c] & (fr_c >= 0)[:, :, None]
                     parent_acc += jnp.sum(
                         evalid, axis=(1, 2), dtype=jnp.int32) - n_eval
+                    blocks_acc += live_blocks(fr_c, cap, tree.dim)
                 with jax.named_scope(COMPACT_SCOPE):
                     leaf_d = leaf_d.reshape(b, wc * cap)
                     cd = jnp.where(leaf_d <= r_q[:, None], leaf_d, _INF)
@@ -684,13 +713,14 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
                     topk_i = jnp.take_along_axis(all_i, sel, axis=1)
             if level_stats:
                 parent_levels.append(parent_acc)
+                block_levels.append(blocks_acc)
 
     res = QueryResult(topk_d, topk_i, page_hits, dist_evals, overflow)
     if level_stats:
         by_bound = (jnp.stack(pruned_levels) if pruned_levels
                     else jnp.zeros((0, b), jnp.int32))
         by_parent = jnp.stack(parent_levels)
-        return res, (by_bound, by_parent)
+        return res, (by_bound, by_parent, jnp.stack(block_levels))
     return res
 
 

@@ -15,35 +15,62 @@ query's frontier, then derive four per-entry quantities (DESIGN.md §8/§17):
 
 XLA expresses this as a ``[b, F, cap, dim]`` gather followed by the metric
 reduction — one full materialisation of every touched node page *per query*
-in HBM.  This kernel instead keys the pipeline on the frontier itself: the
+in HBM.  This kernel instead keys the work on the frontier itself: the
 ``[b, F]`` node-id table is a *scalar-prefetch* operand
-(``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index maps read the ids
-before the body runs and the Pallas pipeline streams exactly the referenced
-node pages (``vecs``, and the 8-row blocks of ``radius``/``pdist``/validity
-holding the node's row) HBM→VMEM, double-buffered across grid steps.  Distances and all four outputs are
-computed in one VMEM-resident pass; nothing of size ``[b, F, cap, dim]``
-ever exists.
+(``pltpu.PrefetchScalarGridSpec``), read from SMEM by the body, which copies
+exactly the referenced node pages (``vecs``, and a per-entry page holding
+the radius, pdist and validity rows) HBM→VMEM itself.  Distances and all
+four outputs are computed in one VMEM-resident pass; nothing of size
+``[b, F, cap, dim]`` ever exists.
+
+Grid: ``(b, ceil(F / G))`` — one step scores a *block* of ``G`` frontier
+slots of one query row (``block_slots``: at most 32, fewer where two
+``G``-page buffers would not fit the page budget of scoped VMEM, never more
+than ``F``).  A grid step has a fixed cost of about 0.35-0.4 us on a v5e
+whatever it holds, and most frontier slots are padding (id -1: 78% of them
+in clustered search), so the step is sized to the block, not the slot:
+
+  * the step starts one async copy per *live* slot (id >= 0) of the
+    *next* block into the other of two VMEM buffers, then waits for this
+    block's copies and scores it — the pages of block n+1 load while
+    block n computes (the grid runs in order, rows back to back);
+  * a block whose ids are all -1 starts no copy, evaluates nothing and
+    only writes its ``[G, cap]`` rows of +inf: the wrapper hands the
+    kernel each block's largest id (a second scalar-prefetch table), so
+    the test is one SMEM read; a dead slot inside a live block is skipped
+    by its own id, so any -1 pattern is handled, not only the live
+    prefix the top-k compaction leaves;
+  * a width that is no multiple of ``G`` pads the id table with -1; the
+    outputs keep their ``[b, F, cap]`` shape (the last block's rows past
+    ``F`` are dropped on writeback).
+
+A copy may only slice whole (8, 128) tiles of an HBM operand, so the
+wrapper pads ``vecs`` and the per-entry page to whole tiles where the
+shapes are not (one copy of the tree's pages a descent: XLA shares it
+between the descent's calls).
 
 Parent-distance pre-filter (DESIGN.md §17): when the caller supplies the
 ``pdist`` page (d(entry, parent routing object), maintained by every
 mutation path), the per-frontier ``qpd`` vector (d(q, parent) — the
 distance that admitted each frontier node, computed at the previous level)
-and the per-query radius ``rq``, the prologue drops every entry with
+and the per-query radius ``rq``, the body masks every entry with
 
     |qpd - pdist| > rq + r + _PRUNE_PAD
 
-*before* the metric eval: by the triangle inequality
-|d(q,p) - d(e,p)| <= d(q,e), so such an entry provably fails the descent's
-d - r <= r_q + eps prune test and its distance never needed computing.
-Filtered entries emit +inf, and a node whose entries are all filtered
-skips the reduction entirely (``pl.when``).  Outputs are bitwise identical
-to the unfiltered kernel — only the evaluation count changes.
+by the triangle inequality |d(q,p) - d(e,p)| <= d(q,e), so such an entry
+provably fails the descent's d - r <= r_q + eps prune test and its
+distance is never needed.  Filtered entries emit +inf.  Outputs are
+bitwise identical to the unfiltered kernel — only the descent's count
+of needed evaluations changes, not the work: the kernel scores every
+entry of every live page, because testing a page for a kept entry first
+is a vector-to-scalar read, which cost more on a v5e than the metric it
+saved.
 
-Grid: ``(b, F)`` — one step per (query, frontier-slot) pair.  Invalid slots
-(node id < 0, the frontier padding) emit +inf rows; the metric itself is the
-shared definition in ``core/metric.py`` whose fixed-association tree-fold
-makes the kernel bitwise identical to the XLA path (``frontier_scores_xla``)
-— asserted by tests/test_frontier_kernel.py in interpret mode, which runs
+Invalid slots emit +inf rows.  The metric is the shared definition in
+``core/metric.py``, folding the coordinates of each live slot's transposed
+page (``[dim, cap]``) over sublanes; its fixed-association tree-fold makes
+the kernel bitwise identical to the XLA path (``frontier_scores_xla``) —
+asserted by tests/test_frontier_kernel.py in interpret mode, which runs
 this exact kernel code on CPU CI.
 """
 from __future__ import annotations
@@ -73,65 +100,168 @@ _PRUNE_PAD = 2e-5
 _IMPLS = ("pallas", "xla")
 
 
-def _frontier_kernel(fids_ref, *refs, metric: str, prune: bool, qb: int,
-                     nb: int):
-    """One grid step (i, j): score frontier slot j of query i.
+# Block size rule (``block_slots``).  The two page buffers of a step
+# (this block's and the prefetched next block's) may take this share of
+# the compiler's default scoped VMEM on v5e (16 MiB); the rest stays with
+# the pipelined query blocks and the resident output blocks.
+_PAGE_VMEM_BYTES = 4 * 2**20
+# largest block: of 8, 16 and 32 on a v5e at the served page, 32 ran the
+# cohort descent fastest (PERF.md section 6): a dead block's step grows
+# with its slots (0.26 us at 8, 0.68 us at 32) but far fewer steps remain
+_MAX_BLOCK = 32
+# rows of a node's per-entry page: radius, entry kind, pdist
+_META_ROWS = 3
 
-    Operands keep their HBM shapes; the TPU's (8, 128) block rule is met by
-    whole-row blocks: queries/rq/qpd arrive as the ``qb``-row block holding
-    query i, and each ``[N, cap]`` per-entry array as the ``nb``-row block
-    holding node ``fids[i, j]`` — the kernel picks its row with a dynamic
-    sublane slice.  The outputs' ``[w, cap]`` block of query i stays
-    resident across the j steps and is written one row at a time.
 
-    The page is scored transposed (``[dim, cap]``): the metric then folds
-    the coordinates over sublanes and yields the ``[1, cap]`` row the
-    outputs want, with no lane slicing.  Masks are i32 rows (1 internal,
-    2 leaf, 0 neither), compared only at the final selects."""
+def _vmem_bytes(rows: int, cols: int) -> int:
+    """VMEM footprint of an f32 ``[rows, cols]`` tile-padded to (8, 128)."""
+    return -(-rows // 8) * 8 * (-(-cols // 128) * 128) * 4
+
+
+def block_slots(w: int, cap: int, dim: int) -> int:
+    """Frontier slots ``G`` one grid step scores, from the shapes alone.
+
+    The smaller of ``_MAX_BLOCK`` and what fits two ``G``-page buffers
+    (page ``[cap, dim]`` plus its ``[_META_ROWS, cap]`` per-entry rows,
+    each tile-padded) in ``_PAGE_VMEM_BYTES``; rounded down to a multiple
+    of 8 when it is 8 or more (one sublane-aligned ``[G, cap]`` store a
+    step); never more than the level's width ``w``, never less than 1."""
+    per_slot = 2 * (_vmem_bytes(cap, dim) + _vmem_bytes(_META_ROWS, cap))
+    g = min(_MAX_BLOCK, _PAGE_VMEM_BYTES // per_slot)
+    if g >= 8:
+        g -= g % 8
+    return max(1, min(g, w))
+
+
+def _block_tops(fids, g: int):
+    """[b, ceil(w / g)] i32: the largest id in each block of ``g`` slots of
+    ``fids`` [b, w] (the last block padded with -1).  A block is live where
+    its top is >= 0."""
+    b, w = fids.shape
+    nblk = -(-w // g)
+    f = jnp.pad(fids, ((0, 0), (0, nblk * g - w)), constant_values=-1)
+    return jnp.max(f.reshape(b, nblk, g), axis=2)
+
+
+def live_blocks(fids, cap: int, dim: int):
+    """[b] i32: grid steps of one ``frontier_scores_pallas`` call over
+    ``fids`` [b, w] whose block of ``block_slots`` slots holds a live id
+    (>= 0) — the steps that copy pages and evaluate the metric."""
+    g = block_slots(fids.shape[1], cap, dim)
+    return jnp.sum(_block_tops(fids, g) >= 0, axis=1, dtype=jnp.int32)
+
+
+def _frontier_kernel(fids_ref, tops_ref, *refs, metric: str, prune: bool,
+                     g: int, nblk: int, qb: int):
+    """One grid step (i, j): score frontier slots ``j*g .. j*g+g-1`` of
+    query i.
+
+    ``vecs`` and the per-entry ``meta`` pages stay in HBM; the body copies
+    the pages of the block's live slots (id >= 0) into one of two VMEM
+    buffers itself, and starts the next step's copies before it scores
+    this block, so they load while it computes (the grid runs in order,
+    row after row).  A block whose top id (``tops_ref``, flat, one a
+    block) is -1 starts no copy and evaluates nothing: it writes its
+    ``[g, cap]`` rows of +inf.  The slots of a block are one rolled
+    loop: unrolling it ran a v5e descent 4-6% faster but tripled the
+    host's lowering time, which every process start pays.
+
+    Each live slot's page is scored transposed (``[dim, cap]``): the
+    metric folds the coordinates over sublanes and yields the ``[1, cap]``
+    row the outputs want.  ``meta`` rows are the radius, the entry kind
+    (1 internal, 2 leaf, 0 neither, as f32) and pdist (prune only)."""
     if prune:
-        (q_ref, qpd_ref, rq_ref, vecs_ref, rad_ref, pd_ref, ival_ref,
-         lval_ref, dmax_ref, score_ref, leafd_ref, dq_ref) = refs
+        (q_ref, rq_ref, qpd_ref, vecs_hbm, meta_hbm, dmax_ref, score_ref,
+         leafd_ref, dq_ref, page_buf, meta_buf, sems) = refs
     else:
-        (q_ref, vecs_ref, rad_ref, ival_ref, lval_ref,
-         dmax_ref, score_ref, leafd_ref, dq_ref) = refs
+        (q_ref, vecs_hbm, meta_hbm, dmax_ref, score_ref, leafd_ref, dq_ref,
+         page_buf, meta_buf, sems) = refs
     i = pl.program_id(0)
     j = pl.program_id(1)
-    fid = fids_ref[i, j]
-    qrow = pl.ds(i % qb, 1)
-    nrow = pl.ds(jnp.maximum(fid, 0) % nb, 1)
-    r = rad_ref[nrow, :]                                   # [1, cap]
-    kind = ival_ref[nrow, :] + 2 * lval_ref[nrow, :]       # [1, cap] i32
-    kind = jnp.where(fid >= 0, kind, 0)
-    if prune:
-        # triangle-inequality pre-filter on the already-resident rows — no
-        # metric eval yet.  Invalid slots carry qpd = +inf, so nothing is
-        # kept there and the whole page is skipped.
-        w = qpd_ref.shape[1]
-        slot = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) == j
-        qpd = jnp.max(jnp.where(slot, qpd_ref[qrow, :], -_INF),
-                      axis=1, keepdims=True)               # [1, 1]
-        lb = jnp.abs(qpd - pd_ref[nrow, :])
-        keep = lb <= rq_ref[qrow, :] + r + _PRUNE_PAD
-        kind = jnp.where(keep, kind, 0)
-    out_row = pl.ds(j, 1)
-    any_live = jnp.max(kind) > 0
+    step = i * nblk + j
+    buf = step % 2
 
-    @pl.when(any_live)
-    def _():
-        q = q_ref[qrow, :]                                 # [1, dim]
-        page = vecs_ref[0]                                 # [cap, dim]
-        d = get_metric(metric)(q.T, page.T, axis=0, keepdims=True)  # [1, cap]
-        iv = kind == 1
-        dmax_ref[0, out_row, :] = jnp.where(iv, d + r, _INF)
-        score_ref[0, out_row, :] = jnp.where(iv, d - r, _INF)
-        leafd_ref[0, out_row, :] = jnp.where(kind == 2, d, _INF)
-        dq_ref[0, out_row, :] = jnp.where(iv, d, _INF)
+    def copies(row, blk, s, b):
+        """The page and per-entry copies of slot ``s`` of block (row, blk)
+        into buffer ``b``.  Each copy signals a semaphore of its own: a
+        DMA semaphore counts bytes, not copies, so a wait on a shared one
+        could be met by another slot's page of the same size while this
+        slot's is still in flight."""
+        node = jnp.maximum(fids_ref[row, blk * g + s], 0)
+        return (pltpu.make_async_copy(vecs_hbm.at[node], page_buf.at[b, s],
+                                      sems.at[0, b, s]),
+                pltpu.make_async_copy(meta_hbm.at[node], meta_buf.at[b, s],
+                                      sems.at[1, b, s]))
 
-    @pl.when(jnp.logical_not(any_live))
+    def start(row, blk, b):
+        @pl.when(tops_ref[row * nblk + blk] >= 0)
+        def _():
+            @pl.loop(0, g)
+            def _(s):
+                @pl.when(fids_ref[row, blk * g + s] >= 0)
+                def _():
+                    for c in copies(row, blk, s, b):
+                        c.start()
+
+    @pl.when(step == 0)
     def _():
-        inf_row = jnp.full_like(r, _INF)
-        for ref in (dmax_ref, score_ref, leafd_ref, dq_ref):
-            ref[0, out_row, :] = inf_row
+        start(i, j, buf)
+
+    last = j == nblk - 1
+    nxt_i = jnp.where(last, i + 1, i)
+
+    @pl.when(nxt_i < pl.num_programs(0))
+    def _():
+        start(nxt_i, jnp.where(last, 0, j + 1), 1 - buf)
+
+    cap, dim = dmax_ref.shape[2], q_ref.shape[1]
+    base = j * g
+    if g % 8 == 0:
+        base = pl.multiple_of(base, 8)
+    inf_rows = jnp.full((g, cap), _INF, jnp.float32)
+    for ref in (dmax_ref, score_ref, leafd_ref, dq_ref):
+        ref[0, pl.ds(base, g), :] = inf_rows
+
+    @pl.when(tops_ref[step] >= 0)
+    def _():
+        qrow = pl.ds(i % qb, 1)
+        qt = q_ref[qrow, :].T                              # [dim, 1]
+        if prune:
+            rq = rq_ref[qrow, :]                           # [1, 1]
+
+        @pl.loop(0, g)
+        def _(s):
+            @pl.when(fids_ref[i, j * g + s] >= 0)
+            def _():
+                for c in copies(i, j, s, buf):
+                    c.wait()
+                meta = meta_buf[buf, s][:, :cap]           # [R, cap]
+                r = meta[0:1, :]
+                kind = meta[1:2, :]
+                if prune:
+                    # triangle-inequality pre-filter on the copied rows
+                    lb = jnp.abs(qpd_ref[0, 0, j * g + s] - meta[2:3, :])
+                    keep = lb <= rq + r + _PRUNE_PAD
+                    kind = jnp.where(keep, kind, 0.0)
+                # every live page is scored (module docstring)
+                page = page_buf[buf, s][:cap, :dim]        # [cap, dim]
+                d = get_metric(metric)(qt, page.T, axis=0,
+                                       keepdims=True)      # [1, cap]
+                iv = kind == 1.0
+                row = pl.ds(j * g + s, 1)
+                dmax_ref[0, row, :] = jnp.where(iv, d + r, _INF)
+                score_ref[0, row, :] = jnp.where(iv, d - r, _INF)
+                leafd_ref[0, row, :] = jnp.where(kind == 2.0, d, _INF)
+                dq_ref[0, row, :] = jnp.where(iv, d, _INF)
+
+
+def _tile_pad(x):
+    """``x`` with its last two dims zero-padded to whole (8, 128) tiles: a
+    copy may only slice whole tiles out of an HBM operand."""
+    pad = ((0, -x.shape[-2] % 8), (0, -x.shape[-1] % 128))
+    if not any(p for _, p in pad):
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 2) + pad)
 
 
 def _check_prune_args(pdist, qpd, rq):
@@ -169,41 +299,64 @@ def frontier_scores_pallas(fids, queries, vecs, radius, internal_valid,
     """
     prune = _check_prune_args(pdist, qpd, rq)
     b, w = fids.shape
-    n, cap, dim = vecs.shape
+    _, cap, dim = vecs.shape
+    g = block_slots(w, cap, dim)
+    nblk = -(-w // g)
+    wp = nblk * g
+    # the outputs' resident block of a query: the whole row, or (past a
+    # partial last block) a sublane-aligned block the writeback clips
+    wo = w if wp == w else -(-wp // 8) * 8
+    # the last block's tail slots are empty: id -1, qpd +inf; the block
+    # tops go flat into SMEM (a 2-D SMEM array pads its rows to 128 words)
+    tops = _block_tops(fids, g).reshape(-1)
+    fids = jnp.pad(fids, ((0, 0), (0, wp - w)), constant_values=-1)
+    # one [R, cap] per-entry page per node, so a slot needs two copies
+    kind = ((internal_valid != 0).astype(jnp.float32)
+            + 2.0 * (leaf_valid != 0).astype(jnp.float32))
+    rows = (radius, kind, pdist) if prune else (radius, kind)
+    meta = _tile_pad(jnp.stack([x.astype(jnp.float32) for x in rows],
+                               axis=1))
+    vecs = _tile_pad(vecs)
     # 8-row blocks satisfy the TPU's sublane rule; a smaller array is one
     # whole block (a block dim equal to the array dim is always legal)
-    qb, nb = min(b, 8), min(n, 8)
-    internal_valid = internal_valid.astype(jnp.int32)
-    leaf_valid = leaf_valid.astype(jnp.int32)
+    qb = min(b, 8)
 
-    q_block = lambda cols: pl.BlockSpec((qb, cols), lambda i, j, f: (i // qb, 0))
-    node_block = pl.BlockSpec(
-        (nb, cap), lambda i, j, f: (jnp.maximum(f[i, j], 0) // nb, 0))
-    page = pl.BlockSpec((1, cap, dim),
-                        lambda i, j, f: (jnp.maximum(f[i, j], 0), 0, 0))
+    q_block = lambda cols: pl.BlockSpec((qb, cols),
+                                        lambda i, j, *_: (i // qb, 0))
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     if prune:
-        in_specs = [q_block(dim), q_block(w), q_block(1), page, node_block,
-                    node_block, node_block, node_block]
-        operands = (fids, queries, qpd, rq[:, None], vecs, radius, pdist,
-                    internal_valid, leaf_valid)
+        qpd = jnp.pad(qpd, ((0, 0), (0, wp - w)), constant_values=_INF)
+        in_specs = [q_block(dim), q_block(1),
+                    pl.BlockSpec((1, 1, wp), lambda i, j, *_: (i, 0, 0),
+                                 memory_space=pltpu.SMEM),
+                    hbm, hbm]
+        operands = (fids, tops, queries, rq[:, None], qpd[:, None], vecs,
+                    meta)
     else:
-        in_specs = [q_block(dim), page, node_block, node_block, node_block]
-        operands = (fids, queries, vecs, radius, internal_valid, leaf_valid)
+        in_specs = [q_block(dim), hbm, hbm]
+        operands = (fids, tops, queries, vecs, meta)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, w),
+        num_scalar_prefetch=2,
+        grid=(b, nblk),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, w, cap), lambda i, j, f: (i, 0, 0))] * 4,
+        out_specs=[pl.BlockSpec((1, wo, cap), lambda i, j, *_: (i, 0, 0))] * 4,
+        scratch_shapes=[
+            pltpu.VMEM((2, g) + vecs.shape[1:], jnp.float32),
+            pltpu.VMEM((2, g) + meta.shape[1:], jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2, g)),
+        ],
     )
     out_shape = [jax.ShapeDtypeStruct((b, w, cap), jnp.float32)] * 4
     return pl.pallas_call(
         functools.partial(_frontier_kernel, metric=metric, prune=prune,
-                          qb=qb, nb=nb),
+                          g=g, nblk=nblk, qb=qb),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        # the copies of the next step start in this one: the grid runs in
+        # order on both axes
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)
 
@@ -223,8 +376,8 @@ def frontier_scores_xla(fids, queries, vecs, radius, internal_valid,
     The parent-distance filter (pdist/qpd/rq — see frontier_scores_pallas)
     applies the identical keep mask and zeroes filtered rows via jnp.where
     *before* the metric eval; on XLA:CPU the compiler still schedules the
-    full reduction shape, so this buys parity and honest eval counters, not
-    wall-clock (DESIGN.md §17 — the lane skip is a kernel-path win)."""
+    full reduction shape, so this buys parity and the needed-eval counters, not
+    wall-clock (DESIGN.md §17)."""
     prune = _check_prune_args(pdist, qpd, rq)
     nodes = jnp.maximum(fids, 0)
     ok = (fids >= 0)[:, :, None]

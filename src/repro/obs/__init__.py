@@ -160,7 +160,7 @@ def want_level_stats() -> bool:
 
 def observe_query_result(res, pruned=None, *, prefix: str = "descent",
                          rows: int | None = None,
-                         widths=None) -> None:
+                         widths=None, grid_steps=None) -> None:
     """Accumulate the descent's per-dispatch reductions into paper-level
     counters: metric (distance) evaluations, nodes visited, and — when
     the kernel was asked for level stats — pruned-by-bound and
@@ -172,15 +172,22 @@ def observe_query_result(res, pruned=None, *, prefix: str = "descent",
     (``smtree.level_widths``), adds ``{prefix}.grid_slots_total``: rows x
     sum(widths), the frontier slots the descent's grid scores whatever
     it prunes; ``nodes_visited_total`` over it is the share that held a
-    live node.
+    live node.  ``grid_steps``, the frontier kernel's grid steps a row
+    runs at each level (``smtree.level_grid_steps``), likewise adds
+    ``{prefix}.grid_steps_total``: rows x sum(grid_steps).
 
     ``pruned`` is what ``smtree.knn(..., level_stats=True)`` returned:
-    a ``(by_bound, by_parent)`` pair of ``[levels, b]`` stacks (a bare
-    array is accepted as by-bound only, for older recorded shapes).
-    ``by_parent`` feeds ``{prefix}.pruned_by_parent_total`` — entries the
-    parent-distance pre-filter dropped before any metric eval, the
-    quantity DESIGN.md §17 moves; note ``dist_evals_total`` already
-    excludes them (it counts evaluations performed).
+    a ``(by_bound, by_parent, live_blocks)`` triple of ``[levels, b]``
+    stacks.  ``by_parent`` feeds ``{prefix}.pruned_by_parent_total`` —
+    entries the parent-distance pre-filter masked, whose metric the
+    triangle bound proves unneeded (DESIGN.md §17); ``dist_evals_total``
+    excludes them (it counts unmasked entries).  Neither counts work the
+    device skipped: both scoring backends compute the metric for every
+    entry of a live page and the filter masks outputs.
+    ``live_blocks`` feeds ``{prefix}.live_blocks_total``, the grid steps
+    whose block held a live node — only beside ``grid_steps``, so that
+    the two count the same dispatches and their ratio is the share of the
+    kernel's grid that did work.
 
     Callers pass a ``QueryResult`` whose fields they are already
     materialising to the host (the serving paths call ``np.asarray`` on
@@ -200,16 +207,19 @@ def observe_query_result(res, pruned=None, *, prefix: str = "descent",
     if widths is not None:
         REGISTRY.counter(f"{prefix}.grid_slots_total").inc(
             b * int(sum(widths)))
+    if grid_steps is not None:
+        REGISTRY.counter(f"{prefix}.grid_steps_total").inc(
+            b * int(sum(grid_steps)))
     if overflow:
         REGISTRY.counter(f"{prefix}.frontier_overflow_total").inc(overflow)
     if pruned is None:
         return
-    by_bound, by_parent = (pruned if isinstance(pruned, tuple)
-                           else (pruned, None))
+    by_bound, by_parent, blocks = pruned
+    if grid_steps is not None:
+        REGISTRY.counter(f"{prefix}.live_blocks_total").inc(
+            int(np.asarray(blocks)[:, :b].sum()))
     for stack, kind in ((by_bound, "pruned_by_bound"),
                         (by_parent, "pruned_by_parent")):
-        if stack is None:
-            continue
         p = np.asarray(stack)[:, :b]    # [levels, b]
         REGISTRY.counter(f"{prefix}.{kind}_total").inc(int(p.sum()))
         for lvl in range(p.shape[0]):

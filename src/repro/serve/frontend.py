@@ -94,11 +94,11 @@ def pinned_knn(pinned, queries: np.ndarray, *, k: int, max_frontier: int):
     With observability on, a 1/``obs.LEVEL_STATS_EVERY`` sample of
     dispatches runs the level-stats descent variant (a separate jit
     cache entry — default geometry untouched) and accumulates the paper
-    counters: queries, distance evals, nodes visited, grid slots,
-    pruned-by-bound per level.  Sampling the whole counter path —
-    denominator included — keeps per-query averages unbiased while the
-    other 15/16 dispatches pay nothing (no device fetches for the
-    reduction arrays).
+    counters: queries, distance evals, nodes visited, grid slots, kernel
+    grid steps and live blocks, pruned-by-bound per level.  Sampling the
+    whole counter path — denominator included — keeps per-query averages
+    unbiased while the other 15/16 dispatches pay nothing (no device
+    fetches for the reduction arrays).
 
     Under an open span (the front end's ``frontend.device_compute``) the
     call is three children: ``frontend.dispatch`` (every shard's descent
@@ -130,10 +130,15 @@ def pinned_knn(pinned, queries: np.ndarray, *, k: int, max_frontier: int):
             ids.append(np.asarray(res.ids))
             if sampled:
                 # the cohort descent's by-parent stack has one row a level
-                widths = (None if pruned is None else smtree.level_widths(
-                    pruned[1].shape[0], t.capacity, max_frontier))
+                widths = steps = None
+                if pruned is not None:
+                    height = pruned[1].shape[0]
+                    widths = smtree.level_widths(height, t.capacity,
+                                                 max_frontier)
+                    steps = smtree.level_grid_steps(height, t.capacity,
+                                                    max_frontier, t.dim)
                 obs.observe_query_result(res, pruned, rows=rows,
-                                         widths=widths)
+                                         widths=widths, grid_steps=steps)
         d = np.concatenate(ds, axis=1)
         i = np.concatenate(ids, axis=1)
         order = np.argsort(d, axis=1, kind="stable")[:, :k]

@@ -182,22 +182,22 @@ def test_parent_prune_rides_on_pdist_invariant():
 
 
 def test_level_stats_parent_counts():
-    """level_stats returns (by_bound, by_parent); parent counts are zero at
-    the root level and with the filter off, and account exactly for the
-    dist_evals delta.  At internal levels, every parent-filtered entry
-    provably fails the d_min bound too (DESIGN.md §17), so in the
-    unfiltered trace it shows up as pruned-by-bound instead:
-    bb_off == bb_on + bp_on at those levels."""
+    """level_stats returns (by_bound, by_parent, live_blocks); parent
+    counts are zero at the root level and with the filter off, and
+    account exactly for the dist_evals delta.  At internal levels, every
+    parent-filtered entry provably fails the d_min bound too (DESIGN.md
+    §17), so in the unfiltered trace it shows up as pruned-by-bound
+    instead: bb_off == bb_on + bp_on at those levels."""
     from repro.core import smtree
     X = clustered(2000, dims=8, seed=23)
     eng = SMTreeEngine.build(X, capacity=16)
     Q = np.asarray(X[:32] + 0.002, np.float32)
-    res_on, (bb_on, bp_on) = smtree.knn(eng.tree, Q, k=5, max_frontier=64,
-                                        impl="xla", level_stats=True,
-                                        parent_prune=True)
-    res_off, (bb_off, bp_off) = smtree.knn(eng.tree, Q, k=5, max_frontier=64,
-                                           impl="xla", level_stats=True,
-                                           parent_prune=False)
+    res_on, (bb_on, bp_on, _) = smtree.knn(
+        eng.tree, Q, k=5, max_frontier=64, impl="xla", level_stats=True,
+        parent_prune=True)
+    res_off, (bb_off, bp_off, _) = smtree.knn(
+        eng.tree, Q, k=5, max_frontier=64, impl="xla", level_stats=True,
+        parent_prune=False)
     assert np.asarray(bp_off).sum() == 0
     assert np.asarray(bp_on)[0].sum() == 0          # root has no parent
     n_internal = np.asarray(bb_on).shape[0]

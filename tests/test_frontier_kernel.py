@@ -15,6 +15,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels import frontier
 from repro.kernels.frontier import (_PRUNE_PAD, frontier_scores,
                                     frontier_scores_pallas,
                                     frontier_scores_xla)
@@ -219,3 +220,95 @@ def test_partial_prune_args_raise():
         frontier_scores(fids, queries, vecs, radius, iv, lv,
                         metric="d_inf", impl="xla",
                         qpd=jnp.zeros((1, 1), jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# Blocked grid: one step scores block_slots(w, cap, dim) slots.  Widths that
+# are no multiple of the block, wholly dead rows and live slots that are
+# not a prefix, at the served page (cap 42, dim 20, d_inf) and a 128-d
+# l2 page, with one slot a step and with the rule's block.
+
+def _blocked_case(rng, w, cap, dim, b=3, N=50):
+    vecs, radius, iv, lv = _random_tree_pages(rng, N=N, cap=cap, dim=dim)
+    f = rng.integers(0, N, size=(b, w)).astype(np.int32)
+    f[0] = -1                                    # wholly dead row
+    f[1, rng.random(w) < 0.5] = -1               # interleaved -1
+    f[1, -1] = rng.integers(0, N)                # live slot in the tail
+    f[2, (w + 1) // 2:] = -1                     # live prefix
+    fids = jnp.asarray(f)
+    queries = jnp.asarray(rng.normal(size=(b, dim)).astype(np.float32))
+    return fids, queries, vecs, radius, iv, lv
+
+
+@pytest.fixture(params=["one_slot", "rule"])
+def block_rule(request, monkeypatch):
+    """Score with one slot a step, or with block_slots' own blocks."""
+    if request.param == "one_slot":
+        monkeypatch.setattr(frontier, "_MAX_BLOCK", 1)
+    frontier_scores_pallas.clear_cache()
+    yield request.param
+    frontier_scores_pallas.clear_cache()
+
+
+@pytest.mark.parametrize("prune", [False, True], ids=["plain", "parent_prune"])
+@pytest.mark.parametrize("cap,dim,metric", [(42, 20, "d_inf"),
+                                            (32, 128, "l2")],
+                         ids=["served", "dim128"])
+@pytest.mark.parametrize("w", [1, 7, 42, 441])
+def test_blocked_kernel_matches_xla_bitwise(block_rule, w, cap, dim, metric,
+                                            prune):
+    rng = np.random.default_rng(w + dim)
+    fids, queries, vecs, radius, iv, lv = _blocked_case(rng, w, cap, dim)
+    g = frontier.block_slots(w, cap, dim)
+    assert (g == 1) == (block_rule == "one_slot" or w == 1)
+    kw = {}
+    if prune:
+        pdist, qpd, rq = _random_prune_inputs(rng, fids, vecs.shape[0], cap)
+        kw = dict(pdist=pdist, qpd=qpd, rq=rq)
+    got = frontier_scores_pallas(fids, queries, vecs, radius, iv, lv,
+                                 metric=metric, interpret=True, **kw)
+    want = frontier_scores_xla(fids, queries, vecs, radius, iv, lv,
+                               metric=metric, **kw)
+    for gv, wv, name in zip(got, want, OUT_NAMES):
+        assert gv.shape == (fids.shape[0], w, cap)
+        np.testing.assert_array_equal(np.asarray(gv), np.asarray(wv),
+                                      err_msg=f"{block_rule}/{name}")
+    assert np.isposinf(np.asarray(got[0])[0]).all()      # the dead row
+
+
+@pytest.mark.parametrize("cap,dim", [(42, 20), (32, 128), (42, 2048),
+                                     (4, 6), (256, 4096)])
+@pytest.mark.parametrize("w", [1, 5, 42, 441, 2048])
+def test_block_slots_rule(w, cap, dim):
+    """1 <= G <= w; a multiple of 8 from 8 up; the two buffers of G pages
+    and their per-entry rows fit the page budget (or G is 1)."""
+    g = frontier.block_slots(w, cap, dim)
+    assert 1 <= g <= w
+    assert g < 8 or g % 8 == 0
+    two_bufs = 2 * g * (frontier._vmem_bytes(cap, dim)
+                        + frontier._vmem_bytes(frontier._META_ROWS, cap))
+    assert g == 1 or two_bufs <= frontier._PAGE_VMEM_BYTES
+    if w >= frontier._MAX_BLOCK and dim <= 128:
+        assert g == frontier._MAX_BLOCK
+
+
+def test_block_slots_shrinks_with_the_page():
+    """The same rule gives wide pages smaller blocks: the dim-2048 page
+    of capacity 42 takes fewer slots a step than the served page."""
+    served = frontier.block_slots(441, 42, 20)
+    wide = frontier.block_slots(441, 42, 2048)
+    assert 1 <= wide < served
+
+
+def test_live_blocks_counts_blocks_with_a_live_id():
+    rng = np.random.default_rng(8)
+    for w in (1, 7, 42, 441):
+        f = rng.integers(0, 9, size=(4, w)).astype(np.int32)
+        f[rng.random((4, w)) < 0.7] = -1
+        f[0] = -1
+        g = frontier.block_slots(w, 42, 20)
+        pad = np.full((4, -w % g), -1, np.int32)
+        want = (np.concatenate([f, pad], 1).reshape(4, -1, g) >= 0).any(2)
+        np.testing.assert_array_equal(
+            np.asarray(frontier.live_blocks(jnp.asarray(f), 42, 20)),
+            want.sum(1))

@@ -130,6 +130,68 @@ def test_grid_counter_counts_live_rows_only(obs_on):
     assert obs.REGISTRY.snapshot()["descent.queries_total"] == 7
 
 
+def test_grid_steps_count_live_rows_only(obs_on):
+    """The sampled dispatch of a cohort of 5 queries padded to 16 rows adds
+    the frontier kernel's grid steps of 5 rows, and the live blocks of
+    those 5 rows alone."""
+    X = _points()
+    tree = bulk_build(X, capacity=8)
+    cfg = FrontendConfig(cohort_width=16, slo_ms=500.0, k=3,
+                         max_frontier=256)
+    with ServeFrontend(StreamingEngine(tree), cfg) as fe:
+        fe.knn(X[:5], timeout=60)
+    snap = obs.REGISTRY.snapshot()
+    steps = smtree.level_grid_steps(int(tree.height), tree.capacity, 256,
+                                    tree.dim)
+    assert snap["descent.grid_steps_total"] == 5 * sum(steps)
+    _, (_, _, blocks) = smtree.knn(tree, X[:5], k=3, max_frontier=256,
+                                   level_stats=True)
+    assert snap["descent.live_blocks_total"] == int(np.asarray(blocks).sum())
+    assert 0 < snap["descent.live_blocks_total"] \
+        <= snap["descent.grid_steps_total"]
+
+
+def test_live_blocks_match_a_count_of_the_frontiers():
+    """The level-stats descent's live-block stack equals a numpy count,
+    over the frontier of every scoring call, of the blocks of
+    ``block_slots`` slots that hold a live id (the leaf level summing its
+    chunks)."""
+    from repro.kernels import frontier
+    X = _points(600, seed=3)
+    tree = bulk_build(X, capacity=8)
+    height = int(tree.height)
+    Q = X[:6] + 0.01
+    seen = []
+    scores = frontier.frontier_scores
+
+    def recording(fids, *a, **kw):
+        jax.debug.callback(lambda f: seen.append(np.asarray(f)), fids,
+                           ordered=True)
+        return scores(fids, *a, **kw)
+
+    smtree._knn_cohort.clear_cache()
+    try:
+        frontier.frontier_scores = recording
+        _, (_, _, blocks) = smtree.knn(tree, Q, k=3, max_frontier=24,
+                                       impl="xla", level_stats=True)
+        blocks = np.asarray(blocks)
+        jax.effects_barrier()
+    finally:
+        frontier.frontier_scores = scores
+        smtree._knn_cohort.clear_cache()
+    widths = smtree.level_widths(height, tree.capacity, 24)
+    assert [f.shape[1] for f in seen] == widths[:-1] + [
+        wc for _, wc in smtree.leaf_chunks(widths[-1])]
+    want = np.zeros((height, len(Q)), np.int64)
+    for n, f in enumerate(seen):
+        g = frontier.block_slots(f.shape[1], tree.capacity, tree.dim)
+        f = np.pad(f, ((0, 0), (0, -f.shape[1] % g)), constant_values=-1)
+        want[min(n, height - 1)] += (f.reshape(len(Q), -1, g) >= 0).any(2) \
+            .sum(1)
+    np.testing.assert_array_equal(blocks, want)
+    assert blocks[-1].sum() > 0
+
+
 def test_level_widths():
     assert smtree.level_widths(3, 42, 2048) == [1, 42, 1764]
     assert smtree.level_widths(4, 8, 64) == [1, 8, 64, 64]

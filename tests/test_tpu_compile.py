@@ -62,6 +62,22 @@ def test_frontier_kernel_compiles(one_chip, metric, prune):
     _assert_kernel(fn.lower(*args, **kw).compile())
 
 
+# the served page (25,000 x 20-d, capacity 42, d_inf, parent filter on, a
+# cohort of 64) at an internal level's width and a leaf chunk's, and a
+# capacity-42 page at dim 2048, where block_slots gives a smaller block
+@pytest.mark.parametrize("w,cap,dim", [(42, 42, 20), (441, 42, 20),
+                                       (441, 42, 2048)],
+                         ids=["served_w42", "served_w441", "cap42_dim2048"])
+def test_blocked_frontier_kernel_compiles(one_chip, w, cap, dim):
+    s = functools.partial(_spec, one_chip)
+    n, b = 1341, 64
+    args = (s((b, w), jnp.int32), s((b, dim)), s((n, cap, dim)),
+            s((n, cap)), s((n, cap), jnp.bool_), s((n, cap), jnp.bool_))
+    kw = dict(pdist=s((n, cap)), qpd=s((b, w)), rq=s((b,)))
+    fn = jax.jit(functools.partial(frontier_scores_pallas, metric="d_inf"))
+    _assert_kernel(fn.lower(*args, **kw).compile())
+
+
 def test_l2_distance_kernel_compiles(one_chip):
     s = functools.partial(_spec, one_chip)
     fn = jax.jit(functools.partial(pairwise_distance_pallas, metric="l2"))
