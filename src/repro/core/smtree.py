@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core.metric import get_metric
+from repro.core.metric import get_metric, make_metric
 
 MAX_HEIGHT = 16          # supports capacity^15 objects; plenty
 _INF = jnp.inf
@@ -62,6 +62,9 @@ _LEAF_CHUNKS = 4
 # cut and the leaf-chunk merges): it lands in the ops' op_name metadata,
 # so a profile can total their device time
 COMPACT_SCOPE = "descent.compact"
+# named scope of the parent-distance bound a level computes before its
+# metric evaluations (its gathers and its top-k, DESIGN.md §17)
+BOUND_SCOPE = "descent.bound"
 
 
 # --------------------------------------------------------------------------
@@ -171,17 +174,151 @@ def _metric_eval(metric: str, q, e):
 
 
 # --------------------------------------------------------------------------
-# Bulk build (host-side, numpy): balanced bottom-up construction
+# Bulk build: balanced bottom-up construction
 # --------------------------------------------------------------------------
+# Corpora of at least this many coordinates (n x dim) compute the build's
+# distances on the default device; smaller ones in numpy on the host.  The
+# shared metric's fixed fold gives the same values on either.
+_DEVICE_BUILD_ELEMS = 2 ** 25
+_BUILD_ROWS = 2 ** 24       # coordinates a distance call holds at once
+
+
+class _BuildDists:
+    """The build's metric evaluations over the rows of one point set: in
+    numpy for a set of fewer than ``_DEVICE_BUILD_ELEMS`` coordinates, else
+    in fixed-shape jitted chunks on the default device (one compile a
+    shape)."""
+
+    def __init__(self, X: np.ndarray, metric: str):
+        self.X, self.dim = X, X.shape[1]
+        self.mfn = make_metric(metric, None)
+        device = X.size >= _DEVICE_BUILD_ELEMS
+        self.dev = jax.device_put(X) if device else None
+        if device:
+            mfn = self.mfn
+            self._rows = jax.jit(
+                lambda X, a, b: mfn(X[a], X[b]))
+            self._pairs = jax.jit(
+                lambda X, g: mfn(X[g][:, :, None, :], X[g][:, None, :, :]))
+
+    def rows(self, a: np.ndarray, b: np.ndarray) -> np.ndarray:
+        """[m] f32: d(X[a[i]], X[b[i]])."""
+        if self.dev is None:
+            return np.asarray(self.mfn(self.X[a], self.X[b]))
+        c = max(1, _BUILD_ROWS // self.dim)
+        c = 1 << (c.bit_length() - 1)
+        out = np.empty(len(a), np.float32)
+        for s in range(0, len(a), c):
+            m = min(c, len(a) - s)
+            ia = np.zeros(c, np.int32)
+            ib = np.zeros(c, np.int32)
+            ia[:m], ib[:m] = a[s:s + m], b[s:s + m]
+            out[s:s + m] = np.asarray(self._rows(self.dev, ia, ib))[:m]
+        return out
+
+    def pairs(self, g: np.ndarray) -> np.ndarray:
+        """[G, M, M] f32: d(X[g[i, j]], X[g[i, l]]) within each row of the
+        index table ``g`` [G, M]."""
+        G, M = g.shape
+        c = max(1, _BUILD_ROWS // (M * M * self.dim))
+        out = np.empty((G, M, M), np.float32)
+        for s in range(0, G, c):
+            part = g[s:s + c]
+            if self.dev is None:
+                P = self.X[part]
+                out[s:s + c] = self.mfn(P[:, :, None, :], P[:, None, :, :])
+                continue
+            idx = np.zeros((c, M), np.int32)
+            idx[:len(part)] = part
+            out[s:s + c] = np.asarray(
+                self._pairs(self.dev, idx))[:len(part)]
+        return out
+
+
+def _bisect(dists: _BuildDists, n: int, tgt: int, min_fill: int,
+            rng) -> list[np.ndarray]:
+    """Partition rows 0..n-1 into groups of near-equal size via recursive
+    2-pivot bisection: a group of m rows splits into
+    ``parts = min(ceil(m / tgt), m // min_fill)`` parts (none when parts
+    <= 1), its rows ordered by d(a, .) - d(b, .) for a random row a and b
+    the row farthest from a, and cut in proportion to the parts each side
+    takes.  Sizes land in [floor(n/parts), ceil(n/parts)]; the cap at
+    m // min_fill keeps every group at the min-fill floor (a group below
+    it would violate the non-root invariant the engine's validate() and
+    the cohort descent's d_max bound rely on — e.g. n=23 at capacity 32
+    must stay one node, not split 11/12).  The cap can only force parts
+    to 1 when m < 2*min_fill <= capacity, so every group fits a node.
+
+    The recursion's shape depends on the sizes alone, so the pivots are
+    drawn in its preorder first and the splits then run a depth at a
+    time, every group of a depth in one batch of distance evaluations.
+    Groups come back in the recursion's order."""
+    splits = []             # (depth, start, size, a, cut), in preorder
+
+    def walk(start, size, depth):
+        parts = min(-(-size // tgt), size // min_fill)
+        if parts <= 1:
+            return
+        a = int(rng.integers(size))
+        cut = round(size * (parts // 2) / parts)
+        splits.append((depth, start, size, a, cut))
+        walk(start, cut, depth + 1)
+        walk(start + cut, size - cut, depth + 1)
+
+    walk(0, n, 0)
+    perm = np.arange(n)
+    cuts = [0, n]
+    for depth in range(max((sp[0] for sp in splits), default=-1) + 1):
+        level = [sp[1:] for sp in splits if sp[0] == depth]
+        starts = np.array([st for st, _, _, _ in level])
+        sizes = np.array([m for _, m, _, _ in level])
+        seg = np.repeat(np.arange(len(level)), sizes)
+        offs = np.concatenate([[0], np.cumsum(sizes)[:-1]])
+        pos = np.repeat(starts - offs, sizes) + np.arange(len(seg))
+        rows = perm[pos]
+        piv_a = perm[starts + np.array([a for _, _, a, _ in level])]
+        da = dists.rows(piv_a[seg], rows)
+        # b: the first row of its group at the largest d(a, .)
+        top = np.maximum.reduceat(da, offs)
+        hit = np.flatnonzero(da == top[seg])
+        _, first = np.unique(seg[hit], return_index=True)
+        db = dists.rows(rows[hit[first]][seg], rows)
+        # rows in order of d(a, .) - d(b, .), closest-to-a first, within
+        # each group (lexsort is stable)
+        perm[pos] = rows[np.lexsort((da - db, seg))]
+        cuts += [st + c for st, _, _, c in level]
+    cuts = np.unique(cuts)
+    return [perm[a:b] for a, b in zip(cuts[:-1], cuts[1:])]
+
+
+def _medoids(dists: _BuildDists, groups: list[np.ndarray],
+             extra: np.ndarray | None = None):
+    """(medoid position, distances from it) of each group: the row whose
+    largest d(row, .) (+ ``extra`` of the other row, over the same index
+    space) is smallest, the first such."""
+    M = max(len(g) for g in groups)
+    table = np.zeros((len(groups), M), np.int64)
+    valid = np.zeros((len(groups), M), bool)
+    for i, g in enumerate(groups):
+        table[i, :len(g)] = g
+        valid[i, :len(g)] = True
+    D = dists.pairs(table)
+    E = D if extra is None else D + extra[table][:, None, :]
+    far = np.where(valid[:, None, :], E, -np.inf).max(axis=2)
+    mi = np.where(valid, far, np.inf).argmin(axis=1)
+    return mi, [D[i, m, :len(g)] for i, (m, g) in
+                enumerate(zip(mi, groups))]
+
+
 def bulk_build(X: np.ndarray, ids: np.ndarray | None = None, *,
                capacity: int = 32, metric: str = "d_inf",
                fill_frac: float = 0.7, min_fill_frac: float = 0.4,
                seed: int = 0, slack: float = 1.5) -> TreeArrays:
     """Construct a valid SM-tree over X [n, d] (balanced recursive-bisection
     grouping, medoid routing objects, exact SM radii).  O(n log n) distance
-    evaluations, fully vectorised per group."""
-    from repro.core.metric import make_metric
-    mfn = make_metric(metric, None)
+    evaluations, batched a recursion depth (or a chunk of groups) at a
+    time, on the default device for corpora of ``_DEVICE_BUILD_ELEMS``
+    coordinates or more (the same values as on the host)."""
     X = np.asarray(X, np.float32)
     n, dim = X.shape
     ids = np.arange(n, dtype=np.int64) if ids is None else np.asarray(ids)
@@ -189,53 +326,22 @@ def bulk_build(X: np.ndarray, ids: np.ndarray | None = None, *,
     min_fill = max(1, math.ceil(min_fill_frac * capacity))
     rng = np.random.default_rng(seed)
 
-    def group(indices: np.ndarray, tgt: int, pts: np.ndarray) -> list[np.ndarray]:
-        """Partition `indices` into groups of near-equal size via recursive
-        2-pivot bisection.  Sizes land in [floor(n/parts), ceil(n/parts)];
-        parts is capped at n // min_fill so every group meets the min-fill
-        floor (a group below it would violate the non-root invariant the
-        engine's validate() and the cohort descent's d_max bound rely on —
-        e.g. n=23 at capacity 32 must stay one node, not split 11/12).
-        The cap can only force parts to 1 when n < 2*min_fill <= capacity,
-        so single groups always fit a node."""
-        n_idx = len(indices)
-        parts = min(-(-n_idx // tgt), n_idx // min_fill)
-        if parts <= 1:
-            return [indices]
-        P = pts[indices]
-        a = int(rng.integers(n_idx))
-        da = mfn(P[a][None, :], P)
-        b = int(np.argmax(da))
-        db = mfn(P[b][None, :], P)
-        order = np.argsort(da - db, kind="stable")   # closest-to-a first
-        left_parts = parts // 2
-        cut = round(n_idx * left_parts / parts)
-        return (group(indices[order[:cut]], tgt, pts)
-                + group(indices[order[cut:]], tgt, pts))
-
     # --- leaves ---
-    leaf_groups = group(np.arange(n), target, X)
-    levels = [leaf_groups]
+    dists = _BuildDists(X, metric)
+    leaf_groups = _bisect(dists, n, target, min_fill, rng)
 
     # node table accumulators
     nodes: list[dict] = []
 
-    def medoid(P: np.ndarray, extra: np.ndarray | None = None) -> int:
-        D = np.asarray(mfn(P[:, None, :], P[None, :, :]))
-        if extra is not None:
-            D = D + extra[None, :]
-        return int(D.max(axis=1).argmin())
-
     # build leaf nodes
     level_nodes = []   # (node_id, routing_vec, covering_radius)
-    for g in leaf_groups:
+    for g, mi, d_to_m in zip(leaf_groups, *_medoids(dists, leaf_groups)):
         P = X[g]
-        mi = medoid(P)
-        d_to_m = np.asarray(mfn(P[mi][None, :], P))
         nid = len(nodes)
         nodes.append(dict(is_leaf=True, vecs=P, radius=np.zeros(len(g)),
                           pdist=d_to_m, oid=ids[g], child=np.full(len(g), -1)))
         level_nodes.append((nid, P[mi], float(d_to_m.max())))
+    del dists
 
     height = 1
     while len(level_nodes) > 1:
@@ -243,13 +349,14 @@ def bulk_build(X: np.ndarray, ids: np.ndarray | None = None, *,
         routing = np.stack([v for _, v, _ in level_nodes])
         radii = np.array([r for _, _, r in level_nodes])
         nids = np.array([i for i, _, _ in level_nodes])
-        parent_groups = group(np.arange(len(level_nodes)), target, routing)
+        rdists = _BuildDists(routing, metric)
+        parent_groups = _bisect(rdists, len(level_nodes), target, min_fill,
+                                rng)
         next_level = []
-        for g in parent_groups:
+        for g, mi, d_to_m in zip(parent_groups,
+                                 *_medoids(rdists, parent_groups, radii)):
             P = routing[g]
             rg = radii[g]
-            mi = medoid(P, rg)
-            d_to_m = np.asarray(mfn(P[mi][None, :], P))
             nid = len(nodes)
             nodes.append(dict(is_leaf=False, vecs=P, radius=rg, pdist=d_to_m,
                               oid=np.full(len(g), -1), child=nids[g]))
@@ -482,6 +589,17 @@ def level_grid_steps(height: int, capacity: int, F: int,
             + [sum(steps(wc) for _, wc in leaf_chunks(widths[-1]))])
 
 
+def kernel_work(height: int, capacity: int, F: int, dim: int) -> dict:
+    """What the frontier kernel does for a query row, as
+    ``obs.observe_query_result`` counts it: the slots (``widths``) and
+    grid steps (``grid_steps``) of each level, and the bytes it copies for
+    one live slot (``page_bytes``)."""
+    from repro.kernels.frontier import page_bytes
+    return dict(widths=level_widths(height, capacity, F),
+                grid_steps=level_grid_steps(height, capacity, F, dim),
+                page_bytes=page_bytes(capacity, dim))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("k", "F", "height", "impl", "interpret",
                                     "level_stats", "prune"))
@@ -576,17 +694,18 @@ def _knn_cohort(tree: TreeArrays, queries: jax.Array, r_cap, *, k: int,
             # pre-filter needs a tight r_q.  It feeds r_q in the pruned
             # AND unpruned traces (identical values), so on/off bitwise
             # identity is untouched.
-            pd_ub = tree.pdist[nodes] + tree.radius[nodes]   # [b, w, cap]
-            ok = tree.valid[nodes] & fvalid[:, :, None]
-            ubnd = jnp.where(ok, qpd[:, :, None] + pd_ub,
-                             _INF).reshape(b, w * cap)
-            j_pre = -(-k // max(1, tree.min_fill) ** (height - 1 - lvl))
-            if j_pre == 1:
-                ub = jnp.minimum(ub, jnp.min(ubnd, axis=1) + _EPS)
-            elif j_pre <= w * cap:
-                ub = jnp.minimum(
-                    ub, -jax.lax.top_k(-ubnd, j_pre)[0][:, j_pre - 1]
-                    + _EPS)
+            with jax.named_scope(BOUND_SCOPE):
+                pd_ub = tree.pdist[nodes] + tree.radius[nodes]  # [b, w, cap]
+                ok = tree.valid[nodes] & fvalid[:, :, None]
+                ubnd = jnp.where(ok, qpd[:, :, None] + pd_ub,
+                                 _INF).reshape(b, w * cap)
+                j_pre = -(-k // max(1, tree.min_fill) ** (height - 1 - lvl))
+                if j_pre == 1:
+                    ub = jnp.minimum(ub, jnp.min(ubnd, axis=1) + _EPS)
+                elif j_pre <= w * cap:
+                    ub = jnp.minimum(
+                        ub, -jax.lax.top_k(-ubnd, j_pre)[0][:, j_pre - 1]
+                        + _EPS)
 
         if lvl < height - 1:
             if use_filter:
@@ -806,12 +925,23 @@ def _knn_perquery(tree: TreeArrays, queries: jax.Array, k: int, F: int,
 # --------------------------------------------------------------------------
 # Jitted insert fast path + host-side split fallback
 # --------------------------------------------------------------------------
+def _node_page(vecs: jax.Array, node: jax.Array) -> jax.Array:
+    """``vecs[node]`` [cap, dim] (``node`` clipped into range).  On a TPU
+    a Pallas copy reads it, which keeps a loop's pages in the tree's own
+    layout (``kernels.frontier.node_page_pallas``); elsewhere plain
+    indexing, with the same values."""
+    from repro.kernels.frontier import node_page_pallas
+    node = jnp.clip(node, 0, vecs.shape[0] - 1)
+    return jax.lax.platform_dependent(
+        vecs, node, tpu=node_page_pallas, default=lambda v, n: v[n])
+
+
 def _descend_path(tree: TreeArrays, x: jax.Array):
     """SM-tree choose-subtree (closest entry) from root to leaf.
     Returns (path_nodes [MAX_HEIGHT], path_slots [MAX_HEIGHT], leaf_id)."""
     def body(state):
         node, lvl, pn, ps = state
-        d = _metric_eval(tree.metric, x[None, :], tree.vecs[node])
+        d = _metric_eval(tree.metric, x[None, :], _node_page(tree.vecs, node))
         d = jnp.where(tree.valid[node], d, _INF)
         slot = jnp.argmin(d)
         pn = pn.at[lvl].set(node)
@@ -992,7 +1122,10 @@ def _apply_row(t: TreeArrays, vecs0: jax.Array, op, x, oid, leaf0, found0):
         touch internal-node rows, which the fast path never writes, so the
         values are identical; reading the carried ``t.vecs`` instead would
         put a gather and a scatter on the same buffer in one fusion, which
-        XLA resolves by copying all of ``vecs`` every step.
+        XLA resolves by copying all of ``vecs`` every step.  The descent
+        reads each page through ``_node_page``: on a TPU, plain indexing
+        there makes XLA copy all of ``vecs0`` into a capacity-minor layout
+        on loop entry (four times the pages at 32 x 3,072).
       * the delete target's leaf (``leaf0``/``found0``) is located once,
         vectorised, before the scan (``_locate_oids``): within a
         conflict-free batch nothing moves an object across leaves, so only

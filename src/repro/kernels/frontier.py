@@ -47,7 +47,13 @@ in clustered search), so the step is sized to the block, not the slot:
 A copy may only slice whole (8, 128) tiles of an HBM operand, so the
 wrapper pads ``vecs`` and the per-entry page to whole tiles where the
 shapes are not (one copy of the tree's pages a descent: XLA shares it
-between the descent's calls).
+between the descent's calls); pages that are whole tiles already (a
+capacity that is a multiple of 8 at a dim that is a multiple of 128, as
+a kNN-LM datastore's 32 x 3,072) go to the kernel as the tree holds them,
+with no copy.  The id table and the block tops are scalar-prefetched into
+SMEM, so a level too wide for it is scored by several calls, each over a
+column chunk of whole blocks; a call whose resident blocks outgrow the
+default scoped VMEM asks for more (``_vmem_limit``).
 
 Parent-distance pre-filter (DESIGN.md §17): when the caller supplies the
 ``pdist`` page (d(entry, parent routing object), maintained by every
@@ -111,6 +117,18 @@ _PAGE_VMEM_BYTES = 4 * 2**20
 _MAX_BLOCK = 32
 # rows of a node's per-entry page: radius, entry kind, pdist
 _META_ROWS = 3
+# The compiler's default scoped VMEM on a v5e.  A call whose resident
+# blocks would not fit under three quarters of it (wide levels of large
+# pages: a 3,072-slot leaf chunk's four [3072, cap] output rows of a
+# query take 12 MiB double-buffered) asks for a larger limit, with room
+# for the compiler's own scratch; every other call keeps the default.
+_SCOPED_VMEM_BYTES = 16 * 2**20
+_VMEM_HEADROOM = 8 * 2**20
+# The frontier's id table and its block tops are scalar-prefetched into
+# SMEM, 1 MiB on a v5e.  A call whose tables would pass this share of it
+# (a level wider than about 3,700 slots at a cohort of 64) is split into
+# calls over column chunks of whole blocks.
+_SMEM_TABLE_BYTES = 960 * 2**10
 
 
 def _vmem_bytes(rows: int, cols: int) -> int:
@@ -131,6 +149,29 @@ def block_slots(w: int, cap: int, dim: int) -> int:
     if g >= 8:
         g -= g % 8
     return max(1, min(g, w))
+
+
+def _vmem_limit(wo: int, cap: int, dim: int, g: int, qb: int,
+                meta_rows: int) -> int | None:
+    """Scoped VMEM a call asks for: ``None`` (the compiler's default)
+    where its blocks fit in three quarters of it, else their size plus
+    ``_VMEM_HEADROOM``.  Blocks: the four ``[wo, cap]`` output rows and
+    the ``[qb, dim]`` query block, each double-buffered, and the two
+    ``g``-page buffers of the scratch."""
+    need = (2 * 4 * _vmem_bytes(wo, cap) + 2 * _vmem_bytes(qb, dim)
+            + 2 * g * (_vmem_bytes(cap, dim) + _vmem_bytes(meta_rows, cap)))
+    if 4 * need <= 3 * _SCOPED_VMEM_BYTES:
+        return None
+    return need + _VMEM_HEADROOM
+
+
+def page_bytes(cap: int, dim: int) -> int:
+    """Bytes the kernel copies HBM->VMEM for one live frontier slot: the
+    node's page ``[cap, dim]`` and its per-entry rows, each tile-padded to
+    (8, 128) as the wrapper hands them over (``_tile_pad``; the two rows
+    of an unfiltered level pad to the same tile as the three of a
+    filtered one)."""
+    return _vmem_bytes(cap, dim) + _vmem_bytes(_META_ROWS, cap)
 
 
 def _block_tops(fids, g: int):
@@ -301,6 +342,34 @@ def frontier_scores_pallas(fids, queries, vecs, radius, internal_valid,
     b, w = fids.shape
     _, cap, dim = vecs.shape
     g = block_slots(w, cap, dim)
+    # one [R, cap] per-entry page per node, so a slot needs two copies
+    kind = ((internal_valid != 0).astype(jnp.float32)
+            + 2.0 * (leaf_valid != 0).astype(jnp.float32))
+    rows = (radius, kind, pdist) if prune else (radius, kind)
+    meta = _tile_pad(jnp.stack([x.astype(jnp.float32) for x in rows],
+                               axis=1))
+    vecs = _tile_pad(vecs)
+    # a level whose id table would not fit SMEM is scored in column chunks
+    # of whole blocks, each its own call (the slots are independent)
+    wc = g * max(1, _SMEM_TABLE_BYTES // (4 * b * (g + 1)))
+    outs = [_score_columns(fids[:, c0:c0 + wc], queries, vecs, meta,
+                           qpd[:, c0:c0 + wc] if prune else None, rq,
+                           cap=cap, g=g, metric=metric, interpret=interpret)
+            for c0 in range(0, w, wc)]
+    return tuple(o[0] if len(outs) == 1 else jnp.concatenate(o, axis=1)
+                 for o in zip(*outs))
+
+
+def _score_columns(fids, queries, vecs, meta, qpd, rq, *, cap: int, g: int,
+                   metric: str, interpret: bool):
+    """One ``pallas_call`` over the frontier slots ``fids`` [b, w] in
+    blocks of ``g`` (of ``cap`` entries a node); ``vecs`` and ``meta`` are
+    tile-padded, ``qpd`` and ``rq`` given with the parent filter (else
+    None)."""
+    prune = qpd is not None
+    b, w = fids.shape
+    dim = queries.shape[1]
+    g = min(g, w)
     nblk = -(-w // g)
     wp = nblk * g
     # the outputs' resident block of a query: the whole row, or (past a
@@ -310,13 +379,6 @@ def frontier_scores_pallas(fids, queries, vecs, radius, internal_valid,
     # tops go flat into SMEM (a 2-D SMEM array pads its rows to 128 words)
     tops = _block_tops(fids, g).reshape(-1)
     fids = jnp.pad(fids, ((0, 0), (0, wp - w)), constant_values=-1)
-    # one [R, cap] per-entry page per node, so a slot needs two copies
-    kind = ((internal_valid != 0).astype(jnp.float32)
-            + 2.0 * (leaf_valid != 0).astype(jnp.float32))
-    rows = (radius, kind, pdist) if prune else (radius, kind)
-    meta = _tile_pad(jnp.stack([x.astype(jnp.float32) for x in rows],
-                               axis=1))
-    vecs = _tile_pad(vecs)
     # 8-row blocks satisfy the TPU's sublane rule; a smaller array is one
     # whole block (a block dim equal to the array dim is always legal)
     qb = min(b, 8)
@@ -356,7 +418,9 @@ def frontier_scores_pallas(fids, queries, vecs, radius, internal_valid,
         # the copies of the next step start in this one: the grid runs in
         # order on both axes
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(wo, cap, dim, g, qb,
+                                         meta.shape[1])),
         interpret=interpret,
     )(*operands)
 
@@ -419,3 +483,30 @@ def frontier_scores(fids, queries, vecs, radius, internal_valid, leaf_valid,
     return frontier_scores_xla(
         fids, queries, vecs, radius, internal_valid, leaf_valid,
         metric=metric, pdist=pdist, qpd=qpd, rq=rq)
+
+
+def node_page_pallas(vecs, node, *, interpret: bool = False):
+    """``vecs[node]`` ([cap, dim]) by one Pallas block copy, ``node`` an
+    in-range i32 scalar.
+
+    A Mosaic call takes its operands in the array's own row-major layout,
+    so a loop that reads pages this way keeps the tree's pages as they
+    are.  Read by plain indexing inside a loop, XLA lays the loop-carried
+    pages out for the row's coordinate fold instead (capacity-minor, the
+    capacity padded to 128 lanes) and copies every page into that layout
+    on entry: 29.5 GB for a kNN-LM datastore's 18,726 x 32 x 3,072."""
+    _, cap, dim = vecs.shape
+
+    def body(node_ref, page_ref, out_ref):
+        out_ref[...] = page_ref[...]
+
+    return pl.pallas_call(
+        body,
+        out_shape=jax.ShapeDtypeStruct((cap, dim), vecs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec((None, cap, dim),
+                                   lambda i, n: (n[0], 0, 0))],
+            out_specs=pl.BlockSpec((cap, dim), lambda i, n: (0, 0))),
+        interpret=interpret,
+    )(jnp.reshape(node, (1,)).astype(jnp.int32), vecs)

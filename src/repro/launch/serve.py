@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import time
 
 import jax
@@ -42,15 +43,24 @@ def _dump_obs(args) -> None:
     print(f"[obs] {body}", flush=True)
 
 
-def _build_store(args, cfg, mesh=None):
-    """Synthetic kNN-LM datastore (keys near the embedding scale); with a
-    mesh the tree pages replicate and query cohorts shard over 'data'."""
-    from repro.serve.knnlm import KnnLmConfig, KnnLmDatastore
-    rng = np.random.default_rng(0)
-    keys = rng.standard_normal((2048, cfg.d_model)).astype(np.float32)
-    vals = rng.integers(0, cfg.vocab_size, 2048).astype(np.int32)
-    store = KnnLmDatastore(KnnLmConfig(lam=args.lam, metric="l2"),
-                           cfg.d_model, mesh=mesh)
+# the datastore's token stream: 16 sequences of 129 tokens give 2,048
+# (key, next token) entries
+STORE_SEQS, STORE_SEQ_LEN = 16, 129
+
+
+def _build_store(args, cfg, params, mesh=None):
+    """kNN-LM datastore keyed as the method publishes it: the model's own
+    pre-head hidden states over a synthetic token stream, each valued by
+    the token that follows it, searched by ``KnnLmConfig``'s metric and
+    ``k``.  With a mesh the tree pages replicate and query cohorts shard
+    over 'data'."""
+    from repro.serve.knnlm import (KnnLmConfig, KnnLmDatastore,
+                                   datastore_entries)
+    dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=STORE_SEQ_LEN,
+                    global_batch=STORE_SEQS)
+    keys, vals = datastore_entries(
+        params, cfg, synth_batch(dc, 1, with_labels=False)["tokens"])
+    store = KnnLmDatastore(KnnLmConfig(lam=args.lam), cfg.d_model, mesh=mesh)
     store.build(keys, vals)
     replicas = getattr(args, "replicas", 0)
     if getattr(args, "knn_mutate", False) or getattr(args, "frontend", False):
@@ -145,7 +155,8 @@ def serve_sharded(args, cfg):
     frontier fast path against replicated tree pages."""
     from repro.configs.base import ShapeSpec
     from repro.dist import sharding as shd
-    from repro.serve.serve_step import make_decode_step, make_knnlm_mixer
+    from repro.serve.serve_step import (ServeSettings, make_decode_step,
+                                        make_knnlm_mixer)
 
     n_dev = len(jax.devices())
     nm = 2 if n_dev % 2 == 0 else 1
@@ -157,12 +168,16 @@ def serve_sharded(args, cfg):
     prompt = jnp.asarray(synth_batch(dc, 0, with_labels=False)["tokens"])
 
     with shd.use_mesh(mesh):
-        fn, sh = make_decode_step(cfg, mesh, shape)
+        # with --knn the step also returns its pre-head hidden state: the
+        # datastore's query
+        fn, sh = make_decode_step(cfg, mesh, shape,
+                                  ServeSettings(hidden=args.knn))
         jitted = jax.jit(fn,
                          in_shardings=(sh["params"], sh["token"],
                                        sh["cache"], sh["pos"]),
                          out_shardings=(sh["token"], sh["logits"],
-                                        sh["cache"]),
+                                        sh["cache"])
+                         + ((sh["hidden"],) if args.knn else ()),
                          donate_argnums=(2,))
         params = jax.jit(M.init_params, static_argnums=0,
                          out_shardings=sh["params"])(
@@ -173,24 +188,23 @@ def serve_sharded(args, cfg):
         mutator = None
         store = None
         if args.knn:
-            store = _build_store(args, cfg, mesh=mesh)
+            store = _build_store(args, cfg, params, mesh=mesh)
             mix_fn, _ = make_knnlm_mixer(cfg, mesh, shape, store,
                                          lam=args.lam)
             if args.knn_mutate:
                 mutator = _WindowMutator(store)
         t0 = time.time()
         for pos in range(args.prompt_len):
-            tok, logits, cache = jitted(params, prompt[:, pos], cache,
-                                        jnp.int32(pos))
+            tok, logits, cache, *_ = jitted(params, prompt[:, pos], cache,
+                                            jnp.int32(pos))
         prefill_s = time.time() - t0
         out = [tok]
         t0 = time.time()
         for step in range(args.steps):
-            fed = tok   # the step's input token (matches single-device path)
-            tok, logits, cache = jitted(params, fed, cache,
-                                        jnp.int32(args.prompt_len + step))
+            tok, logits, cache, *hs = jitted(
+                params, tok, cache, jnp.int32(args.prompt_len + step))
             if mix_fn is not None:
-                h = params["embed"][fed].astype(jnp.float32)
+                h = hs[0]
                 tok = jnp.argmax(mix_fn(logits, h), -1).astype(jnp.int32)
                 if mutator is not None:
                     mutator.step(h, tok)
@@ -220,17 +234,20 @@ def serve_single(args, cfg):
                     global_batch=args.batch)
     prompt = jnp.asarray(synth_batch(dc, 0, with_labels=False)["tokens"])
 
-    store = _build_store(args, cfg) if args.knn else None
+    store = _build_store(args, cfg, params) if args.knn else None
     mutator = (_WindowMutator(store)
                if store is not None and args.knn_mutate else None)
 
     cache = M.init_cache(cfg, args.batch, args.prompt_len + args.steps + 1)
-    step_fn = jax.jit(M.decode_step, static_argnums=1)
+    # each step also returns its pre-head hidden state: the datastore's
+    # query with --knn
+    step_fn = jax.jit(functools.partial(M.decode_step, hidden=True),
+                      static_argnums=1)
 
     t0 = time.time()
     for pos in range(args.prompt_len):
-        logits, cache = step_fn(params, cfg, prompt[:, pos], cache,
-                                jnp.int32(pos))
+        logits, cache, _ = step_fn(params, cfg, prompt[:, pos], cache,
+                                   jnp.int32(pos))
     prefill_s = time.time() - t0
 
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -238,10 +255,10 @@ def serve_single(args, cfg):
     t0 = time.time()
     for step in range(args.steps):
         pos = args.prompt_len + step
-        logits, cache = step_fn(params, cfg, tok, cache, jnp.int32(pos))
+        logits, cache, h = step_fn(params, cfg, tok, cache, jnp.int32(pos))
         if store is not None:
             from repro.serve.knnlm import mix_logits
-            h = params["embed"][tok].astype(jnp.float32)
+            h = h.astype(jnp.float32)
             logits = mix_logits(logits, store.knn_logits(
                 h, logits.shape[-1]), args.lam)
         tok = jnp.argmax(logits, -1).astype(jnp.int32)

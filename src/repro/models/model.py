@@ -19,14 +19,23 @@ def init_params(cfg: ArchConfig, rng) -> dict:
     return transformer.init_lm(cfg, rng)
 
 
+def _no_hidden(cfg: ArchConfig, hidden: bool) -> None:
+    if hidden and cfg.is_encdec:
+        raise ValueError("pre-head hidden states are returned for "
+                         "decoder-only LMs")
+
+
 def forward(params, cfg: ArchConfig, batch: dict, *, remat: bool = False,
-            attn_impl: str | None = None):
-    """Full-sequence forward -> (logits, aux)."""
+            attn_impl: str | None = None, hidden: bool = False):
+    """Full-sequence forward -> (logits, aux); with ``hidden`` (decoder-only
+    LMs) -> (logits, aux, pre-head hidden states [b, s, D]): the states the
+    output head reads, after the final norm, which key a kNN-LM."""
+    _no_hidden(cfg, hidden)
     if cfg.is_encdec:
         return encdec.encdec_forward(params, cfg, batch, remat=remat,
                                      attn_impl=attn_impl)
     return transformer.lm_forward(params, cfg, batch, remat=remat,
-                                  attn_impl=attn_impl)
+                                  attn_impl=attn_impl, hidden=hidden)
 
 
 def init_cache(cfg: ArchConfig, batch: int, length: int, dtype=None):
@@ -35,11 +44,16 @@ def init_cache(cfg: ArchConfig, batch: int, length: int, dtype=None):
     return transformer.init_cache(cfg, batch, length, dtype=dtype)
 
 
-def decode_step(params, cfg: ArchConfig, token, cache, pos_scalar):
-    """One-token decode with cache -> (logits [b, V], new cache)."""
+def decode_step(params, cfg: ArchConfig, token, cache, pos_scalar, *,
+                hidden: bool = False):
+    """One-token decode with cache -> (logits [b, V], new cache); with
+    ``hidden`` (decoder-only LMs) -> (logits, cache, the step's pre-head
+    hidden state [b, D])."""
+    _no_hidden(cfg, hidden)
     if cfg.is_encdec:
         return encdec.encdec_decode_step(params, cfg, token, cache, pos_scalar)
-    return transformer.lm_decode_step(params, cfg, token, cache, pos_scalar)
+    return transformer.lm_decode_step(params, cfg, token, cache, pos_scalar,
+                                      hidden=hidden)
 
 
 def loss_fn(logits, labels, mask):

@@ -116,8 +116,10 @@ def embed_inputs(params, cfg: ArchConfig, batch: dict):
 
 
 def lm_forward(params, cfg: ArchConfig, batch: dict, *, remat: bool = False,
-               attn_impl: str | None = None):
-    """Full-sequence forward.  Returns (logits [b,s,V], aux dict)."""
+               attn_impl: str | None = None, hidden: bool = False):
+    """Full-sequence forward.  Returns (logits [b,s,V], aux dict), and with
+    ``hidden`` the pre-head hidden states [b,s,D] (after the final norm)
+    as a third element."""
     x, pos = embed_inputs(params, cfg, batch)
     x = constrain_batch_seq(x)   # pin DP before the layer scan (GSPMD would
                                  # otherwise happily replicate the batch)
@@ -147,7 +149,7 @@ def lm_forward(params, cfg: ArchConfig, batch: dict, *, remat: bool = False,
     denom = max(1, n_moe * cfg.n_periods)
     aux = {"lb_loss": aux_sums[0] / denom, "z_loss": aux_sums[1] / denom,
            "drop_frac": aux_sums[2] / denom}
-    return logits, aux
+    return (logits, aux, x) if hidden else (logits, aux)
 
 
 # ---------------------------------------------------------------------------
@@ -204,8 +206,11 @@ def _block_decode(kind, p, cfg, x1, cslice, pos_scalar):
     return x1, new_c
 
 
-def lm_decode_step(params, cfg: ArchConfig, token, cache, pos_scalar):
-    """token: [b] int32; pos_scalar: [] int32.  Returns (logits [b,V], cache)."""
+def lm_decode_step(params, cfg: ArchConfig, token, cache, pos_scalar, *,
+                   hidden: bool = False):
+    """token: [b] int32; pos_scalar: [] int32.  Returns (logits [b,V],
+    cache), and with ``hidden`` the step's pre-head hidden state [b,D]
+    as a third element."""
     x = params["embed"][token][:, None, :].astype(jnp.dtype(cfg.compute_dtype))
     if cfg.pos_embedding == "learned":
         x = x + params["pos_embed"][pos_scalar][None, None].astype(x.dtype)
@@ -227,4 +232,6 @@ def lm_decode_step(params, cfg: ArchConfig, token, cache, pos_scalar):
         logits = x @ params["embed"].T.astype(x.dtype)
     else:
         logits = dense(params["lm_head"], x)
+    if hidden:
+        return logits[:, 0], new_cache, x[:, 0]
     return logits[:, 0], new_cache

@@ -160,7 +160,8 @@ def want_level_stats() -> bool:
 
 def observe_query_result(res, pruned=None, *, prefix: str = "descent",
                          rows: int | None = None,
-                         widths=None, grid_steps=None) -> None:
+                         widths=None, grid_steps=None,
+                         page_bytes: int | None = None) -> None:
     """Accumulate the descent's per-dispatch reductions into paper-level
     counters: metric (distance) evaluations, nodes visited, and — when
     the kernel was asked for level stats — pruned-by-bound and
@@ -175,6 +176,9 @@ def observe_query_result(res, pruned=None, *, prefix: str = "descent",
     live node.  ``grid_steps``, the frontier kernel's grid steps a row
     runs at each level (``smtree.level_grid_steps``), likewise adds
     ``{prefix}.grid_steps_total``: rows x sum(grid_steps).
+    ``page_bytes``, what the frontier kernel copies for one live slot
+    (``smtree.kernel_work``), adds ``{prefix}.page_bytes_total``
+    beside them: nodes visited x page_bytes.
 
     ``pruned`` is what ``smtree.knn(..., level_stats=True)`` returned:
     a ``(by_bound, by_parent, live_blocks)`` triple of ``[levels, b]``
@@ -210,6 +214,9 @@ def observe_query_result(res, pruned=None, *, prefix: str = "descent",
     if grid_steps is not None:
         REGISTRY.counter(f"{prefix}.grid_steps_total").inc(
             b * int(sum(grid_steps)))
+    if page_bytes is not None:
+        REGISTRY.counter(f"{prefix}.page_bytes_total").inc(
+            nodes * int(page_bytes))
     if overflow:
         REGISTRY.counter(f"{prefix}.frontier_overflow_total").inc(overflow)
     if pruned is None:

@@ -130,15 +130,11 @@ def pinned_knn(pinned, queries: np.ndarray, *, k: int, max_frontier: int):
             ids.append(np.asarray(res.ids))
             if sampled:
                 # the cohort descent's by-parent stack has one row a level
-                widths = steps = None
+                work = {}
                 if pruned is not None:
-                    height = pruned[1].shape[0]
-                    widths = smtree.level_widths(height, t.capacity,
-                                                 max_frontier)
-                    steps = smtree.level_grid_steps(height, t.capacity,
-                                                    max_frontier, t.dim)
-                obs.observe_query_result(res, pruned, rows=rows,
-                                         widths=widths, grid_steps=steps)
+                    work = smtree.kernel_work(pruned[1].shape[0], t.capacity,
+                                              max_frontier, t.dim)
+                obs.observe_query_result(res, pruned, rows=rows, **work)
         d = np.concatenate(ds, axis=1)
         i = np.concatenate(ids, axis=1)
         order = np.argsort(d, axis=1, kind="stable")[:, :k]

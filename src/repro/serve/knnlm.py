@@ -350,31 +350,13 @@ def mix_logits(lm_logits: jax.Array, knn_logp: jax.Array, lam: float):
     return jnp.logaddexp(lm_logp + jnp.log1p(-lam), knn_logp + jnp.log(lam))
 
 
-def decode_with_knnlm(params, cfg: ArchConfig, store: KnnLmDatastore,
-                      prompt: jax.Array, n_steps: int, *, lam=None):
-    """Greedy decode with kNN-LM mixing; also streams (h, next_token) pairs
-    back into the datastore (online growth).  prompt: [b, s0]."""
-    lam = lam if lam is not None else store.cfg.lam
-    b, s0 = prompt.shape
-    cache = M.init_cache(cfg, b, s0 + n_steps + 1)
-    tok = prompt[:, 0]
-    h_tap = {}
-
-    # feed the prompt
-    for pos in range(s0):
-        logits, cache = M.decode_step(params, cfg, prompt[:, pos], cache,
-                                      jnp.int32(pos))
-    out = []
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    for step in range(n_steps):
-        pos = s0 + step
-        logits, cache = M.decode_step(params, cfg, tok, cache, jnp.int32(pos))
-        # final hidden state proxy: use logits projected back is costly; we
-        # tap the embedding of the argmax as a cheap key in this reference
-        # driver (examples/knnlm_serve.py uses the true pre-head hidden)
-        h = params["embed"][tok].astype(jnp.float32)
-        knn_logp = store.knn_logits(h, logits.shape[-1])
-        mixed = mix_logits(logits, knn_logp, lam)
-        tok = jnp.argmax(mixed, -1).astype(jnp.int32)
-        out.append(tok)
-    return jnp.stack(out, axis=1)
+def datastore_entries(params, cfg: ArchConfig, tokens):
+    """(keys [n, D] f32, values [n] i32) of token streams [b, s]: the
+    pre-head hidden state at each position but the last, and the token
+    that follows it (Khandelwal et al., section 3)."""
+    # jitted, so the output head (whose logits nobody reads) is not run
+    fwd = jax.jit(lambda p, t: M.forward(p, cfg, {"tokens": t},
+                                         hidden=True)[2])
+    h = np.asarray(fwd(params, jnp.asarray(tokens)), np.float32)
+    keys = h[:, :-1].reshape(-1, h.shape[-1])
+    return keys, np.asarray(tokens)[:, 1:].reshape(-1).astype(np.int32)

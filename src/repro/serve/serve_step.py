@@ -22,20 +22,24 @@ class ServeSettings:
     temperature: float = 1.0
     greedy: bool = True
     seq_shard_cache: bool = False   # long-context: shard KV cache over seq
+    hidden: bool = False   # also return the step's pre-head hidden state
 
 
 def make_decode_step(cfg: ArchConfig, mesh, shape: ShapeSpec,
                      settings: ServeSettings = ServeSettings()):
     """Returns (decode_fn, shardings).  decode_fn(params, token, cache, pos)
-    -> (next_token, logits, cache)."""
+    -> (next_token, logits, cache), and with ``settings.hidden`` the step's
+    pre-head hidden state [b, D] (a kNN-LM datastore's query) as a fourth
+    element (sharding ``shardings["hidden"]``)."""
 
     def decode_fn(params, token, cache, pos):
-        logits, cache = M.decode_step(params, cfg, token, cache, pos)
+        logits, cache, *h = M.decode_step(params, cfg, token, cache, pos,
+                                          hidden=settings.hidden)
         if settings.greedy:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         else:
             nxt = jnp.argmax(logits / settings.temperature, -1).astype(jnp.int32)
-        return nxt, logits, cache
+        return (nxt, logits, cache, *h)
 
     pspecs = shd.param_pspecs(cfg, M.param_specs(cfg), mesh)
     param_sh = shd.to_named(pspecs, mesh)
@@ -54,6 +58,8 @@ def make_decode_step(cfg: ArchConfig, mesh, shape: ShapeSpec,
                                       "model"))
     shardings = dict(params=param_sh, cache=cache_sh, token=token_sh,
                      logits=logits_sh, pos=NamedSharding(mesh, P()),
+                     hidden=NamedSharding(mesh, P(tok_spec[0] if tok_spec
+                                                  else None, None)),
                      pspecs=pspecs)
     return decode_fn, shardings
 

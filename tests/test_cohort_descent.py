@@ -382,3 +382,48 @@ def test_l1_metric_end_to_end():
     assert not np.asarray(a.overflow).any()
     np.testing.assert_allclose(np.asarray(a.dists),
                                brute_knn_dists("l1", X, Q, 4), atol=1e-5)
+
+
+# --------------------------------------------------------------------------
+# The kNN-LM datastore's query: k = 1,024 over L2 keys of 3,072 dims
+# --------------------------------------------------------------------------
+def test_knn_k1024_l2_dim3072_matches_brute_force():
+    """A few thousand clustered keys at StarCoder2-3B's width, k = 1,024 (a
+    parent-distance bound over 1,024 leaf entries, top-k cuts of k rows):
+    the served distances are the brute force's, each served id sits at its
+    distance, and a frontier above the leaf count never overflows."""
+    from repro.core import smtree
+    rng = np.random.default_rng(1024)
+    centres = rng.normal(size=(24, 3072)).astype(np.float32)
+    X = (centres[rng.integers(0, 24, 3000)]
+         + 0.3 * rng.normal(size=(3000, 3072))).astype(np.float32)
+    Q = (centres[:2] + 0.3 * rng.normal(size=(2, 3072))).astype(np.float32)
+    tree = smtree.bulk_build(X, capacity=32, metric="l2", seed=2)
+    res = smtree.knn(tree, Q, k=1024, max_frontier=256, impl="xla")
+    want = np.sqrt(((Q[:, None, :].astype(np.float64) - X[None]) ** 2)
+                   .sum(-1))
+    d, ids = np.asarray(res.dists), np.asarray(res.ids)
+    np.testing.assert_allclose(d, np.sort(want, axis=1)[:, :1024],
+                               rtol=1e-5)
+    np.testing.assert_allclose(d, np.take_along_axis(want, ids, 1),
+                               rtol=1e-5)
+    assert (np.sort(ids, axis=1)[:, 1:] != np.sort(ids, axis=1)[:, :-1]).all()
+    assert not np.asarray(res.overflow).any()
+
+
+@pytest.mark.parametrize("metric", ["d_inf", "l2", "l1"])
+def test_bulk_build_on_device_equals_host(metric, monkeypatch):
+    """The build's distances on the default device (the path of large
+    corpora) give the tree the host's numpy gives, bit for bit (the shared
+    metric's fixed fold)."""
+    from repro.core import smtree
+    rng = np.random.default_rng(7)
+    X = (rng.random((1500, 40)) ** 3).astype(np.float32)
+    X[100:160] = X[100]                       # duplicates: tied pivots
+    host = smtree.bulk_build(X, capacity=16, metric=metric, seed=4)
+    monkeypatch.setattr(smtree, "_DEVICE_BUILD_ELEMS", 1)
+    dev = smtree.bulk_build(X, capacity=16, metric=metric, seed=4)
+    for f in ("vecs", "radius", "pdist", "child", "oid", "valid", "count",
+              "is_leaf", "parent", "pslot", "root", "height"):
+        np.testing.assert_array_equal(np.asarray(getattr(host, f)),
+                                      np.asarray(getattr(dev, f)), err_msg=f)

@@ -312,3 +312,82 @@ def test_live_blocks_counts_blocks_with_a_live_id():
         np.testing.assert_array_equal(
             np.asarray(frontier.live_blocks(jnp.asarray(f), 42, 20)),
             want.sum(1))
+
+
+# ---------------------------------------------------------------------------
+# The kNN-LM datastore's page: capacity 32 at StarCoder2-3B's width, 3,072
+# dims (a whole number of lane tiles, so the wrapper hands the kernel the
+# tree's own pages), L2, whose fold of 3 x 2^10 coordinates carries an odd
+# tail.  block_slots gives 5 slots a step, so 12 slots are two full blocks
+# and a partial one.
+
+@pytest.mark.parametrize("prune", [False, True], ids=["plain", "parent_prune"])
+def test_knnlm_page_kernel_matches_xla_bitwise(prune):
+    rng = np.random.default_rng(3072)
+    cap, dim, w = 32, 3072, 12
+    fids, queries, vecs, radius, iv, lv = _blocked_case(rng, w, cap, dim,
+                                                        N=16)
+    assert frontier.block_slots(w, cap, dim) == 5
+    kw = {}
+    if prune:
+        pdist, qpd, rq = _random_prune_inputs(rng, fids, vecs.shape[0], cap)
+        kw = dict(pdist=pdist, qpd=qpd, rq=rq)
+    got = frontier_scores_pallas(fids, queries, vecs, radius, iv, lv,
+                                 metric="l2", interpret=True, **kw)
+    want = frontier_scores_xla(fids, queries, vecs, radius, iv, lv,
+                               metric="l2", **kw)
+    for gv, wv, name in zip(got, want, OUT_NAMES):
+        np.testing.assert_array_equal(np.asarray(gv), np.asarray(wv),
+                                      err_msg=name)
+    assert np.isfinite(np.asarray(got[2])).any()
+
+
+def test_page_bytes_and_vmem_limit():
+    """A live slot copies its tile-padded page and per-entry rows; only
+    calls whose blocks outgrow the default scoped VMEM ask for more."""
+    assert frontier.page_bytes(42, 20) == 48 * 128 * 4 + 8 * 128 * 4
+    assert frontier.page_bytes(32, 3072) == 32 * 3072 * 4 + 8 * 128 * 4
+    for w, cap, dim in ((441, 42, 20), (2048, 42, 20), (1024, 32, 3072)):
+        g = frontier.block_slots(w, cap, dim)
+        assert frontier._vmem_limit(w, cap, dim, g, 8, 8) is None
+    g = frontier.block_slots(3072, 32, 3072)
+    limit = frontier._vmem_limit(3072, 32, 3072, g, 8, 8)
+    assert frontier._SCOPED_VMEM_BYTES < limit < 64 * 2**20
+
+
+@pytest.mark.parametrize("prune", [False, True], ids=["plain", "parent_prune"])
+@pytest.mark.parametrize("w", [42, 441])
+def test_column_chunked_kernel_matches_xla_bitwise(monkeypatch, w, prune):
+    """A level whose id table would overflow SMEM is scored in several
+    calls over column chunks of whole blocks; the outputs are the XLA
+    path's, bit for bit (here the table's budget is cut so that a few
+    blocks make a chunk)."""
+    monkeypatch.setattr(frontier, "_SMEM_TABLE_BYTES", 4 * 3 * 9 * 3)
+    frontier_scores_pallas.clear_cache()
+    rng = np.random.default_rng(w + 5)
+    fids, queries, vecs, radius, iv, lv = _blocked_case(rng, w, 42, 20)
+    kw = {}
+    if prune:
+        pdist, qpd, rq = _random_prune_inputs(rng, fids, vecs.shape[0], 42)
+        kw = dict(pdist=pdist, qpd=qpd, rq=rq)
+    try:
+        got = frontier_scores_pallas(fids, queries, vecs, radius, iv, lv,
+                                     metric="d_inf", interpret=True, **kw)
+    finally:
+        frontier_scores_pallas.clear_cache()
+    want = frontier_scores_xla(fids, queries, vecs, radius, iv, lv,
+                               metric="d_inf", **kw)
+    for gv, wv, name in zip(got, want, OUT_NAMES):
+        np.testing.assert_array_equal(np.asarray(gv), np.asarray(wv),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("cap,dim", [(42, 20), (32, 3072)])
+def test_node_page_pallas_reads_the_page(cap, dim):
+    vecs = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (7, cap, dim)), jnp.float32)
+    for node in (0, 3, 6):
+        page = frontier.node_page_pallas(vecs, jnp.int32(node),
+                                         interpret=True)
+        np.testing.assert_array_equal(np.asarray(page),
+                                      np.asarray(vecs[node]))

@@ -151,6 +151,26 @@ def test_grid_steps_count_live_rows_only(obs_on):
         <= snap["descent.grid_steps_total"]
 
 
+def test_page_bytes_count_live_slots_of_live_rows(obs_on):
+    """Beside the grid counters, the sampled dispatch of a cohort of 5
+    rows padded to 16 counts the frontier kernel's page traffic: each
+    live slot of the 5 rows, the tile-padded page and its per-entry
+    rows."""
+    from repro.kernels.frontier import page_bytes
+    X = _points()
+    tree = bulk_build(X, capacity=8)
+    cfg = FrontendConfig(cohort_width=16, slo_ms=500.0, k=3,
+                         max_frontier=256)
+    with ServeFrontend(StreamingEngine(tree), cfg) as fe:
+        fe.knn(X[:5], timeout=60)
+    snap = obs.REGISTRY.snapshot()
+    assert page_bytes(tree.capacity, tree.dim) == 8 * 128 * 4 + 8 * 128 * 4
+    assert snap["descent.page_bytes_total"] == (
+        snap["descent.nodes_visited_total"]
+        * page_bytes(tree.capacity, tree.dim))
+    assert snap["descent.queries_total"] == 5
+
+
 def test_live_blocks_match_a_count_of_the_frontiers():
     """The level-stats descent's live-block stack equals a numpy count,
     over the frontier of every scoring call, of the blocks of
@@ -190,6 +210,14 @@ def test_live_blocks_match_a_count_of_the_frontiers():
             .sum(1)
     np.testing.assert_array_equal(blocks, want)
     assert blocks[-1].sum() > 0
+
+
+def test_kernel_work_gathers_the_counter_inputs():
+    from repro.kernels.frontier import page_bytes
+    work = smtree.kernel_work(3, 42, 2048, 20)
+    assert work == dict(widths=smtree.level_widths(3, 42, 2048),
+                        grid_steps=smtree.level_grid_steps(3, 42, 2048, 20),
+                        page_bytes=page_bytes(42, 20))
 
 
 def test_level_widths():

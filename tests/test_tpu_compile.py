@@ -82,3 +82,43 @@ def test_l2_distance_kernel_compiles(one_chip):
     s = functools.partial(_spec, one_chip)
     fn = jax.jit(functools.partial(pairwise_distance_pallas, metric="l2"))
     _assert_kernel(fn.lower(s((256, DIM)), s((65_536, DIM))).compile())
+
+
+# the kNN-LM datastore's shapes (262,144 keys of 3,072 dims in 18,685 node
+# slots of capacity 32, L2, a cohort of 64): an internal level's width, a
+# leaf chunk's, whose four output rows of a query outgrow the default
+# scoped VMEM, and a whole frontier's, whose id table outgrows SMEM (an
+# internal level of a taller tree).  The pages are whole tiles, so the
+# tree's own array goes to the kernel with no padded copy.
+@pytest.mark.parametrize("prune", [False, True], ids=["plain", "parent_prune"])
+@pytest.mark.parametrize("w", [1024, 3072, 12288])
+def test_knnlm_page_kernel_compiles(one_chip, w, prune):
+    s = functools.partial(_spec, one_chip)
+    n, b, cap, dim = 18_685, 64, 32, 3072
+    args = (s((b, w), jnp.int32), s((b, dim)), s((n, cap, dim)),
+            s((n, cap)), s((n, cap), jnp.bool_), s((n, cap), jnp.bool_))
+    kw = dict(pdist=s((n, cap)), qpd=s((b, w)), rq=s((b,))) if prune else {}
+    fn = jax.jit(functools.partial(frontier_scores_pallas, metric="l2"))
+    text = fn.lower(*args, **kw).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not [ln for ln in text.splitlines()
+                if " pad(" in ln and f"{n},{cap},{dim}" in ln]
+
+
+# The mutation scan reads routing pages inside its choose-subtree loop.
+# Read by plain indexing there, XLA copies every page of the tree into a
+# capacity-minor layout on entry (four times the tree at the kNN-LM
+# datastore's 32 x 3,072 page, 29.5 GB at its 18,726 slots); the Pallas
+# page read keeps them in place.  A tree of 512 slots shows it: the
+# program's scratch stays under one copy of the pages.
+def test_mutation_scan_keeps_the_page_layout(one_chip):
+    from repro.core import smtree
+    n, b, cap, dim = 512, 1024, 32, 3072
+    tree = jax.eval_shape(lambda: smtree.empty_tree(
+        dim=dim, capacity=cap, max_nodes=n, metric="l2"))
+    tree = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+    s = functools.partial(_spec, one_chip)
+    compiled = smtree._apply_mutations_jit(False).lower(
+        tree, s((b,), jnp.int32), s((b, dim)), s((b,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < n * cap * dim * 4
