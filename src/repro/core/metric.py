@@ -19,12 +19,14 @@ tree fold: the reduction tree depends only on the axis length, never on the
 leading shape or backend, so l1/l2 distances are *bitwise identical* whether
 evaluated on a ``[cap, dim]`` Pallas block, a ``[b, F, cap, dim]`` XLA
 gather, or a numpy array.  The engine's xla-vs-pallas parity guarantee
-(tests/test_cohort_descent.py) rests on this.
+(tests/test_cohort_descent.py) rests on this.  ``get_stages`` splits each
+metric where the kernel re-lays a wide page out (terms and the fold's first
+halvings along lanes, the rest over sublanes) without changing a bit.
 """
 from __future__ import annotations
 
 import functools
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import numpy as np
 
@@ -59,6 +61,19 @@ def _truncate(x, y, n_dims, axis):
     return x, y
 
 
+def _fold_halve(x, axis=-1, times=1):
+    """``_fold_sum``'s first ``times`` halvings along ``axis``: each adds
+    ``x[h:2h]`` to ``x[:h]``, ``h`` half the length, pairing ``x[i]`` with
+    ``x[i + h]``.  An odd length's last element is left out (``_fold_sum``
+    carries it itself), so while each halved length is even,
+    ``_fold_sum(x) == _fold_sum(_fold_halve(x, axis, m), axis)`` bitwise:
+    ``_fold_sum`` halves through this very function."""
+    for _ in range(times):
+        h = x.shape[axis] // 2
+        x = _take(x, 0, h, axis) + _take(x, h, 2 * h, axis)
+    return x
+
+
 def _fold_sum(x, axis=-1, keepdims=False):
     """Sum over ``axis`` with a fixed pairwise-tree association.
 
@@ -76,12 +91,25 @@ def _fold_sum(x, axis=-1, keepdims=False):
         return x.sum(axis=axis, keepdims=keepdims)
     s = x
     if n > 1:
-        h = n // 2
-        s = _fold_sum(_take(x, 0, h, axis) + _take(x, h, 2 * h, axis),
-                      axis, keepdims=True)
+        s = _fold_sum(_fold_halve(x, axis), axis, keepdims=True)
         if n % 2:
             s = s + _take(x, n - 1, n, axis)
     return s if keepdims else s.squeeze(axis)
+
+
+def _max_halve(x, axis=-1, times=1):
+    """``_fold_halve`` for a max reduction: the elementwise max of
+    ``x[:h]`` and ``x[h:2h]``, ``times`` over (each length even).  Max is
+    exact in any order, so the halved max equals the whole one."""
+    for _ in range(times):
+        h = x.shape[axis] // 2
+        a, b = _take(x, 0, h, axis), _take(x, h, 2 * h, axis)
+        if isinstance(x, np.ndarray):
+            x = np.maximum(a, b)
+        else:
+            import jax.numpy as jnp
+            x = jnp.maximum(a, b)
+    return x
 
 
 def _pin_rounding(x):
@@ -100,22 +128,21 @@ def _pin_rounding(x):
     return jnp.maximum(x, 0.0)
 
 
-# Every metric reduces the coordinate axis ``axis`` (default: last) of the
-# broadcast difference.  ``keepdims`` leaves it as length 1 — the kernel's
-# [dim, cap] page view reduces to a [1, cap] row that way.
-
-@register_metric("d_inf")
-def d_inf(x, y, n_dims: int | None = None, *, axis=-1, keepdims=False):
-    """Chebyshev metric; broadcasting pairwise over leading axes."""
-    x, y = _truncate(x, y, n_dims, axis)
-    return abs(x - y).max(axis=axis, keepdims=keepdims)
+def _abs_diff(x, y):
+    return abs(x - y)
 
 
-@register_metric("l2")
-def l2(x, y, n_dims: int | None = None, *, axis=-1, keepdims=False):
-    x, y = _truncate(x, y, n_dims, axis)
+def _sq_diff(x, y):
     d = x - y
-    s = _fold_sum(_pin_rounding(d * d), axis, keepdims)
+    return _pin_rounding(d * d)
+
+
+def _max(t, axis=-1, keepdims=False):
+    return t.max(axis=axis, keepdims=keepdims)
+
+
+def _root_fold_sum(t, axis=-1, keepdims=False):
+    s = _fold_sum(t, axis, keepdims)
     if isinstance(s, np.ndarray):
         return np.sqrt(s)
     # true sqrt, not s ** 0.5: pow goes through libm whose rounding varies
@@ -125,10 +152,57 @@ def l2(x, y, n_dims: int | None = None, *, axis=-1, keepdims=False):
     return jnp.sqrt(s)
 
 
+class Stages(NamedTuple):
+    """A metric as the stages a caller may re-lay its terms out between:
+    the frontier kernel halves a wide page's coordinates along lanes, then
+    transposes what is left to fold it over sublanes.  For each metric
+    ``reduce(halve(terms(x, y), a, m), a, keepdims)`` is the metric over
+    axis ``a``, bit for bit, while each of the ``m`` halved lengths is
+    even; ``m = 0`` is the metric's own definition."""
+    terms: Callable    # (x, y) -> per-coordinate terms, broadcast
+    halve: Callable    # (t, axis, times) -> the reduction's first halvings
+    reduce: Callable   # (t, axis, keepdims) -> the distance
+
+
+_STAGES: dict[str, Stages] = {
+    "d_inf": Stages(_abs_diff, _max_halve, _max),
+    "l2": Stages(_sq_diff, _fold_halve, _root_fold_sum),
+    "l1": Stages(_abs_diff, _fold_halve, _fold_sum),
+}
+
+
+def get_stages(name: str) -> Stages:
+    try:
+        return _STAGES[name]
+    except KeyError:
+        raise KeyError(f"metric {name!r} has no stages; have "
+                       f"{sorted(_STAGES)}") from None
+
+
+def _staged(name, x, y, n_dims, axis, keepdims):
+    x, y = _truncate(x, y, n_dims, axis)
+    st = _STAGES[name]
+    return st.reduce(st.terms(x, y), axis, keepdims)
+
+
+# Every metric reduces the coordinate axis ``axis`` (default: last) of the
+# broadcast difference.  ``keepdims`` leaves it as length 1 — the kernel's
+# [dim, cap] page view reduces to a [1, cap] row that way.
+
+@register_metric("d_inf")
+def d_inf(x, y, n_dims: int | None = None, *, axis=-1, keepdims=False):
+    """Chebyshev metric; broadcasting pairwise over leading axes."""
+    return _staged("d_inf", x, y, n_dims, axis, keepdims)
+
+
+@register_metric("l2")
+def l2(x, y, n_dims: int | None = None, *, axis=-1, keepdims=False):
+    return _staged("l2", x, y, n_dims, axis, keepdims)
+
+
 @register_metric("l1")
 def l1(x, y, n_dims: int | None = None, *, axis=-1, keepdims=False):
-    x, y = _truncate(x, y, n_dims, axis)
-    return _fold_sum(abs(x - y), axis, keepdims)
+    return _staged("l1", x, y, n_dims, axis, keepdims)
 
 
 def pairwise(metric: str | MetricFn, X, Y, n_dims: int | None = None):

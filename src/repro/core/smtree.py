@@ -592,12 +592,14 @@ def level_grid_steps(height: int, capacity: int, F: int,
 def kernel_work(height: int, capacity: int, F: int, dim: int) -> dict:
     """What the frontier kernel does for a query row, as
     ``obs.observe_query_result`` counts it: the slots (``widths``) and
-    grid steps (``grid_steps``) of each level, and the bytes it copies for
-    one live slot (``page_bytes``)."""
-    from repro.kernels.frontier import page_bytes
+    grid steps (``grid_steps``) of each level, the bytes it copies for
+    one live slot (``page_bytes``), and the halvings it folds a page's
+    coordinates by along lanes before its transpose (``lane_stages``)."""
+    from repro.kernels.frontier import lane_stages, page_bytes
     return dict(widths=level_widths(height, capacity, F),
                 grid_steps=level_grid_steps(height, capacity, F, dim),
-                page_bytes=page_bytes(capacity, dim))
+                page_bytes=page_bytes(capacity, dim),
+                lane_stages=lane_stages(dim))
 
 
 @functools.partial(jax.jit,

@@ -73,11 +73,16 @@ is a vector-to-scalar read, which cost more on a v5e than the metric it
 saved.
 
 Invalid slots emit +inf rows.  The metric is the shared definition in
-``core/metric.py``, folding the coordinates of each live slot's transposed
-page (``[dim, cap]``) over sublanes; its fixed-association tree-fold makes
-the kernel bitwise identical to the XLA path (``frontier_scores_xla``) —
-asserted by tests/test_frontier_kernel.py in interpret mode, which runs
-this exact kernel code on CPU CI.
+``core/metric.py``, folding the coordinates of each live slot's page over
+sublanes, transposed (``[dim, cap]``).  A page whose coordinates halve in
+whole 128-lane tiles (``lane_stages``: a kNN-LM datastore's 3,072 three
+times, to 384) first takes its terms and the fold's first halvings along
+lanes as stored, every lane in use, and transposes only the rest; pages
+that do not (20, 128, 784, 960 dims) are transposed whole.  The halvings
+are the fold's own (``core.metric.Stages``), and its fixed-association
+tree-fold makes the kernel bitwise identical to the XLA path
+(``frontier_scores_xla``) — asserted by tests/test_frontier_kernel.py in
+interpret mode, which runs this exact kernel code on CPU CI.
 """
 from __future__ import annotations
 
@@ -88,7 +93,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.metric import get_metric
+from repro.core.metric import get_metric, get_stages
 
 # python literal (not a jnp scalar): kernels may not capture traced consts
 _INF = float("inf")
@@ -129,6 +134,23 @@ _VMEM_HEADROOM = 8 * 2**20
 # (a level wider than about 3,700 slots at a cohort of 64) is split into
 # calls over column chunks of whole blocks.
 _SMEM_TABLE_BYTES = 960 * 2**10
+
+
+# lanes of a vreg: a lane-axis slice at a multiple of this moves whole vregs
+_LANES = 128
+
+
+def lane_stages(dim: int) -> int:
+    """Halvings of the coordinate fold the kernel runs along lanes, on the
+    page as stored (``[cap, dim]``), before it transposes what is left: the
+    number of leading halvings whose half is a whole number of 128-lane
+    tiles (3,072 -> 1,536 -> 768 -> 384 gives 3; 20, 128, 784 and 960
+    give 0, and such pages are transposed whole, as they always were)."""
+    m = 0
+    while dim > 0 and dim % (2 * _LANES) == 0:
+        dim //= 2
+        m += 1
+    return m
 
 
 def _vmem_bytes(rows: int, cols: int) -> int:
@@ -207,10 +229,15 @@ def _frontier_kernel(fids_ref, tops_ref, *refs, metric: str, prune: bool,
     loop: unrolling it ran a v5e descent 4-6% faster but tripled the
     host's lowering time, which every process start pays.
 
-    Each live slot's page is scored transposed (``[dim, cap]``): the
-    metric folds the coordinates over sublanes and yields the ``[1, cap]``
-    row the outputs want.  ``meta`` rows are the radius, the entry kind
-    (1 internal, 2 leaf, 0 neither, as f32) and pdist (prune only)."""
+    Each live slot's page yields the ``[1, cap]`` row the outputs want
+    by a fold of its coordinates over sublanes, on the page transposed
+    (``[dim, cap]``).  A page whose coordinates halve in whole lane tiles
+    (``lane_stages``: ``m`` of them) takes its metric terms and the fold's
+    first ``m`` halvings in the stored ``[cap, dim]`` orientation, every
+    lane in use, and transposes only the ``[cap, dim >> m]`` rest; the
+    halvings are the fold's own (``core.metric.Stages``), so the row is
+    the same bits.  ``meta`` rows are the radius, the entry kind (1
+    internal, 2 leaf, 0 neither, as f32) and pdist (prune only)."""
     if prune:
         (q_ref, rq_ref, qpd_ref, vecs_hbm, meta_hbm, dmax_ref, score_ref,
          leafd_ref, dq_ref, page_buf, meta_buf, sems) = refs
@@ -263,10 +290,15 @@ def _frontier_kernel(fids_ref, tops_ref, *refs, metric: str, prune: bool,
     for ref in (dmax_ref, score_ref, leafd_ref, dq_ref):
         ref[0, pl.ds(base, g), :] = inf_rows
 
+    m = lane_stages(dim)
+
     @pl.when(tops_ref[step] >= 0)
     def _():
         qrow = pl.ds(i % qb, 1)
-        qt = q_ref[qrow, :].T                              # [dim, 1]
+        if m:
+            q = q_ref[qrow, :]                             # [1, dim]
+        else:
+            qt = q_ref[qrow, :].T                          # [dim, 1]
         if prune:
             rq = rq_ref[qrow, :]                           # [1, 1]
 
@@ -285,9 +317,16 @@ def _frontier_kernel(fids_ref, tops_ref, *refs, metric: str, prune: bool,
                     keep = lb <= rq + r + _PRUNE_PAD
                     kind = jnp.where(keep, kind, 0.0)
                 # every live page is scored (module docstring)
-                page = page_buf[buf, s][:cap, :dim]        # [cap, dim]
-                d = get_metric(metric)(qt, page.T, axis=0,
-                                       keepdims=True)      # [1, cap]
+                if m:
+                    # the buffer's rows are whole sublane tiles and its
+                    # lanes the page's own; rows past cap are cut last
+                    st = get_stages(metric)
+                    t = st.halve(st.terms(q, page_buf[buf, s]), 1, m)
+                    d = st.reduce(t.T, 0, True)[:, :cap]   # [1, cap]
+                else:
+                    page = page_buf[buf, s][:cap, :dim]    # [cap, dim]
+                    d = get_metric(metric)(qt, page.T, axis=0,
+                                           keepdims=True)  # [1, cap]
                 iv = kind == 1.0
                 row = pl.ds(j * g + s, 1)
                 dmax_ref[0, row, :] = jnp.where(iv, d + r, _INF)

@@ -161,7 +161,8 @@ def want_level_stats() -> bool:
 def observe_query_result(res, pruned=None, *, prefix: str = "descent",
                          rows: int | None = None,
                          widths=None, grid_steps=None,
-                         page_bytes: int | None = None) -> None:
+                         page_bytes: int | None = None,
+                         lane_stages: int | None = None) -> None:
     """Accumulate the descent's per-dispatch reductions into paper-level
     counters: metric (distance) evaluations, nodes visited, and — when
     the kernel was asked for level stats — pruned-by-bound and
@@ -178,7 +179,11 @@ def observe_query_result(res, pruned=None, *, prefix: str = "descent",
     ``{prefix}.grid_steps_total``: rows x sum(grid_steps).
     ``page_bytes``, what the frontier kernel copies for one live slot
     (``smtree.kernel_work``), adds ``{prefix}.page_bytes_total``
-    beside them: nodes visited x page_bytes.
+    beside them: nodes visited x page_bytes.  ``lane_stages``, the
+    halvings the kernel folds a page's coordinates by along lanes before
+    its transpose (``smtree.kernel_work``), adds
+    ``{prefix}.lane_folded_slots_total``: the nodes visited where it is
+    above 0, else 0.
 
     ``pruned`` is what ``smtree.knn(..., level_stats=True)`` returned:
     a ``(by_bound, by_parent, live_blocks)`` triple of ``[levels, b]``
@@ -211,6 +216,9 @@ def observe_query_result(res, pruned=None, *, prefix: str = "descent",
     if widths is not None:
         REGISTRY.counter(f"{prefix}.grid_slots_total").inc(
             b * int(sum(widths)))
+    if lane_stages is not None:
+        REGISTRY.counter(f"{prefix}.lane_folded_slots_total").inc(
+            nodes if lane_stages > 0 else 0)
     if grid_steps is not None:
         REGISTRY.counter(f"{prefix}.grid_steps_total").inc(
             b * int(sum(grid_steps)))

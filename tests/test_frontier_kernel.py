@@ -252,13 +252,19 @@ def block_rule(request, monkeypatch):
 
 @pytest.mark.parametrize("prune", [False, True], ids=["plain", "parent_prune"])
 @pytest.mark.parametrize("cap,dim,metric", [(42, 20, "d_inf"),
-                                            (32, 128, "l2")],
-                         ids=["served", "dim128"])
+                                            (32, 128, "l2"),
+                                            (8, 256, "l2"),
+                                            (8, 256, "d_inf")],
+                         ids=["served", "dim128", "dim256_l2",
+                              "dim256_d_inf"])
 @pytest.mark.parametrize("w", [1, 7, 42, 441])
 def test_blocked_kernel_matches_xla_bitwise(block_rule, w, cap, dim, metric,
                                             prune):
     rng = np.random.default_rng(w + dim)
     fids, queries, vecs, radius, iv, lv = _blocked_case(rng, w, cap, dim)
+    # the 20- and 128-d pages are transposed whole; the 256-d one folds
+    # once along lanes first
+    assert frontier.lane_stages(dim) == (dim == 256)
     g = frontier.block_slots(w, cap, dim)
     assert (g == 1) == (block_rule == "one_slot" or w == 1)
     kw = {}
@@ -319,27 +325,37 @@ def test_live_blocks_counts_blocks_with_a_live_id():
 # dims (a whole number of lane tiles, so the wrapper hands the kernel the
 # tree's own pages), L2, whose fold of 3 x 2^10 coordinates carries an odd
 # tail.  block_slots gives 5 slots a step, so 12 slots are two full blocks
-# and a partial one.
+# and a partial one.  The page folds three times along lanes (3,072 ->
+# 384) before its transpose, for L2's sums as for L-infinity's max.
 
+@pytest.mark.parametrize("metric", ["l2", "d_inf"])
 @pytest.mark.parametrize("prune", [False, True], ids=["plain", "parent_prune"])
-def test_knnlm_page_kernel_matches_xla_bitwise(prune):
+def test_knnlm_page_kernel_matches_xla_bitwise(prune, metric):
     rng = np.random.default_rng(3072)
     cap, dim, w = 32, 3072, 12
     fids, queries, vecs, radius, iv, lv = _blocked_case(rng, w, cap, dim,
                                                         N=16)
     assert frontier.block_slots(w, cap, dim) == 5
+    assert frontier.lane_stages(dim) == 3
     kw = {}
     if prune:
         pdist, qpd, rq = _random_prune_inputs(rng, fids, vecs.shape[0], cap)
         kw = dict(pdist=pdist, qpd=qpd, rq=rq)
     got = frontier_scores_pallas(fids, queries, vecs, radius, iv, lv,
-                                 metric="l2", interpret=True, **kw)
+                                 metric=metric, interpret=True, **kw)
     want = frontier_scores_xla(fids, queries, vecs, radius, iv, lv,
-                               metric="l2", **kw)
+                               metric=metric, **kw)
     for gv, wv, name in zip(got, want, OUT_NAMES):
         np.testing.assert_array_equal(np.asarray(gv), np.asarray(wv),
                                       err_msg=name)
     assert np.isfinite(np.asarray(got[2])).any()
+
+
+@pytest.mark.parametrize("dim,m", [(20, 0), (128, 0), (784, 0), (960, 0),
+                                   (256, 1), (3072, 3), (2048, 4)])
+def test_lane_stages_rule(dim, m):
+    """Halvings while the half is a whole number of 128-lane tiles."""
+    assert frontier.lane_stages(dim) == m
 
 
 def test_page_bytes_and_vmem_limit():

@@ -269,3 +269,21 @@ def test_metrics_snapshot_and_missing_rows(obs_on):
     assert missing_rows(snap, ["router.", "frontend."]) == ["router."]
     # the snapshot is JSON all the way down
     json.dumps(snap)
+
+
+# ---------------------------------------------------------------- descent
+
+@pytest.mark.parametrize("dim", [3072, 20])
+def test_lane_folded_slots_count_wide_pages_only(obs_on, dim):
+    """A cohort over 3,072-dim pages (folded along lanes before the
+    kernel's transpose) counts every live slot; over 20-d pages, none."""
+    from repro.core.smtree import bulk_build
+    from repro.serve.frontend import pinned_knn
+    X = np.random.default_rng(dim).random((96, dim)).astype(np.float32)
+    tree = bulk_build(X, capacity=8, metric="l2")
+    pinned_knn(tree, X[:5], k=3, max_frontier=64)
+    snap = obs.REGISTRY.snapshot()
+    nodes = snap["descent.nodes_visited_total"]
+    assert nodes > 0
+    assert snap["descent.lane_folded_slots_total"] == \
+        (nodes if dim == 3072 else 0)

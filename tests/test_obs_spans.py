@@ -217,7 +217,8 @@ def test_kernel_work_gathers_the_counter_inputs():
     work = smtree.kernel_work(3, 42, 2048, 20)
     assert work == dict(widths=smtree.level_widths(3, 42, 2048),
                         grid_steps=smtree.level_grid_steps(3, 42, 2048, 20),
-                        page_bytes=page_bytes(42, 20))
+                        page_bytes=page_bytes(42, 20), lane_stages=0)
+    assert smtree.kernel_work(4, 32, 12288, 3072)["lane_stages"] == 3
 
 
 def test_level_widths():
